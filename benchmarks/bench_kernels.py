@@ -63,9 +63,9 @@ def run() -> list[dict]:
                 "bench shape or K")
 
         us_two = time_us(lambda: ops.block_shotgun_round(
-            Ap, z, x, blk, prob.lam, prob.beta, yp, mask, interpret=True), reps=5)
+            Ap, z, x, blk, prob.lam, prob.beta, yp, mask), reps=5)
         us_fused_launch = time_us(lambda: fused_shotgun_rounds(
-            Ap, z, x, idx, prob.lam, prob.beta, yp, mask, interpret=True),
+            Ap, z, x, idx, prob.lam, prob.beta, yp, mask),
             reps=10)
         us_fused = us_fused_launch / R
         # sentinel-armed launch: dynamic k_eff/guard ride the scalar-prefetch
@@ -74,7 +74,7 @@ def run() -> list[dict]:
         k_eff = jnp.int32(K)
         guard_f = jnp.float32(3.4e38)
         us_fused_g = time_us(lambda: fused_shotgun_rounds(
-            Ap, z, x, idx, prob.lam, prob.beta, yp, mask, interpret=True,
+            Ap, z, x, idx, prob.lam, prob.beta, yp, mask,
             k_eff=k_eff, guard_f=guard_f), reps=10) / R
         # scalar Shotgun round with the same effective P = K*128
         us_scalar = time_us(lambda: shotgun_solve(

@@ -13,7 +13,8 @@ Appends its rows (tagged ``"bench": "sharded"``) to the repo-root
 ``BENCH_kernels.json`` perf-trajectory artifact — full runs only; a
 BENCH_SMOKE=1 pass shrinks the shape and leaves the committed artifact
 alone.  Spawns its own subprocess so the forced device count never leaks
-into the caller's jax.
+into the caller's jax; the parent never imports JAX, so the child is the
+only process that can hold an accelerator.
 """
 from __future__ import annotations
 

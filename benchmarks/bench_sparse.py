@@ -79,11 +79,9 @@ def run() -> list[dict]:
 
         # two-kernel sparse round vs R fused sparse rounds in one launch
         us_blk_sparse = time_us(lambda: ops.sparse_block_shotgun_round(
-            rows_t, vals_t, zs, xs, blk, ps.lam, ps.beta, ps.y,
-            interpret=True))
+            rows_t, vals_t, zs, xs, blk, ps.lam, ps.beta, ps.y))
         us_fused_sparse = time_us(lambda: fused_sparse_shotgun_rounds(
-            rows_t, vals_t, zs, xs, idx_rk, ps.lam, ps.beta, ps.y,
-            interpret=True)) / R
+            rows_t, vals_t, zs, xs, idx_rk, ps.lam, ps.beta, ps.y)) / R
 
         # bf16 nnz value tiles (DESIGN §8.3): 6 B/slot instead of 8, f32
         # accumulation in-kernel — time the same fused launch and check the
@@ -92,15 +90,13 @@ def run() -> list[dict]:
         # perturbs the coordinate updates before the iterates settle)
         vals16 = vals_t.astype(jnp.bfloat16)
         us_fused_bf16 = time_us(lambda: fused_sparse_shotgun_rounds(
-            rows_t, vals16, zs, xs, idx_rk, ps.lam, ps.beta, ps.y,
-            interpret=True)) / R
+            rows_t, vals16, zs, xs, idx_rk, ps.lam, ps.beta, ps.y)) / R
 
         def solve_chain(vals, launches, idx):
             x, z = xs, zs
             for _ in range(launches):
                 x, z, f, _, _ = fused_sparse_shotgun_rounds(
-                    rows_t, vals, z, x, idx, ps.lam, ps.beta, ps.y,
-                    interpret=True)
+                    rows_t, vals, z, x, idx, ps.lam, ps.beta, ps.y)
             return float(f[-1])
 
         rel_err_bf16 = None
@@ -164,10 +160,9 @@ def run() -> list[dict]:
             x = jnp.zeros(Ap.shape[1])
             z = jnp.zeros(Ap.shape[0])
             us_blk_dense = time_us(lambda: ops.block_shotgun_round(
-                Ap, z, x, blk, pd.lam, pd.beta, yp, mask, interpret=True))
+                Ap, z, x, blk, pd.lam, pd.beta, yp, mask))
             us_fused_dense = time_us(lambda: fused_shotgun_rounds(
-                Ap, z, x, idx_rk, pd.lam, pd.beta, yp, mask,
-                interpret=True)) / R
+                Ap, z, x, idx_rk, pd.lam, pd.beta, yp, mask)) / R
 
             row.update({
                 "scalar_round_us_dense": round(us_scalar_dense, 1),

@@ -1,11 +1,13 @@
-"""Shared benchmark plumbing: timing + CSV emission + F* oracles."""
+"""Shared benchmark plumbing: timing + CSV emission + F* oracles.
+
+JAX is imported inside the functions that use it, so a parent process
+that only emits or merges results (``bench_sharded``) never touches it.
+"""
 from __future__ import annotations
 
 import json
 import pathlib
 import time
-
-import jax
 
 RESULTS = pathlib.Path(__file__).resolve().parent / "results"
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -18,6 +20,7 @@ def fstar_of(prob, iters=6000) -> float:
 
 def timed(fn, *args, **kw):
     """(result, seconds) with block_until_ready on jax outputs."""
+    import jax
     t0 = time.time()
     out = fn(*args, **kw)
     jax.block_until_ready(out)
@@ -82,6 +85,7 @@ def merge_root(rows, tag, root_name="BENCH_kernels.json"):
 
 def time_us(fn, reps=3):
     """Mean wall time of ``fn`` in µs after one warm/compile call."""
+    import jax
     fn()
     t0 = time.time()
     for _ in range(reps):

@@ -3,43 +3,50 @@
     PYTHONPATH=src python -m benchmarks.run            # everything
     PYTHONPATH=src python -m benchmarks.run fig2 fig5  # subset
 
-Each sub-benchmark prints progress lines; this wrapper ends with a
-``name,seconds,rows`` CSV summary and writes JSON under benchmarks/results/.
+Each sub-benchmark runs as ``python -m benchmarks.<module>`` in a child
+process of its own, one after another, and this parent never imports
+JAX: a process that has touched JAX holds the accelerator, and a child
+that needs it would then fail or hang.  Each child prints progress lines
+and writes JSON under benchmarks/results/; this wrapper ends with a
+``name,seconds`` CSV summary and exits non-zero if any child failed.
 """
 from __future__ import annotations
 
+import os
+import subprocess
 import sys
 import time
 
+MODULES = {
+    "fig2": "fig2_parallelism",
+    "fig3": "fig3_lasso_solvers",
+    "fig4": "fig4_logreg",
+    "logreg": "fig4_logreg",   # alias: the bench=logreg kernel rows
+    "fig5": "fig5_speedup",
+    "kernels": "bench_kernels",
+    "serve": "bench_serve",
+    "sharded": "bench_sharded",
+    "sparse": "bench_sparse",
+    "shotgun_scale": "shotgun_scale",
+    "roofline": "roofline",
+}
+
 
 def main() -> None:
-    from benchmarks import (bench_kernels, bench_serve, bench_sharded,
-                            bench_sparse, fig2_parallelism,
-                            fig3_lasso_solvers, fig4_logreg, fig5_speedup,
-                            roofline, shotgun_scale)
-    ALL = {
-        "fig2": fig2_parallelism.run,
-        "fig3": fig3_lasso_solvers.run,
-        "fig4": fig4_logreg.run,
-        "logreg": fig4_logreg.run,   # alias: the bench=logreg kernel rows
-        "fig5": fig5_speedup.run,
-        "kernels": bench_kernels.run,
-        "serve": bench_serve.run,
-        "sharded": bench_sharded.run,
-        "sparse": bench_sparse.run,
-        "shotgun_scale": shotgun_scale.run,
-        "roofline": roofline.run,
-    }
-    picks = [a for a in sys.argv[1:] if a in ALL] or list(ALL)
+    picks = [a for a in sys.argv[1:] if a in MODULES] or [
+        name for name in MODULES if name != "logreg"]
     summary = []
     for name in picks:
         t0 = time.time()
-        rows = ALL[name]()
-        dt = time.time() - t0
-        summary.append((name, dt, len(rows) if rows is not None else 0))
-    print("\n# name,seconds,rows")
-    for name, dt, n in summary:
-        print(f"{name},{dt:.1f},{n}")
+        rc = subprocess.run([sys.executable, "-m",
+                             f"benchmarks.{MODULES[name]}"],
+                            env=os.environ).returncode
+        summary.append((name, time.time() - t0, rc))
+    print("\n# name,seconds")
+    for name, dt, rc in summary:
+        print(f"{name},{dt:.1f}" + ("" if rc == 0 else f",FAILED rc={rc}"))
+    if any(rc for _, _, rc in summary):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -12,23 +12,14 @@ Matching is line-number-free on purpose — line anchors rot with every
 edit.  A finding is suppressed when an entry's rule and path match and
 ``match`` (when present) is a substring of the message.  Entries that
 suppress nothing are reported by the CLI so dead exceptions get pruned.
-
-Python 3.10 has no ``tomllib``, so a tiny parser for exactly this subset
-(table arrays of ``key = "string"`` pairs, comments, blank lines) backs the
-stdlib module when it is missing.  Anything fancier in the file is a lint
-configuration error and raises.
 """
 from __future__ import annotations
 
 import pathlib
+import tomllib
 from typing import Iterable, NamedTuple
 
 from repro.analyze.findings import Finding
-
-try:                                    # Python >= 3.11
-    import tomllib as _toml
-except ImportError:                     # this container: 3.10
-    _toml = None
 
 
 class AllowEntry(NamedTuple):
@@ -42,42 +33,13 @@ class AllowEntry(NamedTuple):
                 and (not self.match or self.match in f.message))
 
 
-def _parse_toml_subset(text: str) -> dict:
-    """``[[allow]]`` arrays of ``key = "value"`` string pairs, nothing else."""
-    out: dict = {"allow": []}
-    cur: dict | None = None
-    for ln, raw in enumerate(text.splitlines(), 1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line == "[[allow]]":
-            cur = {}
-            out["allow"].append(cur)
-            continue
-        if "=" in line and cur is not None:
-            key, _, val = line.partition("=")
-            key, val = key.strip(), val.strip()
-            # strip a trailing comment outside the quoted value
-            if val.startswith('"') and val.count('"') >= 2:
-                val = val[1:val.index('"', 1)]
-                cur[key] = val
-                continue
-        raise ValueError(f"allowlist line {ln}: cannot parse {raw!r} "
-                         "(only [[allow]] tables of key = \"value\" pairs)")
-    return out
-
-
 def load_allowlist(path: str | pathlib.Path | None) -> list[AllowEntry]:
     if path is None:
         return []
     path = pathlib.Path(path)
     if not path.exists():
         return []
-    text = path.read_text()
-    if _toml is not None:
-        data = _toml.loads(text)
-    else:
-        data = _parse_toml_subset(text)
+    data = tomllib.loads(path.read_text())
     entries = []
     for i, raw in enumerate(data.get("allow", [])):
         missing = {"rule", "path", "reason"} - set(raw)

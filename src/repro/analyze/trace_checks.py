@@ -6,7 +6,7 @@ binding.
   SL101  VMEM budget        every registered fused config (the rows of the
                             committed ``BENCH_kernels.json`` perf artifact)
                             must fit its whole VMEM resident set — scratch +
-                            BlockSpec tiles — inside ``VMEM_BUDGET`` (16 MiB)
+                            BlockSpec tiles — inside ``VMEM_BUDGET``
                             per ``fused_vmem_bytes`` (dense) and
                             ``fused_sparse_vmem_bytes`` (BlockedCSC).
                             Interpret mode never notices an oversized
@@ -287,13 +287,13 @@ def default_retrace_targets() -> list[tuple]:
             return (lambda: solve(lprob, k0, rounds=2),
                     lambda: solve(lprob2, k1, rounds=2))
         if name == "block":
-            return (lambda: solve(prob, k0, K=1, rounds=2, interpret=True),
-                    lambda: solve(prob2, k1, K=1, rounds=2, interpret=True))
+            return (lambda: solve(prob, k0, K=1, rounds=2),
+                    lambda: solve(prob2, k1, K=1, rounds=2))
         if name == "block_fused":
             return (lambda: solve(prob, k0, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True),
+                                  rounds_per_launch=2),
                     lambda: solve(prob2, k1, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True))
+                                  rounds_per_launch=2))
         if name == "sharded":
             return (lambda: solve(prob, k0, P_local=2, rounds=2,
                                   engine="scalar"),
@@ -301,14 +301,14 @@ def default_retrace_targets() -> list[tuple]:
                                   engine="scalar"))
         if name == "shotgun_logreg_fused":
             return (lambda: solve(lprob, k0, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True),
+                                  rounds_per_launch=2),
                     lambda: solve(lprob2, k1, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True))
+                                  rounds_per_launch=2))
         if name == "sparse_logreg_fused":
             return (lambda: solve(slprob, k0, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True),
+                                  rounds_per_launch=2),
                     lambda: solve(slprob2, k1, K=1, rounds=2,
-                                  rounds_per_launch=2, interpret=True))
+                                  rounds_per_launch=2))
         raise ValueError(f"no retrace target for solver {name!r}")
 
     targets = [(name,) + calls(name) for name in SOLVER_NAMES]
@@ -353,8 +353,7 @@ def _batched_retrace_targets() -> list[tuple]:
     def solve(probs, seed):
         keys = [jax.random.PRNGKey(seed + s) for s in range(len(probs))]
         return batched_block_shotgun_solve(probs, keys, 1, 2,
-                                           rounds_per_launch=2,
-                                           interpret=True)
+                                           rounds_per_launch=2)
 
     return [
         ("batched_dense",
@@ -406,7 +405,6 @@ def probe_shard_map(mesh_shape, mesh_axes, spec_axis) -> str | None:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.launch.mesh import make_mesh
 
     n_need = 1
@@ -417,9 +415,9 @@ def probe_shard_map(mesh_shape, mesh_axes, spec_axis) -> str | None:
     try:
         mesh = make_mesh(mesh_shape, mesh_axes)
         size = n_need * 8
-        f = shard_map(lambda a: jax.lax.psum(a, spec_axis), mesh=mesh,
-                      in_specs=(P(spec_axis),), out_specs=P(None),
-                      check_vma=False)
+        f = jax.shard_map(lambda a: jax.lax.psum(a, spec_axis), mesh=mesh,
+                          in_specs=(P(spec_axis),), out_specs=P(None),
+                          check_vma=False)
         jax.block_until_ready(f(jnp.ones(size, jnp.float32)))
         return None
     except Exception as e:

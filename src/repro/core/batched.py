@@ -47,7 +47,8 @@ from repro.data.sparse import BlockedCSC, bcsc_matvec, pad_feature_blocks
 from repro.kernels.batched import (batched_draw_blocks,
                                    batched_fused_shotgun_rounds,
                                    batched_fused_sparse_shotgun_rounds)
-from repro.kernels.shotgun_block import BLOCK, TILE_N, auto_tile_n
+from repro.kernels.shotgun_block import BLOCK, TILE_N
+from repro.kernels.shotgun_sparse import require_sparse_backend
 
 
 class BatchMeta(NamedTuple):
@@ -180,8 +181,7 @@ def stack_problems(probs: Sequence[Problem], meta: BatchMeta | None = None
 # ---------------------------------------------------------------------------
 
 def launch_rounds(meta: BatchMeta, stacked: SlotArrays, z, x, idx, k_eff,
-                  guard_f=None, interpret: bool = True,
-                  tile_n: int | None = None):
+                  guard_f=None, tile_n: int | None = None):
     """ONE batched launch: R fused rounds on every slot with ``k_eff[s]``
     live blocks (0 = frozen, bit-exact no-op).  ``guard_f`` is the per-slot
     in-kernel objective guard ((S,), None = +inf = unguarded, bit-exact):
@@ -196,14 +196,11 @@ def launch_rounds(meta: BatchMeta, stacked: SlotArrays, z, x, idx, k_eff,
     if meta.layout == "bcsc":
         return batched_fused_sparse_shotgun_rounds(
             stacked.rows, stacked.vals, z, x, idx, stacked.lam,
-            stacked.beta, stacked.y, k_eff, guard, loss=meta.loss,
-            interpret=interpret)
-    if tile_n is None:
-        tile_n = auto_tile_n(meta.n_pad, meta.block, d=meta.d_pad)
+            stacked.beta, stacked.y, k_eff, guard, loss=meta.loss)
     return batched_fused_shotgun_rounds(
         stacked.A, z, x, idx, stacked.lam, stacked.beta, stacked.y,
         stacked.mask, k_eff, guard, loss=meta.loss, block=meta.block,
-        tile_n=tile_n, interpret=interpret)
+        tile_n=tile_n)
 
 
 def init_margin(meta: BatchMeta, stacked: SlotArrays, x):
@@ -212,7 +209,8 @@ def init_margin(meta: BatchMeta, stacked: SlotArrays, x):
     if meta.layout == "bcsc":
         return jax.vmap(lambda r, v, x_: bcsc_matvec(r, v, x_, meta.n_pad)
                         )(stacked.rows, stacked.vals, x)
-    return jax.vmap(lambda a, x_: a.astype(jnp.float32) @ x_)(stacked.A, x)
+    return jax.vmap(lambda a, x_: obj.matvec(a.astype(jnp.float32), x_))(
+        stacked.A, x)
 
 
 def _stack_x0(x0s, S, d_pad):
@@ -232,7 +230,6 @@ def batched_block_shotgun_solve(probs: Sequence[Problem], keys,
                                 K: int | None = None,
                                 rounds: int | None = None,
                                 rounds_per_launch: int = 8,
-                                interpret: bool = True,
                                 meta: BatchMeta | None = None,
                                 x0s=None, tile_n: int | None = None,
                                 spec: SolverSpec | None = None
@@ -276,6 +273,8 @@ def batched_block_shotgun_solve(probs: Sequence[Problem], keys,
         raise ValueError(f"rounds={rounds} not divisible by "
                          f"rounds_per_launch={R}")
     meta, stacked = stack_problems(probs, meta)
+    if meta.layout == "bcsc":
+        require_sparse_backend()
     S = len(probs)
     keys = jnp.stack([jnp.asarray(k) for k in keys]) \
         if not isinstance(keys, jax.Array) else keys
@@ -293,7 +292,6 @@ def batched_block_shotgun_solve(probs: Sequence[Problem], keys,
         x, z = carry
         idx = batched_draw_blocks(keys_l, K, meta.nblk)
         x, z, fs, nnzs, _ = launch_rounds(meta, stacked, z, x, idx, k_eff,
-                                          interpret=interpret,
                                           tile_n=tile_n)
         return (x, z), (fs, nnzs)
 

@@ -131,7 +131,6 @@ class BlockEngine(NamedTuple):
     K: int
     loss: str
     block: int = 128
-    interpret: bool = True
 
     fold_always = False
     run_segment = _run_segment
@@ -151,15 +150,13 @@ class BlockEngine(NamedTuple):
             blk = jax.random.choice(key_t, nblk, (self.K,),
                                     replace=False).astype(jnp.int32)
             r = obj.residual_like(z + dz, y, self.loss) * mask
-            g = gather_block_matvec(A_blk, r, blk, block=self.block,
-                                    interpret=self.interpret)
+            g = gather_block_matvec(A_blk, r, blk, block=self.block)
             xb = x_l.reshape(nblk, self.block)
             x_sel = jnp.take(xb, blk, axis=0)
             x_new = obj.soft_threshold(x_sel - g / beta, lam / beta)
             delta = (x_new - x_sel) * live
             dz = scatter_block_update(A_blk, dz, blk, delta,
-                                      block=self.block,
-                                      interpret=self.interpret)
+                                      block=self.block)
             x_l = xb.at[blk].add(delta).reshape(-1)
             return (x_l, dz), None
 
@@ -175,8 +172,7 @@ class FusedEngine(NamedTuple):
     K: int
     loss: str
     block: int = 128
-    tile_n: int | None = None     # resolved to a static int by the driver
-    interpret: bool = True
+    tile_n: int | None = None     # None: the kernel sizes it (auto_tile_n)
 
     fold_always = False
     run_segment = _run_segment
@@ -193,8 +189,7 @@ class FusedEngine(NamedTuple):
         idx = jax.vmap(draw)(keys).astype(jnp.int32)
         return fused_shotgun_delta_rounds(
             A_blk, z, x_l, idx, lam, beta, y, mask, loss=self.loss,
-            block=self.block, tile_n=self.tile_n, interpret=self.interpret,
-            k_eff=p_eff)
+            block=self.block, tile_n=self.tile_n, k_eff=p_eff)
 
 
 class SparseBlockEngine(NamedTuple):
@@ -208,7 +203,6 @@ class SparseBlockEngine(NamedTuple):
     K: int
     loss: str
     block: int = 128
-    interpret: bool = True
 
     fold_always = False
     run_segment = _run_segment
@@ -229,14 +223,12 @@ class SparseBlockEngine(NamedTuple):
             blk = jax.random.choice(key_t, nblk, (self.K,),
                                     replace=False).astype(jnp.int32)
             r = obj.residual_like(z + dz, y, self.loss) * mask
-            g = sparse_gather_block_matvec(rows, vals, r, blk,
-                                           interpret=self.interpret)
+            g = sparse_gather_block_matvec(rows, vals, r, blk)
             xb = x_l.reshape(nblk, self.block)
             x_sel = jnp.take(xb, blk, axis=0)
             x_new = obj.soft_threshold(x_sel - g / beta, lam / beta)
             delta = (x_new - x_sel) * live
-            dz = sparse_scatter_block_update(rows, vals, dz, blk, delta,
-                                             interpret=self.interpret)
+            dz = sparse_scatter_block_update(rows, vals, dz, blk, delta)
             x_l = xb.at[blk].add(delta).reshape(-1)
             return (x_l, dz), None
 
@@ -256,7 +248,6 @@ class SparseFusedEngine(NamedTuple):
 
     K: int
     loss: str
-    interpret: bool = True
 
     fold_always = False
     run_segment = _run_segment
@@ -275,12 +266,12 @@ class SparseFusedEngine(NamedTuple):
         idx = jax.vmap(draw)(keys).astype(jnp.int32)
         return fused_sparse_shotgun_delta_rounds(
             rows, vals, z, x_l, idx, lam, beta, y, loss=self.loss,
-            interpret=self.interpret, k_eff=p_eff)
+            k_eff=p_eff)
 
 
 def make_engine(name: str, *, loss: str, P_local: int = 8, K: int = 2,
                 block: int = 128, tile_n: int | None = None,
-                interpret: bool = True, newton: bool = False):
+                newton: bool = False):
     """Engine registry: build a ``RoundEngine`` by name (``ENGINE_NAMES``).
 
     ``loss`` is a registry string ("lasso" / "logistic") or a full
@@ -301,13 +292,11 @@ def make_engine(name: str, *, loss: str, P_local: int = 8, K: int = 2,
     if name == "scalar":
         return ScalarEngine(P_local=P_local, loss=lname)
     if name == "block":
-        return BlockEngine(K=K, loss=lname, block=block, interpret=interpret)
+        return BlockEngine(K=K, loss=lname, block=block)
     if name == "fused":
-        return FusedEngine(K=K, loss=loss, block=block, tile_n=tile_n,
-                           interpret=interpret)
+        return FusedEngine(K=K, loss=loss, block=block, tile_n=tile_n)
     if name == "sparse_block":
-        return SparseBlockEngine(K=K, loss=lname, block=block,
-                                 interpret=interpret)
+        return SparseBlockEngine(K=K, loss=lname, block=block)
     if name == "sparse_fused":
-        return SparseFusedEngine(K=K, loss=loss, interpret=interpret)
+        return SparseFusedEngine(K=K, loss=loss)
     raise ValueError(f"unknown engine {name!r}; choose from {ENGINE_NAMES}")

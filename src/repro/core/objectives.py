@@ -127,17 +127,20 @@ def require_dense(A, what: str):
 
 
 def matvec(A, x) -> jax.Array:
-    """A @ x for dense or BlockedCSC A."""
+    """A @ x for dense or BlockedCSC A, at full f32 precision: on a TPU the
+    default would round f32 operands to bf16, and a margin built here (warm
+    starts, objectives) must agree with the kernels' f32 accumulation."""
     if isinstance(A, BlockedCSC):
         return A.matvec(x)
-    return A @ x
+    return jnp.matmul(A, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def rmatvec(A, r) -> jax.Array:
-    """A^T r for dense or BlockedCSC A."""
+    """A^T r for dense or BlockedCSC A, at full f32 precision (see
+    ``matvec``)."""
     if isinstance(A, BlockedCSC):
         return A.rmatvec(r)
-    return A.T @ r
+    return jnp.matmul(A.T, r, precision=jax.lax.Precision.HIGHEST)
 
 
 def gather_cols(A, idx):

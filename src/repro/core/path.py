@@ -55,7 +55,7 @@ def _solver_by_name(name: str, **solver_kwargs) -> Callable:
     ``P`` maps onto each family's parallelism knob: the per-round update
     count for the scalar solvers, K = ceil(P / 128) blocks for the Pallas
     solvers, and P_local for the sharded driver.  ``solver_kwargs`` pass
-    through (e.g. ``interpret=``, ``engine=``, ``mesh=``).
+    through (e.g. ``engine=``, ``mesh=``, ``tile_n=``).
     """
     solve = shotgun.get_solver(name)
     # (family, loss) pairs and the frozen *_logreg_fused aliases adapt like

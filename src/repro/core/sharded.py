@@ -61,7 +61,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.core import health
 from repro.core import objectives as obj
@@ -310,10 +309,10 @@ def _engine_solve(A, y, mask, x0, lam, beta, key, *, engine, rounds: int,
         a_spec = jax.tree_util.tree_map(lambda _: P(axes, None, None), A)
     else:
         a_spec = P(None, axes)
-    solve = shard_map(
+    solve = jax.shard_map(
         solve_local, mesh=mesh,
         in_specs=(a_spec, P(None), P(None), P(axes), P(None)),
-        out_specs=(P(axes), P(None), P(None), P(None), P(None)),
+        out_specs=(P(axes), P(None), P(None), P(None), P()),
         check_vma=False,
     )
     x, z, fs, nnzs, backoffs = solve(A, y, mask, x0, key)
@@ -345,7 +344,6 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
                           compression: str = "none", topk_frac: float = 0.01,
                           hierarchical: bool = False,
                           pipeline: bool = False,
-                          interpret: bool = True,
                           guard: GuardConfig | None = None,
                           faults=None,
                           ckpt_dir=None, ckpt_every: int = 0,
@@ -357,7 +355,7 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
 
     engine      "scalar" (P = P_local × shards coordinate updates/round),
                 "block" / "fused" (P = K × 128 × shards via the Pallas
-                kernels; ``interpret=True`` on CPU), "sparse_block" /
+                kernels), "sparse_block" /
                 "sparse_fused" (same P but over a BlockedCSC design via the
                 nnz-tile kernels, DESIGN §8 — column blocks sharded on
                 nblk; "sparse_fused" keeps the margin view and Δz in VMEM
@@ -438,6 +436,8 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
                 f"engine={engine!r} needs a BlockedCSC design; got "
                 f"{type(prob.A).__name__} (use data.sparse.BlockedCSC."
                 "from_dense or a layout='bcsc' generator)")
+        from repro.kernels.shotgun_sparse import require_sparse_backend
+        require_sparse_backend()
         A = pad_feature_blocks(prob.A, nshards)
         nblk_local = A.nblk // nshards
         if K > nblk_local:
@@ -446,7 +446,7 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
                 f"(nblk={A.nblk}, shards={nshards})")
         y, mask = prob.y, jnp.ones(prob.n, jnp.float32)
         eng = make_engine(engine, loss=prob.loss, K=K, block=A.block,
-                          interpret=interpret, newton=newton)
+                          newton=newton)
     elif isinstance(prob.A, BlockedCSC):
         raise ValueError(
             f"engine={engine!r} needs a dense design; BlockedCSC problems "
@@ -458,7 +458,7 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
                           newton=newton)
     else:
         from repro.kernels import ops
-        from repro.kernels.shotgun_block import BLOCK, auto_tile_n
+        from repro.kernels.shotgun_block import BLOCK
         A, y, mask = ops.pad_problem(prob.A, prob.y)
         A = pad_features(A, nshards * BLOCK)     # d_local must tile by 128
         d_local = A.shape[1] // nshards
@@ -467,11 +467,9 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
             raise ValueError(
                 f"K={K} blocks > {nblk_local} local blocks "
                 f"(d_local={d_local}, block={BLOCK})")
-        if tile_n is None:
-            tile_n = auto_tile_n(A.shape[0], BLOCK, d=d_local)
         mask = mask.astype(jnp.float32)
         eng = make_engine(engine, loss=prob.loss, K=K, block=BLOCK,
-                          tile_n=tile_n, interpret=interpret, newton=newton)
+                          tile_n=tile_n, newton=newton)
 
     d_full = A.d_pad if isinstance(A, BlockedCSC) else A.shape[1]
     x0 = (jnp.zeros(d_full, jnp.float32) if x0 is None

@@ -42,7 +42,7 @@ def batched_fused_shotgun_rounds(A, z, x, blk_idx, lam, beta, y, mask,
                                  k_eff, guard_f, loss: str = "lasso",
                                  block: int = BLOCK,
                                  tile_n: int | None = None,
-                                 interpret: bool = False,
+                                 interpret: bool | None = None,
                                  shared_design: bool = False):
     """R fused dense rounds on S stacked slots in ONE launch.
 
@@ -69,7 +69,7 @@ def batched_fused_shotgun_rounds(A, z, x, blk_idx, lam, beta, y, mask,
 def batched_fused_sparse_shotgun_rounds(rows, vals, z, x, blk_idx, lam,
                                         beta, y, k_eff, guard_f,
                                         loss: str = "lasso",
-                                        interpret: bool = False,
+                                        interpret: bool | None = None,
                                         shared_design: bool = False):
     """R fused sparse rounds on S stacked slots in ONE launch.
 

@@ -12,8 +12,10 @@
                           Both draw identical block indices from the same
                           key, so their traces coincide.
 
-On CPU (this container) pass ``interpret=True``; on TPU the same code path
-compiles to Mosaic.  ``ref.py`` holds the pure-jnp oracles used by the tests.
+The kernels run in the Pallas interpreter on the CPU backend and compile to
+Mosaic on a TPU (``shotgun_block.interpret_mode`` decides; no solver takes
+an ``interpret`` flag).  ``ref.py`` holds the pure-jnp oracles used by the
+tests.
 
 ``block_shotgun_solve`` also accepts ``BlockedCSC`` problems (DESIGN §8):
 the round scan then runs the nnz-tile kernels from ``shotgun_sparse.py``,
@@ -37,12 +39,13 @@ from repro.core.objectives import Problem
 from repro.core.shotgun import Result, Trace
 from repro.core.spec import SolverSpec, reject_legacy_kwargs
 from repro.data.sparse import BlockedCSC, bcsc_matvec
-from repro.kernels.shotgun_block import (BLOCK, TILE_N, auto_tile_n,
+from repro.kernels.shotgun_block import (BLOCK, TILE_N,
                                          fused_shotgun_rounds,
                                          gather_block_matvec, resolve_loss,
                                          scatter_block_update)
 from repro.kernels.shotgun_sparse import (block_delta,
                                           fused_sparse_shotgun_rounds,
+                                          require_sparse_backend,
                                           sparse_gather_block_matvec,
                                           sparse_scatter_block_update)
 
@@ -61,16 +64,16 @@ def pad_problem(A, y, block=BLOCK, tile_n=TILE_N):
     return A, y, mask
 
 
-@functools.partial(jax.jit, static_argnames=("block", "loss", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "loss"))
 def block_shotgun_round(A, z, x, blk_idx, lam, beta, y, mask,
                         loss: str = obj.LASSO, block: int = BLOCK,
-                        interpret: bool = False, k_eff=None):
+                        k_eff=None):
     """One Block-Shotgun round.  Returns (x_new, z_new, delta).
 
     ``k_eff`` (dynamic) masks blocks at or past the backoff point
     (DESIGN §9); None applies all K drawn blocks, bit-exactly."""
     r = obj.residual_like(z, y, loss) * mask
-    g = gather_block_matvec(A, r, blk_idx, block=block, interpret=interpret)
+    g = gather_block_matvec(A, r, blk_idx, block=block)
     d = x.shape[0]
     xb = x.reshape(d // block, block)
     x_sel = jnp.take(xb, blk_idx, axis=0)
@@ -78,21 +81,20 @@ def block_shotgun_round(A, z, x, blk_idx, lam, beta, y, mask,
     delta = x_new_sel - x_sel
     if k_eff is not None:
         delta = delta * health.live_mask(blk_idx.shape[0], k_eff)[:, None]
-    z_new = scatter_block_update(A, z, blk_idx, delta, block=block,
-                                 interpret=interpret)
+    z_new = scatter_block_update(A, z, blk_idx, delta, block=block)
     xb = xb.at[blk_idx].add(delta)
     return xb.reshape(d), z_new, delta
 
 
 @functools.partial(jax.jit, static_argnames=("K", "rounds", "block", "loss",
-                                             "interpret", "guard"))
-def _solve(A, y, mask, lam, beta, key, K, rounds, block, loss, interpret,
-           x0=None, guard=None):
+                                             "guard"))
+def _solve(A, y, mask, lam, beta, key, K, rounds, block, loss, x0=None,
+           guard=None):
     n, d = A.shape
     nblk = d // block
     x0 = jnp.zeros(d, A.dtype) if x0 is None else x0.astype(A.dtype)
     # warm-start margin: accumulate in f32 even when A is stored bf16
-    z0 = A.astype(jnp.float32) @ x0.astype(jnp.float32)
+    z0 = obj.matvec(A.astype(jnp.float32), x0.astype(jnp.float32))
 
     def objective(z, x):
         return obj.masked_data_loss(z, y, mask, loss) + lam * jnp.sum(jnp.abs(x))
@@ -104,8 +106,7 @@ def _solve(A, y, mask, lam, beta, key, K, rounds, block, loss, interpret,
             x, z = carry
             blk_idx = jax.random.choice(key_t, nblk, (K,), replace=False)
             x, z, _ = block_shotgun_round(A, z, x, blk_idx, lam, beta, y,
-                                          mask, loss=loss, block=block,
-                                          interpret=interpret)
+                                          mask, loss=loss, block=block)
             return (x, z), (objective(z, x), jnp.sum(x != 0))
 
         (x, z), (fs, nnzs) = jax.lax.scan(round_fn, (x0, z0), keys)
@@ -119,9 +120,7 @@ def _solve(A, y, mask, lam, beta, key, K, rounds, block, loss, interpret,
         blk_idx = jax.random.choice(key_t, nblk, (K,), replace=False)
         x_new, z_new, _ = block_shotgun_round(A, z, x, blk_idx, lam, beta,
                                               y, mask, loss=loss,
-                                              block=block,
-                                              interpret=interpret,
-                                              k_eff=gs.p_eff)
+                                              block=block, k_eff=gs.p_eff)
         x, z, f, gs, _ = health.apply_sentinel(
             gs, x_new, z_new, objective(z_new, x_new),
             factor=guard.factor, p_floor=p_floor)
@@ -134,10 +133,9 @@ def _solve(A, y, mask, lam, beta, key, K, rounds, block, loss, interpret,
 
 
 @functools.partial(jax.jit, static_argnames=("K", "rounds", "R", "block",
-                                             "tile_n", "loss", "interpret",
-                                             "guard"))
+                                             "tile_n", "loss", "guard"))
 def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
-                 loss, interpret, x0=None, guard=None):
+                 loss, x0=None, guard=None):
     """Scan over launches: one fused pallas_call per R rounds.
 
     Draws the same per-round keys/indices as ``_solve`` (jax.random.split of
@@ -159,7 +157,7 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
           else x0.astype(jnp.float32))
     # warm-start margin in f32 even for bf16-stored A (cast before the
     # matmul, not after — the accumulation itself is what must stay f32)
-    z0 = A.astype(jnp.float32) @ x0
+    z0 = obj.matvec(A.astype(jnp.float32), x0)
     draw = functools.partial(jax.random.choice, a=nblk, shape=(K,),
                              replace=False)
     keys = jax.random.split(key, rounds).reshape(L, R, -1)
@@ -170,7 +168,7 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
             idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
             x, z, fs, nnzs, _ = fused_shotgun_rounds(
                 A, z, x, idx, lam, beta, y, mask, loss=loss, block=block,
-                tile_n=tile_n, interpret=interpret)
+                tile_n=tile_n)
             return (x, z), (fs, nnzs)
 
         (x, z), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0), keys)
@@ -186,7 +184,7 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
         idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
         x_new, z_new, fs, nnzs, h = fused_shotgun_rounds(
             A, z, x, idx, lam, beta, y, mask, loss=loss, block=block,
-            tile_n=tile_n, interpret=interpret, k_eff=gs.p_eff,
+            tile_n=tile_n, k_eff=gs.p_eff,
             guard_f=health.guard_threshold(gs.f_good, guard.factor))
         x, z, f_rep, gs, bad = health.apply_sentinel(
             gs, x_new, z_new, fs[-1], factor=guard.factor, p_floor=p_floor,
@@ -207,33 +205,30 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
                   status=health.status_from_trace(fs, gs.backoffs))
 
 
-@functools.partial(jax.jit, static_argnames=("loss", "interpret"))
+@functools.partial(jax.jit, static_argnames=("loss",))
 def sparse_block_shotgun_round(rows, vals, z, x, blk_idx, lam, beta, y,
-                               loss: str = obj.LASSO,
-                               interpret: bool = False, k_eff=None):
+                               loss: str = obj.LASSO, k_eff=None):
     """One Block-Shotgun round on BlockedCSC nnz tiles (the sparse
     counterpart of ``block_shotgun_round``; no mask — the sparse path never
     pads samples).  ``k_eff`` masks blocks past the backoff point
     (DESIGN §9).  Returns (x_new, z_new, delta)."""
     nblk, tile, block = rows.shape
     r = obj.residual_like(z, y, loss)
-    g = sparse_gather_block_matvec(rows, vals, r, blk_idx,
-                                   interpret=interpret)
+    g = sparse_gather_block_matvec(rows, vals, r, blk_idx)
     xb = x.reshape(nblk, block)
     x_sel = jnp.take(xb, blk_idx, axis=0)
     delta = block_delta(x_sel, g, lam, beta)
     if k_eff is not None:
         delta = delta * health.live_mask(blk_idx.shape[0], k_eff)[:, None]
-    z_new = sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
-                                        interpret=interpret)
+    z_new = sparse_scatter_block_update(rows, vals, z, blk_idx, delta)
     xb = xb.at[blk_idx].add(delta)
     return xb.reshape(-1), z_new, delta
 
 
 @functools.partial(jax.jit, static_argnames=("K", "rounds", "loss",
-                                             "interpret", "guard"))
-def _sparse_solve(rows, vals, y, lam, beta, key, K, rounds, loss, interpret,
-                  x0=None, guard=None):
+                                             "guard"))
+def _sparse_solve(rows, vals, y, lam, beta, key, K, rounds, loss, x0=None,
+                  guard=None):
     """Round scan over the sparse Pallas kernels (BlockedCSC tiles).
 
     Draws the same block indices as the dense ``_solve`` for the same key,
@@ -258,8 +253,7 @@ def _sparse_solve(rows, vals, y, lam, beta, key, K, rounds, loss, interpret,
             blk_idx = jax.random.choice(key_t, nblk, (K,),
                                         replace=False).astype(jnp.int32)
             x, z, _ = sparse_block_shotgun_round(rows, vals, z, x, blk_idx,
-                                                 lam, beta, y, loss=loss,
-                                                 interpret=interpret)
+                                                 lam, beta, y, loss=loss)
             return (x, z), (objective(z, x), jnp.sum(x != 0))
 
         (x, z), (fs, nnzs) = jax.lax.scan(round_fn, (x0, z0), keys)
@@ -274,7 +268,7 @@ def _sparse_solve(rows, vals, y, lam, beta, key, K, rounds, loss, interpret,
                                     replace=False).astype(jnp.int32)
         x_new, z_new, _ = sparse_block_shotgun_round(
             rows, vals, z, x, blk_idx, lam, beta, y, loss=loss,
-            interpret=interpret, k_eff=gs.p_eff)
+            k_eff=gs.p_eff)
         x, z, f, gs, _ = health.apply_sentinel(
             gs, x_new, z_new, objective(z_new, x_new),
             factor=guard.factor, p_floor=p_floor)
@@ -287,9 +281,9 @@ def _sparse_solve(rows, vals, y, lam, beta, key, K, rounds, loss, interpret,
 
 
 @functools.partial(jax.jit, static_argnames=("K", "rounds", "R", "loss",
-                                             "interpret", "guard"))
+                                             "guard"))
 def _fused_sparse_solve(rows, vals, y, lam, beta, key, K, rounds, R, loss,
-                        interpret, x0=None, guard=None):
+                        x0=None, guard=None):
     """Scan over launches of the fused sparse kernel: one pallas_call per R
     rounds (DESIGN §8.3).
 
@@ -315,8 +309,7 @@ def _fused_sparse_solve(rows, vals, y, lam, beta, key, K, rounds, R, loss,
             x, z = carry
             idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
             x, z, fs, nnzs, _ = fused_sparse_shotgun_rounds(
-                rows, vals, z, x, idx, lam, beta, y, loss=loss,
-                interpret=interpret)
+                rows, vals, z, x, idx, lam, beta, y, loss=loss)
             return (x, z), (fs, nnzs)
 
         (x, z), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0), keys)
@@ -332,7 +325,7 @@ def _fused_sparse_solve(rows, vals, y, lam, beta, key, K, rounds, R, loss,
         idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
         x_new, z_new, fs, nnzs, h = fused_sparse_shotgun_rounds(
             rows, vals, z, x, idx, lam, beta, y, loss=loss,
-            interpret=interpret, k_eff=gs.p_eff,
+            k_eff=gs.p_eff,
             guard_f=health.guard_threshold(gs.f_good, guard.factor))
         x, z, f_rep, gs, bad = health.apply_sentinel(
             gs, x_new, z_new, fs[-1], factor=guard.factor, p_floor=p_floor,
@@ -353,7 +346,7 @@ def _fused_sparse_solve(rows, vals, y, lam, beta, key, K, rounds, R, loss,
 
 def block_shotgun_solve(prob: Problem, key: jax.Array,
                         K: int | None = None, rounds: int | None = None,
-                        block: int = BLOCK, interpret: bool = True,
+                        block: int = BLOCK,
                         fused: bool = False, rounds_per_launch: int = 8,
                         tile_n: int | None = None,
                         x0: jax.Array | None = None,
@@ -380,7 +373,9 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
 
     A ``BlockedCSC`` problem routes to the sparse kernels
     (``kernels/shotgun_sparse.py``): same block draws for the same key, so
-    the trajectory matches the dense path on the densified design.
+    the trajectory matches the dense path on the densified design.  They
+    run only on the CPU backend; elsewhere this raises
+    ``NotImplementedError`` (``require_sparse_backend``).
     ``fused=True`` runs the fused multi-round sparse kernel (DESIGN §8.3)
     — one launch per ``rounds_per_launch`` rounds with the margin resident
     in VMEM and nnz tiles as the only per-round A traffic; ``tile_n`` is
@@ -414,6 +409,7 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
                 "tile is computed inside the fused kernel body")
         loss = resolve_loss(prob.loss)._replace(newton=True)
     if isinstance(prob.A, BlockedCSC):
+        require_sparse_backend()
         if block != prob.A.block:
             raise ValueError(f"block={block} != BlockedCSC block "
                              f"{prob.A.block}")
@@ -426,12 +422,12 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
                     f"rounds_per_launch={rounds_per_launch}")
             res = _fused_sparse_solve(prob.A.rows, prob.A.vals, prob.y,
                                       prob.lam, prob.beta, key, K, rounds,
-                                      rounds_per_launch, loss,
-                                      interpret, x0=x0, guard=guard)
+                                      rounds_per_launch, loss, x0=x0,
+                                      guard=guard)
         else:
             res = _sparse_solve(prob.A.rows, prob.A.vals, prob.y, prob.lam,
-                                prob.beta, key, K, rounds, loss,
-                                interpret, x0=x0, guard=guard)
+                                prob.beta, key, K, rounds, loss, x0=x0,
+                                guard=guard)
         return Result(x=res.x[: prob.d], z=res.z, trace=res.trace,
                       status=res.status)
 
@@ -443,15 +439,12 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
             raise ValueError(
                 f"rounds={rounds} not divisible by "
                 f"rounds_per_launch={rounds_per_launch}")
-        if tile_n is None:
-            tile_n = auto_tile_n(A.shape[0], block, d=A.shape[1])
         res = _fused_solve(A, y, mask.astype(jnp.float32), prob.lam,
                            prob.beta, key, K, rounds, rounds_per_launch,
-                           block, tile_n, loss, interpret, x0=x0,
-                           guard=guard)
+                           block, tile_n, loss, x0=x0, guard=guard)
     else:
         res = _solve(A, y, mask, prob.lam, prob.beta, key, K, rounds, block,
-                     loss, interpret, x0=x0, guard=guard)
+                     loss, x0=x0, guard=guard)
     return Result(x=res.x[: prob.d], z=res.z[: prob.n], trace=res.trace,
                   status=res.status)
 
@@ -461,7 +454,6 @@ def fused_block_shotgun_solve(prob: Problem, key: jax.Array,
                               rounds: int | None = None,
                               rounds_per_launch: int = 8,
                               block: int = BLOCK, tile_n: int | None = None,
-                              interpret: bool = True,
                               x0: jax.Array | None = None,
                               guard: GuardConfig | None = None,
                               spec: SolverSpec | None = None) -> Result:
@@ -476,10 +468,8 @@ def fused_block_shotgun_solve(prob: Problem, key: jax.Array,
         if not spec.fused:
             spec = dataclasses.replace(spec, fused=True)
         return block_shotgun_solve(prob, key, block=block,
-                                   interpret=interpret,
                                    rounds_per_launch=rounds_per_launch,
                                    tile_n=tile_n, x0=x0, spec=spec)
-    return block_shotgun_solve(prob, key, K, rounds, block=block,
-                               interpret=interpret, fused=True,
+    return block_shotgun_solve(prob, key, K, rounds, block=block, fused=True,
                                rounds_per_launch=rounds_per_launch,
                                tile_n=tile_n, x0=x0, guard=guard)

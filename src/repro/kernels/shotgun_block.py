@@ -28,10 +28,10 @@ and the fused multi-round kernel (DESIGN §4.2):
   halving A traffic vs. the two-kernel round; otherwise it runs the same
   gather/scatter phases as above but without the z/r/g HBM round trips.
 
-Block size B = 128 (MXU/lane width); TILE_N default 512 keeps the f32
-working set (512·128·4B · 2 operands · 2 buffers ≈ 1 MB) comfortably in
-the ~16 MB VMEM budget with double buffering.  VMEM budget math for the
-fused kernel is in DESIGN §4.3.
+Block size B = 128 (MXU/lane width); the two-phase sample tile is TILE_N
+= 512.  Every kernel compiles with ``vmem_limit_bytes = VMEM_BUDGET``; the
+VMEM model (each (n, 1) vector at 512 B per sample in (8, 128) tiles) is
+in ``fused_vmem_bytes`` and DESIGN §4.3.
 """
 from __future__ import annotations
 
@@ -45,6 +45,42 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 128        # coordinate block width (MXU dimension)
 TILE_N = 512       # sample-dimension tile
+
+# Scoped-VMEM limit every kernel here is compiled with (``vmem_limit_bytes``)
+# and the ceiling ``fused_vmem_bytes`` / ``fused_sparse_vmem_bytes`` refuse
+# shapes against.  A v5e core has 128 MiB of VMEM and the compiler's default
+# scoped limit is 16 MiB.  The kernel's scoped area and the (n, 1) operands
+# XLA places in VMEM beside it must share the 128 MiB, so the model counts
+# both and 8 MiB stay free for the XLA ops around the kernel.  Rehearsed on a
+# described v5e: at the largest n ``auto_tile_n`` admits under this budget
+# (d=2048, K=4: lasso 40448, logistic Newton 24064, their Δz engine
+# variants 30208 / 22016) every fused variant compiles.
+VMEM_BUDGET = 120 * 2 ** 20
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in the interpreter: exactly when the
+    default backend is the CPU.  Every solver, engine and service decides
+    through here; only the raw ``pallas_call`` wrappers take an explicit
+    ``interpret`` (None defers to this), so a TPU never interprets."""
+    return jax.default_backend() == "cpu"
+
+
+def _call_params(interpret: bool | None) -> dict:
+    """``pallas_call`` keyword arguments shared by every kernel: the
+    backend-chosen interpret flag and the ``VMEM_BUDGET`` scoped limit."""
+    return dict(
+        interpret=interpret_mode() if interpret is None else interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET))
+
+
+def _f32_dot(a, b, contract):
+    """MXU contraction over the ``contract`` dims at full f32 precision.
+    Without ``HIGHEST`` the TPU may round f32 operands to bf16; with it the
+    chip computes what the interpreter and the ``ref.py`` oracles do."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _check_divisible(n: int, d: int, block: int, tile_n: int) -> None:
@@ -61,26 +97,29 @@ def _check_divisible(n: int, d: int, block: int, tile_n: int) -> None:
 # ---------------------------------------------------------------------------
 
 def _gather_matvec_kernel(idx_ref, a_ref, r_ref, g_ref):
-    # grid = (K, T); T (sample tiles) is the fast axis -> accumulate into g[k].
+    # grid = (K, T); T (sample tiles) is the fast axis -> accumulate into
+    # row k of the VMEM-resident (K, B) output (a (1, B) block is not a
+    # legal Mosaic tile, so the whole array stays put and flushes once).
+    k = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
+        g_ref[pl.ds(k, 1), :] = jnp.zeros((1, g_ref.shape[1]), jnp.float32)
 
     a = a_ref[...]                       # (TILE_N, B)
     r = r_ref[...]                       # (TILE_N, 1)
     # MXU: (B, TILE_N) @ (TILE_N, 1) with f32 accumulation
-    contrib = jax.lax.dot_general(
-        a, r, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (B, 1)
-    g_ref[...] += contrib.reshape(1, -1)
+    contrib = _f32_dot(a, r, ((0,), (0,)))          # (B, 1)
+    g_ref[pl.ds(k, 1), :] += contrib.reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "tile_n", "interpret"))
 def gather_block_matvec(A, r, blk_idx, block: int = BLOCK,
-                        tile_n: int = TILE_N, interpret: bool = False):
-    """g (K, block) = per-selected-block column gradients A_Bᵀ r."""
+                        tile_n: int = TILE_N, interpret: bool | None = None):
+    """g (K, block) = per-selected-block column gradients A_Bᵀ r.
+
+    ``interpret=None`` lets the backend decide (``interpret_mode``)."""
     n, d = A.shape
     _check_divisible(n, d, block, tile_n)
     K = blk_idx.shape[0]
@@ -93,13 +132,13 @@ def gather_block_matvec(A, r, blk_idx, block: int = BLOCK,
             pl.BlockSpec((tile_n, block), lambda k, t, idx: (t, idx[k])),
             pl.BlockSpec((tile_n, 1), lambda k, t, idx: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda k, t, idx: (k, 0)),
+        out_specs=pl.BlockSpec((K, block), lambda k, t, idx: (0, 0)),
     )
     return pl.pallas_call(
         _gather_matvec_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
-        interpret=interpret,
+        **_call_params(interpret),
     )(blk_idx, A, r.reshape(n, 1))
 
 
@@ -116,17 +155,17 @@ def _scatter_update_kernel(idx_ref, a_ref, d_ref, z_ref, out_ref):
         out_ref[...] = z_ref[...].astype(jnp.float32)
 
     a = a_ref[...]                       # (TILE_N, B)
-    dlt = d_ref[...]                     # (1, B)
-    contrib = jax.lax.dot_general(
-        a, dlt, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (TILE_N, 1)
+    dlt = d_ref[pl.ds(k, 1), :]          # (1, B) row of the resident (K, B)
+    contrib = _f32_dot(a, dlt, ((1,), (1,)))          # (TILE_N, 1)
     out_ref[...] += contrib
 
 
 @functools.partial(jax.jit, static_argnames=("block", "tile_n", "interpret"))
 def scatter_block_update(A, z, blk_idx, delta, block: int = BLOCK,
-                         tile_n: int = TILE_N, interpret: bool = False):
-    """z_new = z + Σ_k A[:, blk_k] δ_k  — f32 accumulation, z.dtype out."""
+                         tile_n: int = TILE_N, interpret: bool | None = None):
+    """z_new = z + Σ_k A[:, blk_k] δ_k  — f32 accumulation, z.dtype out.
+
+    ``interpret=None`` lets the backend decide (``interpret_mode``)."""
     n, d = A.shape
     _check_divisible(n, d, block, tile_n)
     K = blk_idx.shape[0]
@@ -137,7 +176,7 @@ def scatter_block_update(A, z, blk_idx, delta, block: int = BLOCK,
         grid=(T, K),
         in_specs=[
             pl.BlockSpec((tile_n, block), lambda t, k, idx: (t, idx[k])),
-            pl.BlockSpec((1, block), lambda t, k, idx: (k, 0)),
+            pl.BlockSpec((K, block), lambda t, k, idx: (0, 0)),
             pl.BlockSpec((tile_n, 1), lambda t, k, idx: (t, 0)),
         ],
         out_specs=pl.BlockSpec((tile_n, 1), lambda t, k, idx: (t, 0)),
@@ -146,7 +185,7 @@ def scatter_block_update(A, z, blk_idx, delta, block: int = BLOCK,
         _scatter_update_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        interpret=interpret,
+        **_call_params(interpret),
     )(blk_idx, A, delta.astype(A.dtype), z.reshape(n, 1))
     return out.reshape(n).astype(z.dtype)
 
@@ -284,7 +323,7 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
     ``k_eff`` (blocks past it get their delta masked to zero — the in-kernel
     half of adaptive-P backoff; at k_eff == K the mask multiplies by exactly
     1.0) and a guard objective level; the kernel max-accumulates a (1, 1)
-    health output that goes 1.0 the first round the objective crosses the
+    SMEM health output that goes 1.0 the first round the objective crosses the
     guard or goes non-finite (engine variant: the margin view goes
     non-finite), so the caller detects an in-launch divergence from one
     scalar instead of scanning the trace.
@@ -355,17 +394,13 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
                                                        jnp.float32)
 
             rt = r_s[pl.ds(t_id * tile_n, tile_n), :]   # (tile_n, 1)
-            contrib = jax.lax.dot_general(
-                a, rt, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # (block, 1)
+            contrib = _f32_dot(a, rt, ((0,), (0,)))      # (block, 1)
             g_s[pl.ds(k_id, 1), :] += contrib.reshape(1, block)
             if newton:
                 # h_B += (a∘a)ᵀ w from the tile already in VMEM: the Newton
                 # curvature costs one extra dot_general, no extra A bytes.
                 wt = w_s[pl.ds(t_id * tile_n, tile_n), :]
-                hc = jax.lax.dot_general(
-                    a * a, wt, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # (block, 1)
+                hc = _f32_dot(a * a, wt, ((0,), (0,)))  # (block, 1)
                 c_s[pl.ds(k_id, 1), :] += hc.reshape(1, block)
 
             @pl.when(t_id == T - 1)
@@ -392,9 +427,7 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
         @pl.when(scatter_on)
         def _scatter_phase():
             dlt = d_s[pl.ds(k_id, 1), :]                 # (1, block)
-            contrib = jax.lax.dot_general(
-                a, dlt, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # (tile_n, 1)
+            contrib = _f32_dot(a, dlt, ((1,), (1,)))      # (tile_n, 1)
             z_s[pl.ds(t_id * tile_n, tile_n), :] += contrib
             if emit_dz:
                 dz_s[pl.ds(t_id * tile_n, tile_n), :] += contrib
@@ -420,11 +453,12 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
                 else:
                     f = loss.objective(z_s[...], y_ref[...], m_ref[...],
                                        x_s[...], lam)
-                    f_ref[0, 0] = f
+                    f_ref[r_id, 0] = f
                     bad = ~jnp.isfinite(f) | (f > guard)
                     h_ref[0, 0] = jnp.maximum(
                         h_ref[0, 0], jnp.where(bad, 1.0, 0.0))
-                    nnz_ref[0, 0] = jnp.sum((x_s[...] != 0).astype(jnp.int32))
+                    nnz_ref[r_id, 0] = jnp.sum(
+                        (x_s[...] != 0).astype(jnp.int32))
                     zo_ref[...] = z_s[...]
                     xo_ref[...] = x_s[...]
 
@@ -441,9 +475,14 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
     loss = resolve_loss(loss)
     n, d = A.shape
     R, K = blk_idx.shape
+    a_bytes = A.dtype.itemsize
     if tile_n is None:
-        tile_n = auto_tile_n(n, block, d=d)
+        tile_n = auto_tile_n(n, block, d=d, K=K, loss=loss, emit_dz=emit_dz,
+                             a_bytes=a_bytes)
     _check_divisible(n, d, block, tile_n)
+    check_vmem(fused_vmem_bytes(n, d, K, block, tile_n, emit_dz, a_bytes,
+                                loss=loss),
+               f"fused kernel (n={n}, d={d}, K={K}, tile_n={tile_n})")
     nblk = d // block
     T = n // tile_n
     single = T == 1
@@ -463,18 +502,20 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
         grid = (R, K)
         a_map = lambda r, k, idx, scal: (0, idx[r, k])
         const = lambda r, k, idx, scal: (0, 0)
-        f_map = lambda r, k, idx, scal: (r, 0)
     else:
         grid = (R, K, 2, T)
         a_map = lambda r, k, p, t, idx, scal: (t, idx[r, k])
         const = lambda r, k, p, t, idx, scal: (0, 0)
-        f_map = lambda r, k, p, t, idx, scal: (r, 0)
 
+    # The per-round traces and the health flag are scalar stores, which
+    # Mosaic only takes in SMEM: whole-array SMEM outputs, written at
+    # [r_id, 0] / [0, 0] and flushed once at the end of the launch.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     if emit_dz:
         out_specs = [
             pl.BlockSpec((n, 1), const),            # Δz
             pl.BlockSpec((nblk, block), const),     # x
-            pl.BlockSpec((1, 1), const),            # health scalar
+            smem,                                   # health scalar
         ]
         out_shape = [
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
@@ -486,9 +527,9 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
         out_specs = [
             pl.BlockSpec((n, 1), const),            # z
             pl.BlockSpec((nblk, block), const),     # x
-            pl.BlockSpec((1, 1), f_map),            # f trace
-            pl.BlockSpec((1, 1), f_map),            # nnz trace
-            pl.BlockSpec((1, 1), const),            # health scalar
+            smem,                                   # f trace
+            smem,                                   # nnz trace
+            smem,                                   # health scalar
         ]
         out_shape = [
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
@@ -526,7 +567,7 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
         _make_fused_kernel(loss, R, K, T, block, tile_n, emit_dz=emit_dz),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        **_call_params(interpret),
     )(idx, scal, A, z0, x0, y2, m2)
 
 
@@ -534,7 +575,8 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
                    static_argnames=("loss", "block", "tile_n", "interpret"))
 def fused_shotgun_rounds(A, z, x, blk_idx, lam, beta, y, mask,
                          loss: str | Loss = LASSO, block: int = BLOCK,
-                         tile_n: int | None = None, interpret: bool = False,
+                         tile_n: int | None = None,
+                         interpret: bool | None = None,
                          k_eff=None, guard_f=None):
     """R Block-Shotgun rounds in ONE pallas_call.
 
@@ -572,7 +614,7 @@ def fused_shotgun_rounds(A, z, x, blk_idx, lam, beta, y, mask,
 def fused_shotgun_delta_rounds(A, z, x, blk_idx, lam, beta, y, mask,
                                loss: str | Loss = LASSO, block: int = BLOCK,
                                tile_n: int | None = None,
-                               interpret: bool = False, k_eff=None):
+                               interpret: bool | None = None, k_eff=None):
     """Shard-local fused engine kernel: R rounds against a margin *snapshot*.
 
     Same dataflow as ``fused_shotgun_rounds`` — z/r/x/g/δ resident in VMEM,
@@ -597,31 +639,42 @@ def fused_shotgun_delta_rounds(A, z, x, blk_idx, lam, beta, y, mask,
     return x_new.reshape(d), dz.reshape(n), h.reshape(())
 
 
-# Per-core VMEM ceiling every fused config must clear (shotgun-lint SL101
-# and the benchmark drivers both check against this; ``auto_tile_n`` sizes
-# tiles against a lower 12 MiB default to leave compiler slack inside it).
-VMEM_BUDGET = 16 * 2 ** 20
+def vec_vmem_bytes(rows: int, cols: int = 1) -> int:
+    """VMEM bytes of a (rows, cols) f32 buffer as Mosaic lays it out: in
+    (8, 128) tiles, so an (n, 1) vector costs 512 B per sample, not 4."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def check_vmem(need: int, what: str) -> None:
+    """Refuse a kernel shape whose modelled VMEM exceeds ``VMEM_BUDGET``
+    before the compiler does, naming the limit."""
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"{what} needs {need} B of VMEM > VMEM_BUDGET = {VMEM_BUDGET} B "
+            f"(kernels/shotgun_block.py): shrink n, d, K or the tile, or "
+            f"shard the design")
 
 
 def fused_vmem_bytes(n: int, d: int, K: int, block: int = BLOCK,
                      tile_n: int | None = None, emit_dz: bool = False,
                      a_bytes: int = 4, slots: int = 1,
                      loss: str | Loss = "lasso") -> int:
-    """f32 VMEM resident set of the dense fused kernel — the twin of
+    """VMEM the dense fused kernel needs — the twin of
     ``shotgun_sparse.fused_sparse_vmem_bytes`` for ``_fused_call``'s
-    buffers: the z0/y/mask in-vectors, z/r scratch (+ Δz scratch and out
-    for the ``emit_dz`` engine variant, replacing the z out), the three
-    full-d x buffers (x0/scratch/out), the two (K, block) g/δ scratches,
-    and the double-buffered streamed (tile_n, block) A tile.  ``a_bytes``
-    is the stored dtype of A (4 = f32, 2 = bf16 — accumulation stays f32
-    either way, so only the streamed tile shrinks).  R never enters: only
-    the (R, K) scalar-prefetch index matrix and the (R, 1) trace outputs
-    scale with R, both negligible.
+    buffers, each priced at its (8, 128)-tiled layout (``vec_vmem_bytes``).
 
-    ``loss`` (string or ``Loss`` spec) prices the logistic kernel twins:
-    a Newton spec adds the (n, 1) curvature-weight scratch and the
-    (K, block) per-block curvature accumulator (DESIGN §12); the
-    gradient-form logistic kernel has the same resident set as lasso.
+    Every (n, 1) vector counts 512 B per sample: the z0/y/mask in-vectors
+    and the z (or Δz) out-vector, which XLA places in VMEM beside the
+    kernel's scoped area; the z/r scratch (+ Δz scratch for the ``emit_dz``
+    engine variant, + the curvature-weight scratch for a Newton spec); and
+    the (n, 1) temporaries the compiler allocates for the loss math — three
+    for the logistic tile, one for the engine variant's finiteness check.
+    Then the three full-d x buffers (x0/scratch/out), the (K, block) g/δ
+    (+ Newton h) scratches, and the double-buffered streamed (tile_n,
+    block) A tile.  ``a_bytes`` is the stored dtype of A (4 = f32, 2 =
+    bf16).  R never enters: the (R, K) index matrix and the (R, 1) traces
+    live in SMEM.  Calibrated against the v5e compiler's own scoped-VMEM
+    reports; it over-counts by at most two n-vectors.
 
     ``slots`` is the batched-launch multiplier (DESIGN §11): the vmapped
     entry points (``kernels/batched.py``) stack S independent problems on
@@ -629,31 +682,37 @@ def fused_vmem_bytes(n: int, d: int, K: int, block: int = BLOCK,
     slots × the per-problem set — conservative on hardware, where the
     batch axis is the outermost (sequential) grid dimension, and exact in
     interpret mode, where vmap physically batches every buffer."""
+    spec = resolve_loss(loss)
     if tile_n is None:
-        tile_n = auto_tile_n(n, block, d=d)
-    newton = resolve_loss(loss).newton
-    # z0/y/mask in + z/r scratch + z-out, or +dz scratch/out - z-out;
-    # Newton adds the (n, 1) curvature-weight scratch
-    vecs = ((7 if emit_dz else 6) + (1 if newton else 0)) * n * 4
-    xbuf = 3 * d * 4                               # x0, x scratch, x out
-    # g, delta (+ Newton per-block curvature accumulator)
-    kbuf = (3 if newton else 2) * K * block * 4
+        tile_n = auto_tile_n(n, block, d=d, K=K, loss=spec, emit_dz=emit_dz,
+                             a_bytes=a_bytes)
+    # z0/y/mask in, z-or-Δz out, z/r scratch (+ Δz, + Newton w scratch)
+    nvec = 6 + emit_dz + spec.newton
+    nvec += 3 if spec.name == LOGISTIC else int(emit_dz)   # temporaries
+    vecs = nvec * vec_vmem_bytes(n)
+    xbuf = 3 * vec_vmem_bytes(d // block, block)   # x0, x scratch, x out
+    kbuf = (3 if spec.newton else 2) * vec_vmem_bytes(K, block)
     tiles = 2 * tile_n * block * a_bytes           # double-buffered A tile
     return slots * (vecs + xbuf + kbuf + tiles)
 
 
-def auto_tile_n(n: int, block: int = BLOCK, d: int = 0,
-                vmem_budget: int = 12 * 2 ** 20):
-    """Largest sample tile that keeps the fused kernel's whole VMEM resident
-    set inside ``vmem_budget`` (leaving ~4 MB of the ~16 MB/core for
-    compiler slack): the double-buffered f32 A tile plus the z/r scratch and
-    y/mask/z0/zo vectors (6·n·4 B) and the three full-d x buffers
-    (x0/x_s/xo, 3·d·4 B).  Prefers tile_n == n (single-phase fused kernel,
-    one A fetch per block per round) whenever it fits.  See DESIGN §4.3."""
-    resident = 6 * n * 4 + 3 * d * 4
-    if 2 * n * block * 4 + resident <= vmem_budget:
+def auto_tile_n(n: int, block: int = BLOCK, d: int = 0, K: int = 1,
+                loss: str | Loss = "lasso", emit_dz: bool = False,
+                a_bytes: int = 4) -> int:
+    """Largest sample tile whose ``fused_vmem_bytes`` fits ``VMEM_BUDGET``.
+    Prefers tile_n == n (single-phase fused kernel, one A fetch per block
+    per round) whenever it fits, else ``TILE_N`` (two-phase).  Raises
+    ``ValueError`` naming the limit when even that does not fit: the
+    resident (n, 1) vectors and x buffers alone exceed it.  See DESIGN
+    §4.3."""
+    def need(tile):
+        return fused_vmem_bytes(n, d, K, block, tile, emit_dz, a_bytes,
+                                loss=loss)
+
+    if need(n) <= VMEM_BUDGET:
         return n
     tile = max(TILE_N, block)
     while n % tile:            # n is pre-padded to a TILE_N multiple by
         tile //= 2             # ops.pad_problem, so this terminates >= 8
+    check_vmem(need(tile), f"fused kernel (n={n}, d={d}, K={K})")
     return tile

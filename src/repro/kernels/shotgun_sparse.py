@@ -35,10 +35,15 @@ data path with the §4.2 VMEM-residency dataflow:
 Padded tile slots hold (row 0, value 0) so they are additive no-ops in both
 directions.  Value tiles may be stored bf16 (``BlockedCSC.astype``) to halve
 their HBM/wire bytes — every kernel here casts the fetched tile to f32
-before accumulating, exactly like the dense fused kernel's bf16 A storage.  Like the dense kernels these run under ``interpret=True`` on
-this CPU container; the gather/scatter lower to XLA there and to Mosaic's
-dynamic gather / scatter-accumulate on TPU.  The layout is chosen for the
-TPU path: tiles are rectangular (tile × 128), lane-aligned, and selected by
+before accumulating, exactly like the dense fused kernel's bf16 A storage.
+
+These kernels run only in the Pallas interpreter (CPU backend).  Mosaic
+refuses the data-dependent row gather in ``_tile_gather`` ("Only 2D gather
+is supported"), and the scatter-add in ``_tile_scatter`` is the same kind
+of operation, so every BlockedCSC entry point calls
+``require_sparse_backend`` and raises ``NotImplementedError`` on a TPU
+rather than falling back.  The tile layout is still chosen for the TPU
+path: tiles are rectangular (tile × 128), lane-aligned, and selected by
 ``PrefetchScalarGridSpec`` index maps exactly like the dense A blocks.
 """
 from __future__ import annotations
@@ -50,8 +55,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.shotgun_block import (BLOCK, LASSO, Loss, _soft_threshold,
-                                         resolve_loss)
+from repro.kernels.shotgun_block import (BLOCK, LASSO, Loss, _call_params,
+                                         _soft_threshold, interpret_mode,
+                                         resolve_loss, vec_vmem_bytes)
+
+
+def require_sparse_backend() -> None:
+    """Refuse a BlockedCSC kernel solve where the kernels cannot lower.
+
+    Called at entry by every solver, engine and service path that would
+    run these kernels.  Off the CPU backend they would have to compile
+    for Mosaic, which rejects the row gather of ``_tile_gather`` ("Only 2D
+    gather is supported"); there is no interpreter or ``kernels/ref.py``
+    fallback on purpose."""
+    if not interpret_mode():
+        raise NotImplementedError(
+            f"BlockedCSC solves do not run on the {jax.default_backend()} "
+            "backend: Mosaic refuses the data-dependent row gather in "
+            "kernels/shotgun_sparse._tile_gather ('Only 2D gather is "
+            "supported'); densify the design or solve on the CPU backend")
 
 
 # ---------------------------------------------------------------------------
@@ -86,15 +108,17 @@ def block_delta(x_sel, g, lam, beta):
 # ---------------------------------------------------------------------------
 
 def _gather_kernel(idx_ref, rows_ref, vals_ref, r_ref, g_ref):
-    # grid = (K,); one selected column block per step.
-    g_ref[...] = _tile_gather(rows_ref[0],
-                              vals_ref[0].astype(jnp.float32),
-                              r_ref[...].reshape(-1))
+    # grid = (K,); one selected column block per step, written to row k of
+    # the resident (K, block) output.
+    k = pl.program_id(0)
+    g_ref[pl.ds(k, 1), :] = _tile_gather(rows_ref[0],
+                                         vals_ref[0].astype(jnp.float32),
+                                         r_ref[...].reshape(-1))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sparse_gather_block_matvec(rows, vals, r, blk_idx,
-                               interpret: bool = False):
+                               interpret: bool | None = None):
     """g (K, block) = A_Bᵀ r for the selected blocks, from nnz tiles.
 
     rows/vals: (nblk, tile, block) BlockedCSC tiles; r: (n,) f32;
@@ -112,13 +136,13 @@ def sparse_gather_block_matvec(rows, vals, r, blk_idx,
             pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
             pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda k, idx: (k, 0)),
+        out_specs=pl.BlockSpec((K, block), lambda k, idx: (0, 0)),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
-        interpret=interpret,
+        **_call_params(interpret),
     )(blk_idx.astype(jnp.int32), rows, vals,
       r.reshape(n, 1).astype(jnp.float32))
 
@@ -138,7 +162,8 @@ def _make_scatter_kernel(K: int):
         n = acc_ref.shape[0]
         acc_ref[...] = _tile_scatter(
             acc_ref[...].reshape(-1), rows_ref[0],
-            vals_ref[0].astype(jnp.float32), d_ref[...]).reshape(n, 1)
+            vals_ref[0].astype(jnp.float32),
+            d_ref[pl.ds(k, 1), :]).reshape(n, 1)
 
         @pl.when(k == K - 1)
         def _flush():
@@ -149,7 +174,7 @@ def _make_scatter_kernel(K: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
-                                interpret: bool = False):
+                                interpret: bool | None = None):
     """z_new = z + Σ_k A_{B_k} δ_k from nnz tiles — f32 accumulation.
 
     delta: (K, block).  Duplicate blocks in ``blk_idx`` accumulate, matching
@@ -165,7 +190,7 @@ def sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
         in_specs=[
             pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
             pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
-            pl.BlockSpec((1, block), lambda k, idx: (k, 0)),
+            pl.BlockSpec((K, block), lambda k, idx: (0, 0)),
             pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
         ],
         out_specs=pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
@@ -175,7 +200,7 @@ def sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
         _make_scatter_kernel(K),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        interpret=interpret,
+        **_call_params(interpret),
     )(blk_idx.astype(jnp.int32), rows, vals,
       delta.astype(jnp.float32), z.reshape(n, 1).astype(jnp.float32))
     return out.reshape(n).astype(z.dtype)
@@ -203,7 +228,7 @@ def _make_fused_sparse_kernel(loss: Loss, K: int, emit_dz: bool = False):
     Divergence sentinel (DESIGN §9): like the dense fused kernel, the
     scalar-prefetch vector carries ``k_eff`` (blocks past it have their
     delta masked to zero; exactly 1.0 at k_eff == K) and a guard objective
-    level, and a (1, 1) max-accumulated health output trips on a
+    level, and a (1, 1) max-accumulated SMEM health output trips on a
     guard-crossing / non-finite round.
 
     Per-block Newton (``loss.newton``, DESIGN §12): the round start also
@@ -291,11 +316,12 @@ def _make_fused_sparse_kernel(loss: Loss, K: int, emit_dz: bool = False):
             else:
                 f = loss.objective(z_s[...], y_ref[...], one,
                                    x_s[...], lam)
-                f_ref[0, 0] = f
+                f_ref[r_id, 0] = f
                 bad = ~jnp.isfinite(f) | (f > guard)
                 h_ref[0, 0] = jnp.maximum(
                     h_ref[0, 0], jnp.where(bad, 1.0, 0.0))
-                nnz_ref[0, 0] = jnp.sum((x_s[...] != 0).astype(jnp.int32))
+                nnz_ref[r_id, 0] = jnp.sum(
+                    (x_s[...] != 0).astype(jnp.int32))
                 zo_ref[...] = z_s[...]
                 xo_ref[...] = x_s[...]
 
@@ -325,13 +351,13 @@ def _fused_sparse_call(rows, vals, z, x, blk_idx, lam, beta, y, loss,
 
     tile_map = lambda r, k, idx, scal: (idx[r, k], 0, 0)
     const = lambda r, k, idx, scal: (0, 0)
-    f_map = lambda r, k, idx, scal: (r, 0)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars (dense twin)
 
     if emit_dz:
         out_specs = [
             pl.BlockSpec((n, 1), const),            # Δz
             pl.BlockSpec((nblk, block), const),     # x
-            pl.BlockSpec((1, 1), const),            # health scalar
+            smem,                                   # health scalar
         ]
         out_shape = [
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
@@ -343,9 +369,9 @@ def _fused_sparse_call(rows, vals, z, x, blk_idx, lam, beta, y, loss,
         out_specs = [
             pl.BlockSpec((n, 1), const),            # z
             pl.BlockSpec((nblk, block), const),     # x
-            pl.BlockSpec((1, 1), f_map),            # f trace
-            pl.BlockSpec((1, 1), f_map),            # nnz trace
-            pl.BlockSpec((1, 1), const),            # health scalar
+            smem,                                   # f trace
+            smem,                                   # nnz trace
+            smem,                                   # health scalar
         ]
         out_shape = [
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
@@ -381,14 +407,14 @@ def _fused_sparse_call(rows, vals, z, x, blk_idx, lam, beta, y, loss,
         _make_fused_sparse_kernel(loss, K, emit_dz=emit_dz),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        **_call_params(interpret),
     )(idx, scal, rows, vals, z0, x0, y2)
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "interpret"))
 def fused_sparse_shotgun_rounds(rows, vals, z, x, blk_idx, lam, beta, y,
                                 loss: str | Loss = LASSO,
-                                interpret: bool = False,
+                                interpret: bool | None = None,
                                 k_eff=None, guard_f=None):
     """R Block-Shotgun rounds over BlockedCSC tiles in ONE pallas_call.
 
@@ -419,7 +445,8 @@ def fused_sparse_shotgun_rounds(rows, vals, z, x, blk_idx, lam, beta, y,
 @functools.partial(jax.jit, static_argnames=("loss", "interpret"))
 def fused_sparse_shotgun_delta_rounds(rows, vals, z, x, blk_idx, lam, beta,
                                       y, loss: str | Loss = LASSO,
-                                      interpret: bool = False, k_eff=None):
+                                      interpret: bool | None = None,
+                                      k_eff=None):
     """Shard-local fused sparse engine kernel: R rounds against a margin
     *snapshot* (DESIGN §3).  Same dataflow as ``fused_sparse_shotgun_rounds``
     but the kernel does not own the global margin: ``z`` is the last merged
@@ -442,30 +469,30 @@ def fused_sparse_vmem_bytes(n: int, nblk: int, tile: int, K: int,
                             block: int = BLOCK, emit_dz: bool = False,
                             val_bytes: int = 4, slots: int = 1,
                             loss: str | Loss = "lasso") -> int:
-    """f32/int32 VMEM resident set of the fused sparse kernel (DESIGN §8.3):
-    z/r scratch (+ Δz for the engine variant), the z0/y in- and z out-
-    vectors, the three full-width x buffers (x0/scratch/out), the K-row
+    """VMEM resident set of the fused sparse kernel (DESIGN §8.3), each
+    buffer priced at its (8, 128)-tiled layout like the dense twin
+    ``shotgun_block.fused_vmem_bytes``: the z/r scratch (+ Δz for the
+    engine variant), the z0/y in- and z out-vectors (512 B per sample
+    each), the three (nblk, block) x buffers (x0/scratch/out), the K-row
     delta scratch, and the double-buffered (tile, block) rows+vals tile
     pair.  ``val_bytes`` is the stored dtype of the vals tiles (4 = f32,
     2 = bf16 via ``BlockedCSC.astype`` — rows stay int32 and all in-kernel
     accumulation stays f32, so only the vals term shrinks).  R never
-    enters — only the (R·K) scalar-prefetch index matrix and the per-round
-    (1, 1) trace outputs scale with R, both negligible — so the tile size
-    (and through it the density) is what bounds the shapes this kernel
-    accepts, not the rounds-per-launch.  ``slots`` is the batched-launch
-    multiplier (DESIGN §11): the vmapped entry points stack S slots on a
-    leading axis, modeled as slots × the per-problem resident set (see
+    enters: the (R, K) index matrix and the (R, 1) traces live in SMEM.
+    ``slots`` is the batched-launch multiplier (DESIGN §11), modeled as
+    slots × the per-problem resident set (see
     ``shotgun_block.fused_vmem_bytes``).  ``loss`` prices the logistic
     kernel twins: a Newton spec adds the (n, 1) curvature-weight scratch
     (the per-block h is a per-step local here — no (K, block) accumulator,
-    DESIGN §12)."""
+    DESIGN §12).  No compiler temporaries are counted: Mosaic refuses
+    this kernel before allocating (``require_sparse_backend``)."""
     newton = resolve_loss(loss).newton
     # z0-in, y-in, z_s, r_s, plus z-out (margin-owning) or dz_s + dz-out
     # minus z-out (engine variant): 5 vs 6 n-vectors; Newton adds the
     # curvature-weight vector
-    vecs = ((6 if emit_dz else 5) + (1 if newton else 0)) * n * 4
-    xbuf = 3 * nblk * block * 4                    # x0, x_s, x out
-    dbuf = K * block * 4                           # delta scratch
+    vecs = ((6 if emit_dz else 5) + (1 if newton else 0)) * vec_vmem_bytes(n)
+    xbuf = 3 * vec_vmem_bytes(nblk, block)         # x0, x_s, x out
+    dbuf = vec_vmem_bytes(K, block)                # delta scratch
     # rows (int32) + vals (val_bytes), each double-buffered
     tiles = 2 * tile * block * (4 + val_bytes)
     return slots * (vecs + xbuf + dbuf + tiles)

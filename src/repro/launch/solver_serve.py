@@ -30,9 +30,8 @@ batched launch of the fused kernels per scheduler step
 Slot/queue bookkeeping (free slots, FIFO refill, age, round-deadline
 eviction with re-queue) is the shared ``launch.slots.SlotBoard`` — an
 evicted solve keeps its partial iterate and resumes from it when
-re-admitted.  Throughput numbers from this container are interpret-mode
-(DESIGN §11.5): batching wins come from slot refill + warm starts, not
-kernel overlap.
+re-admitted.  A BlockedCSC stream raises ``NotImplementedError`` at
+construction on a TPU (``shotgun_sparse.require_sparse_backend``).
 """
 from __future__ import annotations
 
@@ -52,6 +51,7 @@ from repro.core.batched import (BatchMeta, SlotArrays, WarmStartCache,
 from repro.core.objectives import Problem
 from repro.data.sparse import bcsc_matvec
 from repro.kernels.batched import batched_draw_blocks
+from repro.kernels.shotgun_sparse import require_sparse_backend
 from repro.launch.slots import SlotBoard
 
 GUARD_FACTOR = 10.0         # §9 trip threshold: F > factor·|F_prev| + factor
@@ -92,7 +92,7 @@ def _slot_objective(z, y, mask, lam, x, loss):
 
 @jax.jit
 def _dense_margin(A, x0):
-    return A.astype(jnp.float32) @ x0
+    return obj.matvec(A.astype(jnp.float32), x0)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -130,19 +130,20 @@ class SolverService:
 
     def __init__(self, meta: BatchMeta, *, slots: int = 4, K: int = 2,
                  max_rounds: int = 64, rounds_per_launch: int = 8,
-                 tol: float = 1e-4, interpret: bool = True,
+                 tol: float = 1e-4,
                  cache: WarmStartCache | None = None,
                  deadline_launches: int | None = None,
                  max_evictions: int = 2):
         if max_rounds % rounds_per_launch:
             raise ValueError(f"max_rounds={max_rounds} not divisible by "
                              f"rounds_per_launch={rounds_per_launch}")
+        if meta.layout == "bcsc":
+            require_sparse_backend()
         self.meta = meta
         self.K = K
         self.R = rounds_per_launch
         self.max_launches = max_rounds // rounds_per_launch
         self.tol = tol
-        self.interpret = interpret
         self.cache = WarmStartCache() if cache is None else cache
         self.board = SlotBoard(slots, max_rounds=deadline_launches,
                                max_evictions=max_evictions)
@@ -241,8 +242,7 @@ class SolverService:
                                   self.meta.nblk)
         self.x, self.z, fs, _, hlt = launch_rounds(
             self.meta, self.stacked, self.z, self.x, idx,
-            jnp.asarray(k_eff), guard_f=jnp.asarray(guard),
-            interpret=self.interpret)
+            jnp.asarray(k_eff), guard_f=jnp.asarray(guard))
         self.launch_count += 1
         fs_h, hlt_h = np.asarray(fs), np.asarray(hlt)
         for i, r in enumerate(self.board.slots):
@@ -328,7 +328,6 @@ def req_d(req: SolveRequest) -> int:
 
 def solve_queue_sequential(requests, *, K: int = 2, max_rounds: int = 64,
                            rounds_per_launch: int = 8, tol: float = 1e-4,
-                           interpret: bool = True,
                            cache: WarmStartCache | None = None):
     """The solve-one-at-a-time baseline: each request served through a
     1-slot service (same launch schedule, same early stop, same cache
@@ -339,7 +338,7 @@ def solve_queue_sequential(requests, *, K: int = 2, max_rounds: int = 64,
         svc = SolverService(batch_meta_of(req.prob), slots=1, K=K,
                             max_rounds=max_rounds,
                             rounds_per_launch=rounds_per_launch, tol=tol,
-                            interpret=interpret, cache=cache)
+                            cache=cache)
         out.extend(svc.serve([req]))
     return out
 

@@ -68,10 +68,10 @@ def test_batched_dense_slots_bit_identical_to_standalone():
     probs = _dense_probs()
     keys = [jax.random.PRNGKey(7 + s) for s in range(len(probs))]
     res = batched_block_shotgun_solve(probs, keys, K, ROUNDS,
-                                      rounds_per_launch=R, interpret=True)
+                                      rounds_per_launch=R)
     for s, (p, k) in enumerate(zip(probs, keys)):
         ref = ops.block_shotgun_solve(p, k, K, ROUNDS, fused=True,
-                                      rounds_per_launch=R, interpret=True)
+                                      rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
         assert np.array_equal(np.asarray(res.trace.objective[s]),
@@ -84,10 +84,10 @@ def test_batched_sparse_slots_bit_identical_to_standalone():
     probs = _sparse_probs(tile=64)
     keys = [jax.random.PRNGKey(99 + s) for s in range(len(probs))]
     res = batched_block_shotgun_solve(probs, keys, K, ROUNDS,
-                                      rounds_per_launch=R, interpret=True)
+                                      rounds_per_launch=R)
     for s, (p, k) in enumerate(zip(probs, keys)):
         ref = ops.block_shotgun_solve(p, k, K, ROUNDS, fused=True,
-                                      rounds_per_launch=R, interpret=True)
+                                      rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
 
@@ -104,7 +104,7 @@ def test_heterogeneous_tile_admission_matches_stream_tiling():
     assert meta.tile == max(tiles)
     keys = [jax.random.PRNGKey(5 + s) for s in range(len(probs))]
     res = batched_block_shotgun_solve(probs, keys, K, ROUNDS,
-                                      rounds_per_launch=R, interpret=True)
+                                      rounds_per_launch=R)
     for s, (p, k) in enumerate(zip(probs, keys)):
         S = p.A
         if S.tile < meta.tile:
@@ -113,8 +113,7 @@ def test_heterogeneous_tile_admission_matches_stream_tiling():
                            vals=jnp.pad(S.vals, pad),
                            n=S.n, d=S.d, block=S.block)
         ref = ops.block_shotgun_solve(p._replace(A=S), k, K, ROUNDS,
-                                      fused=True, rounds_per_launch=R,
-                                      interpret=True)
+                                      fused=True, rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
 
@@ -130,8 +129,7 @@ def test_frozen_slot_is_bit_exact_noop():
     idx = jax.vmap(lambda k: jax.random.choice(
         k, meta.nblk, (R, K), replace=True))(keys).astype(jnp.int32)
     x, z, fs, _, _ = launch_rounds(meta, stacked, z0, x0, idx,
-                                   jnp.array([0.0, float(K)]),
-                                   interpret=True)
+                                   jnp.array([0.0, float(K)]))
     assert np.array_equal(np.asarray(x[0]), np.asarray(x0[0]))
     assert np.array_equal(np.asarray(z[0]), np.asarray(z0[0]))
     assert np.any(np.asarray(x[1]) != 0)    # the live slot actually moved
@@ -173,8 +171,7 @@ def test_served_stream_matches_sequential_queue():
     reqs = _fresh_stream()
     for r in reqs:
         r.problem_id = ("solo", r.rid)      # no cross-request cache hits
-    kw = dict(K=1, max_rounds=24, rounds_per_launch=8, tol=1e-4,
-              interpret=True)
+    kw = dict(K=1, max_rounds=24, rounds_per_launch=8, tol=1e-4)
     svc = SolverService(batch_meta_of(reqs[0].prob), slots=3,
                         cache=WarmStartCache(), **kw)
     served = {r.rid: r for r in svc.serve(_clone(reqs))}
@@ -196,8 +193,7 @@ def test_served_stream_deterministic_under_eviction():
     reqs = _fresh_stream(requests=4)
     for r in reqs:
         r.problem_id = ("solo", r.rid)
-    kw = dict(K=1, max_rounds=24, rounds_per_launch=8, tol=1e-4,
-              interpret=True)
+    kw = dict(K=1, max_rounds=24, rounds_per_launch=8, tol=1e-4)
     plain = {r.rid: r for r in SolverService(
         batch_meta_of(reqs[0].prob), slots=2, cache=WarmStartCache(),
         **kw).serve(_clone(reqs))}
@@ -238,7 +234,7 @@ def test_solve_path_cached_second_sweep_fewer_rounds():
     prob = obj.make_problem(A, y, lam=2.0)
     cache = WarmStartCache()
     kw = dict(lam_target=2.0, P=128, rounds_per_lambda=64, num_lambdas=4,
-              solver="block_fused", interpret=True, validate_p=False,
+              solver="block_fused", validate_p=False,
               cache=cache, problem_id="p0")
     r1 = solve_path(prob, jax.random.PRNGKey(0), **kw)
     r2 = solve_path(prob, jax.random.PRNGKey(1), **kw)

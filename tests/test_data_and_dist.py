@@ -133,7 +133,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.dist.collectives import hierarchical_psum
 
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("pod", "data"))
@@ -146,10 +145,10 @@ def f(xs):
 def g(xs):
     return jax.lax.psum(xs, ("pod", "data"))
 
-fm = shard_map(f, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(None),
-               check_vma=False)
-gm = shard_map(g, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(None),
-               check_vma=False)
+fm = jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                   out_specs=P(None), check_vma=False)
+gm = jax.shard_map(g, mesh=mesh, in_specs=P(("pod", "data")),
+                   out_specs=P(None), check_vma=False)
 np.testing.assert_allclose(np.asarray(fm(x)), np.asarray(gm(x)), rtol=1e-6)
 print("HIERARCHICAL_OK")
 

@@ -10,7 +10,9 @@ import pytest
 from repro.core import objectives as obj
 from repro.data import synthetic as syn
 from repro.kernels import ops, ref
-from repro.kernels.shotgun_block import BLOCK, auto_tile_n, fused_shotgun_rounds
+from repro.kernels.shotgun_block import (BLOCK, VMEM_BUDGET, auto_tile_n,
+                                         fused_shotgun_rounds,
+                                         fused_vmem_bytes)
 
 
 def _padded_problem(loss, seed=0, n=300, d=500, lam=0.4):
@@ -46,7 +48,7 @@ def test_fused_rounds_match_oracle(loss, tile_n):
 
     xk, zk, fk, nk, _h = fused_shotgun_rounds(
         Ap, z, x, idx, prob.lam, prob.beta, yp, mask, loss=loss,
-        tile_n=tile_n, interpret=True)
+        tile_n=tile_n)
     xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
         Ap, z, x, idx, prob.lam, prob.beta, yp, mask, loss, BLOCK)
 
@@ -68,8 +70,7 @@ def test_fused_padded_coordinates_stay_zero():
     nblk = Ap.shape[1] // BLOCK
     idx = jnp.tile(jnp.arange(nblk, dtype=jnp.int32), (8, 1))[:, :nblk]
     xk, zk, fk, _, _h = fused_shotgun_rounds(
-        Ap, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO,
-        interpret=True)
+        Ap, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO)
     np.testing.assert_allclose(np.asarray(xk[prob.d:]), 0.0)
     np.testing.assert_allclose(np.asarray(zk[prob.n:]), 0.0, atol=1e-6)
     assert np.all(np.isfinite(np.asarray(fk)))
@@ -86,7 +87,7 @@ def test_fused_bf16_storage():
     idx = _idx_with_duplicates(Ap.shape[1] // BLOCK, 8, 2)
     xk, zk, fk, nk, _h = fused_shotgun_rounds(
         Abf, z, x, idx, prob.lam, prob.beta, yp, mask,
-        loss=obj.LASSO, interpret=True)
+        loss=obj.LASSO)
     xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
         Abf, z, x, idx, prob.lam, prob.beta, yp, mask, obj.LASSO, BLOCK)
     np.testing.assert_allclose(np.asarray(xk), np.asarray(xr),
@@ -98,22 +99,26 @@ def test_fused_bf16_storage():
     x0 = jnp.zeros_like(x)
     z0 = jnp.zeros_like(z)
     _, _, f16, _, _ = fused_shotgun_rounds(
-        Abf, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO,
-        interpret=True)
+        Abf, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO)
     _, _, f32_, _, _ = fused_shotgun_rounds(
-        Ap, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO,
-        interpret=True)
+        Ap, z0, x0, idx, prob.lam, prob.beta, yp, mask, loss=obj.LASSO)
     np.testing.assert_allclose(np.asarray(f16), np.asarray(f32_), rtol=2e-2)
 
 
 def test_auto_tile_n():
     assert auto_tile_n(512, d=512) == 512     # whole-n tile -> single phase
     assert auto_tile_n(2048, d=8192) == 2048  # benchmark shape fits easily
-    big = auto_tile_n(1 << 20)
-    assert big < (1 << 20) and (1 << 20) % big == 0
+    big = auto_tile_n(1 << 15)
+    assert big < (1 << 15) and (1 << 15) % big == 0
+    # at 512 B per sample the resident (n, 1) vectors alone outgrow the
+    # budget: refused up front, naming the limit
+    with pytest.raises(ValueError, match="VMEM_BUDGET"):
+        auto_tile_n(1 << 20)
     # large d pins 3 full-d x buffers in VMEM: must veto single-phase even
     # though the A tile alone would fit
-    assert auto_tile_n(8192, d=1 << 20) < 8192
+    n = 8192
+    spare = VMEM_BUDGET - fused_vmem_bytes(n, 0, 1, tile_n=n)
+    assert auto_tile_n(n, d=(spare // 12 // BLOCK + 1) * BLOCK) < n
 
 
 def test_fused_solve_trace_parity():
@@ -123,8 +128,8 @@ def test_fused_solve_trace_parity():
     A, y, _ = syn.sparco(seed=6, n=640, d=1024)
     prob = obj.make_problem(A, y, lam=1.0)
     key = jax.random.PRNGKey(0)
-    two = ops.block_shotgun_solve(prob, key, K=2, rounds=32, interpret=True)
-    fus = ops.block_shotgun_solve(prob, key, K=2, rounds=32, interpret=True,
+    two = ops.block_shotgun_solve(prob, key, K=2, rounds=32)
+    fus = ops.block_shotgun_solve(prob, key, K=2, rounds=32,
                                   fused=True, rounds_per_launch=8)
     f2, ff = np.asarray(two.trace.objective), np.asarray(fus.trace.objective)
     np.testing.assert_allclose(ff, f2, rtol=2e-5)
@@ -150,7 +155,7 @@ def test_solver_registry_exposes_fused():
     solve = get_solver("block_fused")
     A, y, _ = syn.sparco(seed=0, n=256, d=512)
     prob = obj.make_problem(A, y, lam=1.0)
-    res = solve(prob, jax.random.PRNGKey(0), K=1, rounds=8, interpret=True)
+    res = solve(prob, jax.random.PRNGKey(0), K=1, rounds=8)
     assert res.trace.objective.shape == (8,)
     assert res.x.shape == (prob.d,)
     with pytest.raises(ValueError, match="unknown solver"):

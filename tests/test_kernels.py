@@ -1,4 +1,4 @@
-"""Pallas kernel allclose sweeps (interpret=True) against the ref.py oracles,
+"""Pallas kernel allclose sweeps (the interpreter on the CPU backend) against the ref.py oracles,
 across shapes and dtypes, plus full-round and solver-level parity."""
 import jax
 import jax.numpy as jnp
@@ -35,8 +35,7 @@ def test_gather_block_matvec_allclose(n, d, block, tile_n, K, dtype):
     A, r, _ = _mk(n, d, dtype)
     nblk = d // block
     blk = jax.random.choice(jax.random.PRNGKey(1), nblk, (K,), replace=False)
-    got = gather_block_matvec(A, r, blk, block=block, tile_n=tile_n,
-                              interpret=True)
+    got = gather_block_matvec(A, r, blk, block=block, tile_n=tile_n)
     want = ref.gather_block_matvec_ref(A, r, blk, block)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -51,8 +50,7 @@ def test_scatter_block_update_allclose(n, d, block, tile_n, K, dtype):
     nblk = d // block
     blk = jax.random.choice(jax.random.PRNGKey(2), nblk, (K,), replace=False)
     delta = jnp.asarray(rng.standard_normal((K, block)) * 0.1, dtype)
-    got = scatter_block_update(A, z, blk, delta, block=block, tile_n=tile_n,
-                               interpret=True)
+    got = scatter_block_update(A, z, blk, delta, block=block, tile_n=tile_n)
     want = ref.scatter_block_update_ref(A, z, blk, delta, block)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -72,7 +70,7 @@ def test_block_round_matches_ref(loss):
     blk = jax.random.choice(jax.random.PRNGKey(5), Ap.shape[1] // ops.BLOCK,
                             (3,), replace=False)
     x_k, z_k, d_k = ops.block_shotgun_round(Ap, z, x, blk, prob.lam, prob.beta,
-                                            yp, mask, loss=loss, interpret=True)
+                                            yp, mask, loss=loss)
     x_r, z_r, d_r = ref.block_shotgun_round_ref(Ap, z, x, blk, prob.lam,
                                                 prob.beta, yp, loss, ops.BLOCK)
     np.testing.assert_allclose(np.asarray(x_k), np.asarray(x_r), rtol=1e-4, atol=1e-4)
@@ -89,7 +87,7 @@ def test_block_solver_converges_to_reference_objective():
     prob = obj.make_problem(A, y, lam=1.0)
     assert p_star(prob.A) > 2 * ops.BLOCK   # P = K*128 = 256 is theory-legal
     f_blk = float(ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=2,
-                                          rounds=800, interpret=True)
+                                          rounds=800)
                   .trace.objective[-1])
     f_ref = float(shotgun_solve(prob, jax.random.PRNGKey(1), P=256,
                                 rounds=2000).trace.objective[-1])

@@ -55,7 +55,7 @@ def test_fused_newton_matches_oracle(tile_n):
 
     xk, zk, fk, nk, _h = fused_shotgun_rounds(
         Ap, z, x, idx, prob.lam, prob.beta, yp, mask,
-        loss="logistic_newton", tile_n=tile_n, interpret=True)
+        loss="logistic_newton", tile_n=tile_n)
     xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
         Ap, z, x, idx, prob.lam, prob.beta, yp, mask, "logistic_newton",
         BLOCK)
@@ -79,7 +79,7 @@ def test_fused_sparse_newton_matches_oracle():
 
     xk, zk, fk, nk, _h = fused_sparse_shotgun_rounds(
         rows, vals, z, x, idx, prob.lam, prob.beta, prob.y,
-        loss="logistic_newton", interpret=True)
+        loss="logistic_newton")
     xr, zr, fr, nr = ref.fused_sparse_shotgun_rounds_ref(
         rows, vals, z, x, idx, prob.lam, prob.beta, prob.y,
         "logistic_newton")
@@ -178,7 +178,7 @@ def test_spec_shim_bit_for_bit_fused():
     key = jax.random.PRNGKey(2)
     with pytest.warns(DeprecationWarning):
         r_old = ops.block_shotgun_solve(prob, key, K=1, rounds=8,
-                                        fused=True, interpret=True)
+                                        fused=True)
     r_new = ops.block_shotgun_solve(prob, key, spec=SolverSpec(
         loss="logistic", P=128, rounds=8, fused=True))
     np.testing.assert_array_equal(np.asarray(r_old.x), np.asarray(r_new.x))
@@ -191,10 +191,8 @@ def test_spec_shim_bit_for_bit_batched():
     keys = [jax.random.PRNGKey(i) for i in range(2)]
     with pytest.warns(DeprecationWarning):
         old = batched_block_shotgun_solve(probs, keys, 1, 4,
-                                          rounds_per_launch=4,
-                                          interpret=True)
+                                          rounds_per_launch=4)
     new = batched_block_shotgun_solve(probs, keys, rounds_per_launch=4,
-                                      interpret=True,
                                       spec=SolverSpec(loss="logistic",
                                                       P=128, rounds=4))
     np.testing.assert_array_equal(np.asarray(old.x), np.asarray(new.x))
@@ -226,8 +224,7 @@ def test_spec_rejects_mixed_interfaces_and_bad_combos():
 def test_get_solver_family_loss_pair_admission():
     solver = get_solver(("block_fused", "logistic"))
     prob = _logistic_problem(n=200, d=128)
-    r = solver(prob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2,
-               interpret=True)
+    r = solver(prob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2)
     assert np.isfinite(float(r.trace.objective[-1]))
     lasso = obj.make_problem(*syn.sparco(seed=0, n=128, d=256)[:2], lam=0.5)
     with pytest.raises(ValueError) as ei:
@@ -240,27 +237,25 @@ def test_get_solver_family_loss_pair_admission():
 def test_logreg_fused_aliases():
     prob = _logistic_problem(n=200, d=128)
     r = get_solver("shotgun_logreg_fused")(
-        prob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2,
-        interpret=True)
+        prob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2)
     assert np.isfinite(float(r.trace.objective[-1]))
     # the sparse alias insists on a BlockedCSC design
     with pytest.raises(ValueError, match="BlockedCSC"):
         get_solver("sparse_logreg_fused")(prob, jax.random.PRNGKey(0), 1, 2)
     sprob = _bcsc_logistic_problem()
     rs = get_solver("sparse_logreg_fused")(
-        sprob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2,
-        interpret=True)
+        sprob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2)
     assert np.isfinite(float(rs.trace.objective[-1]))
     # the alias speaks spec= too, promoting fused=True (a spec left at
     # its fused=False default must not silently fall off the fused path),
     # and refuses the mixed spec+legacy interface like every entry point
     r2 = get_solver("shotgun_logreg_fused")(
-        prob, jax.random.PRNGKey(0), rounds_per_launch=2, interpret=True,
+        prob, jax.random.PRNGKey(0), rounds_per_launch=2,
         spec=SolverSpec(loss="logistic", P=128, rounds=2))
     assert np.array_equal(np.asarray(r.x), np.asarray(r2.x))
     with pytest.raises(ValueError, match="spec"):
         get_solver("shotgun_logreg_fused")(
-            prob, jax.random.PRNGKey(0), K=1, interpret=True,
+            prob, jax.random.PRNGKey(0), K=1,
             spec=SolverSpec(loss="logistic", P=128, rounds=2))
 
 

@@ -44,7 +44,7 @@ def test_fused_engine_single_shard_matches_fused_solver(prob):
     key = jax.random.PRNGKey(0)
     sh = shotgun_sharded_solve(prob, key, rounds=16, mesh=_mesh1(),
                                engine="fused", merge="round", K=2)
-    fu = ops.block_shotgun_solve(prob, key, K=2, rounds=16, interpret=True,
+    fu = ops.block_shotgun_solve(prob, key, K=2, rounds=16,
                                  fused=True, rounds_per_launch=8)
     np.testing.assert_allclose(np.asarray(sh.trace.objective),
                                np.asarray(fu.trace.objective), rtol=2e-5)
@@ -58,7 +58,7 @@ def test_block_engine_single_shard_matches_two_kernel_solver(prob):
     key = jax.random.PRNGKey(0)
     sh = shotgun_sharded_solve(prob, key, rounds=8, mesh=_mesh1(),
                                engine="block", merge="round", K=2)
-    tk = ops.block_shotgun_solve(prob, key, K=2, rounds=8, interpret=True)
+    tk = ops.block_shotgun_solve(prob, key, K=2, rounds=8)
     np.testing.assert_allclose(np.asarray(sh.trace.objective),
                                np.asarray(tk.trace.objective), rtol=2e-5)
     np.testing.assert_allclose(np.asarray(sh.x), np.asarray(tk.x),
@@ -118,12 +118,10 @@ def test_kernel_shape_checks_raise_value_error_not_assert():
     from repro.kernels.shotgun_block import gather_block_matvec
     A = jnp.zeros((256, 200))          # 200 % 128 != 0
     with pytest.raises(ValueError, match="block"):
-        gather_block_matvec(A, jnp.zeros(256), jnp.zeros(1, jnp.int32),
-                            interpret=True)
+        gather_block_matvec(A, jnp.zeros(256), jnp.zeros(1, jnp.int32))
     A = jnp.zeros((250, 256))          # 250 % 512 != 0
     with pytest.raises(ValueError, match="tile_n"):
-        gather_block_matvec(A, jnp.zeros(250), jnp.zeros(1, jnp.int32),
-                            interpret=True)
+        gather_block_matvec(A, jnp.zeros(250), jnp.zeros(1, jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -134,12 +132,12 @@ def test_block_solver_warm_start(prob):
     """x0 warm start: the first traced objective continues from F(x0), not
     from F(0), and the returned margin stays consistent with x."""
     key = jax.random.PRNGKey(3)
-    warm = ops.block_shotgun_solve(prob, key, K=2, rounds=64, interpret=True)
-    res = ops.block_shotgun_solve(prob, key, K=2, rounds=8, interpret=True,
+    warm = ops.block_shotgun_solve(prob, key, K=2, rounds=64)
+    res = ops.block_shotgun_solve(prob, key, K=2, rounds=8,
                                   x0=warm.x)
     f_warm0 = float(res.trace.objective[0])
     f_cold0 = float(ops.block_shotgun_solve(
-        prob, key, K=2, rounds=8, interpret=True).trace.objective[0])
+        prob, key, K=2, rounds=8).trace.objective[0])
     assert f_warm0 < f_cold0
     assert f_warm0 <= float(warm.trace.objective[-1]) * 1.01
     np.testing.assert_allclose(np.asarray(res.z),
@@ -149,7 +147,7 @@ def test_block_solver_warm_start(prob):
 
 def test_sharded_solver_warm_start(prob):
     key = jax.random.PRNGKey(3)
-    warm = ops.block_shotgun_solve(prob, key, K=2, rounds=64, interpret=True)
+    warm = ops.block_shotgun_solve(prob, key, K=2, rounds=64)
     res = shotgun_sharded_solve(prob, key, P_local=4, rounds=20,
                                 mesh=_mesh1(), x0=warm.x)
     assert float(res.trace.objective[0]) < float(
@@ -162,10 +160,9 @@ def test_solve_path_runs_on_registry_solvers(name):
     from repro.core.path import solve_path
     A, y, _ = syn.sparco(seed=0, n=512, d=1024)
     prob = obj.make_problem(A, y, lam=0.5)
-    kw = {"interpret": True} if name.startswith("block") else {}
     # P=128 (one 128-block for the Pallas solvers) respects P* here
     res = solve_path(prob, jax.random.PRNGKey(0), lam_target=0.5, P=128,
-                     rounds_per_lambda=16, num_lambdas=3, solver=name, **kw)
+                     rounds_per_lambda=16, num_lambdas=3, solver=name)
     assert res.x.shape == (prob.d,)
     assert res.lambdas.shape == (3,)
     assert np.all(np.isfinite(res.objectives))

@@ -152,7 +152,7 @@ def test_sparse_gather_kernel_matches_dense_ref(K):
     Ad, S, _ = _pair(seed=4)
     r = jnp.asarray(np.random.default_rng(5).standard_normal(S.n), jnp.float32)
     blk = jax.random.choice(jax.random.PRNGKey(6), S.nblk, (K,), replace=False)
-    got = sparse_gather_block_matvec(S.rows, S.vals, r, blk, interpret=True)
+    got = sparse_gather_block_matvec(S.rows, S.vals, r, blk)
     want = ref.gather_block_matvec_ref(jnp.asarray(Ad), r, blk, S.block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -165,8 +165,7 @@ def test_sparse_scatter_kernel_matches_dense_ref(K):
     z = jnp.asarray(rng.standard_normal(S.n), jnp.float32)
     delta = jnp.asarray(rng.standard_normal((K, S.block)) * 0.1, jnp.float32)
     blk = jax.random.choice(jax.random.PRNGKey(9), S.nblk, (K,), replace=False)
-    got = sparse_scatter_block_update(S.rows, S.vals, z, blk, delta,
-                                      interpret=True)
+    got = sparse_scatter_block_update(S.rows, S.vals, z, blk, delta)
     want = ref.scatter_block_update_ref(jnp.asarray(Ad), z, blk, delta, S.block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -200,10 +199,8 @@ def test_sparse_block_solver_matches_dense_trajectory(category):
     Ad, S, y = _pair(category=category)
     pd = obj.make_problem(Ad, y, lam=0.5)
     ps = obj.make_problem(S, y, lam=0.5)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(1), K=2, rounds=80,
-                                 interpret=True)
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80,
-                                 interpret=True)
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(1), K=2, rounds=80)
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80)
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
                                rtol=1e-3, atol=1e-3)
@@ -219,10 +216,8 @@ def test_sparse_warm_start_threads_through():
     ps = obj.make_problem(S, y, lam=0.5)
     x0 = np.asarray(shotgun_solve(pd, jax.random.PRNGKey(2), P=8,
                                   rounds=200).x)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=40,
-                                 interpret=True, x0=jnp.asarray(x0))
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=40,
-                                 interpret=True, x0=jnp.asarray(x0))
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=40, x0=jnp.asarray(x0))
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=40, x0=jnp.asarray(x0))
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
                                rtol=1e-3, atol=1e-3)
@@ -248,7 +243,7 @@ def test_sparse_engine_single_shard_matches_block_solver():
     mesh = make_feature_mesh(jax.devices()[:1])
     rounds = 40
     r_blk = ops.block_shotgun_solve(ps, jax.random.PRNGKey(4), K=2,
-                                    rounds=rounds, interpret=True)
+                                    rounds=rounds)
     r_sh = shotgun_sharded_solve(ps, jax.random.PRNGKey(4), rounds=rounds,
                                  engine="sparse_block", K=2, mesh=mesh,
                                  trace_every=rounds)
@@ -276,7 +271,7 @@ def test_fused_sparse_kernel_matches_refs(category):
     lam, beta = 0.5, 1.0
 
     xk, zk, fk, nnzk, _h = fused_sparse_shotgun_rounds(
-        S.rows, S.vals, z, x, idx, lam, beta, y, interpret=True)
+        S.rows, S.vals, z, x, idx, lam, beta, y)
     xs, zs, fs, nnzs = ref.fused_sparse_shotgun_rounds_ref(
         S.rows, S.vals, z, x, idx, lam, beta, y, "lasso")
     np.testing.assert_allclose(np.asarray(xk), np.asarray(xs),
@@ -307,7 +302,7 @@ def test_fused_sparse_delta_rounds_matches_ref():
     y = jnp.asarray(y, jnp.float32)
 
     xk, dzk, _h = fused_sparse_shotgun_delta_rounds(
-        S.rows, S.vals, z, x, idx, 0.5, 1.0, y, interpret=True)
+        S.rows, S.vals, z, x, idx, 0.5, 1.0, y)
     xs, dzs = ref.fused_sparse_shotgun_delta_rounds_ref(
         S.rows, S.vals, z, x, idx, 0.5, 1.0, y, "lasso")
     np.testing.assert_allclose(np.asarray(xk), np.asarray(xs),
@@ -323,10 +318,8 @@ def test_fused_sparse_solver_matches_two_kernel_sparse(category):
     coincide (the §8.3 acceptance equivalence)."""
     _, S, y = _pair(category=category)
     ps = obj.make_problem(S, y, lam=0.5)
-    r2 = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80,
-                                 interpret=True)
-    rf = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80,
-                                 interpret=True, fused=True,
+    r2 = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80)
+    rf = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80, fused=True,
                                  rounds_per_launch=8)
     np.testing.assert_allclose(np.asarray(rf.trace.objective),
                                np.asarray(r2.trace.objective),
@@ -340,11 +333,9 @@ def test_fused_sparse_solver_matches_dense_fused():
     Ad, S, y = _pair()
     pd = obj.make_problem(Ad, y, lam=0.5)
     ps = obj.make_problem(S, y, lam=0.5)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(5), K=2, rounds=16,
-                                 interpret=True, fused=True,
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(5), K=2, rounds=16, fused=True,
                                  rounds_per_launch=8)
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(5), K=2, rounds=16,
-                                 interpret=True, fused=True,
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(5), K=2, rounds=16, fused=True,
                                  rounds_per_launch=8)
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
@@ -369,18 +360,15 @@ def test_fused_sparse_warm_start():
     ps = obj.make_problem(S, y, lam=0.5)
     x0 = np.asarray(shotgun_solve(pd, jax.random.PRNGKey(2), P=8,
                                   rounds=200).x)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=16,
-                                 interpret=True, fused=True,
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
                                  rounds_per_launch=8, x0=jnp.asarray(x0))
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16,
-                                 interpret=True, fused=True,
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
                                  rounds_per_launch=8, x0=jnp.asarray(x0))
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
                                rtol=1e-3, atol=1e-3)
     # warm trace must continue below the cold start's first objective
-    cold = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16,
-                                   interpret=True, fused=True,
+    cold = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
                                    rounds_per_launch=8)
     assert float(rs.trace.objective[0]) < float(cold.trace.objective[0])
 
@@ -395,7 +383,7 @@ def test_sparse_fused_engine_single_shard_matches_fused_solver():
     mesh = make_feature_mesh(jax.devices()[:1])
     rounds = 16
     rf = ops.block_shotgun_solve(ps, jax.random.PRNGKey(4), K=2,
-                                 rounds=rounds, interpret=True, fused=True,
+                                 rounds=rounds, fused=True,
                                  rounds_per_launch=8)
     r_sh = shotgun_sharded_solve(ps, jax.random.PRNGKey(4), rounds=rounds,
                                  engine="sparse_fused", merge="round", K=2,
@@ -415,18 +403,22 @@ def test_sparse_fused_engine_single_shard_matches_fused_solver():
 def test_fused_sparse_vmem_budget_tracks_scratch_list():
     """Drift pin for ``fused_sparse_vmem_bytes`` (DESIGN §8.3): the formula
     must mirror ``_fused_sparse_call``'s actual resident set — 5 (6 with
-    Δz) n-vectors, three (nblk, block) x buffers, the (K, block) δ scratch,
+    Δz) (8, 128)-tiled n-vectors, three (nblk, block) x buffers, the
+    (K, block) δ scratch,
     and the double-buffered rows+vals tile pair.  Editing the kernel's
     scratch/output lists must come back here."""
     from repro.kernels.shotgun_sparse import fused_sparse_vmem_bytes
     n, nblk, tile, K, block = 2048, 128, 16, 4, 128
-    expect = (5 * n * 4 + 3 * nblk * block * 4 + K * block * 4
+    # (n, 1) vectors take 512 B per sample in (8, 128) tiles; the (K, block)
+    # delta scratch pads K to 8 rows
+    vec = n * 512
+    expect = (5 * vec + 3 * nblk * block * 4 + 8 * block * 4
               + 2 * tile * block * 8)
     assert fused_sparse_vmem_bytes(n, nblk, tile, K) == expect
     assert (fused_sparse_vmem_bytes(n, nblk, tile, K, emit_dz=True)
-            == expect + n * 4)
+            == expect + vec)
     # bf16 value tiles shrink only the streamed rows+vals pair: 4+2 B/slot
-    expect16 = (5 * n * 4 + 3 * nblk * block * 4 + K * block * 4
+    expect16 = (5 * vec + 3 * nblk * block * 4 + 8 * block * 4
                 + 2 * tile * block * 6)
     assert fused_sparse_vmem_bytes(n, nblk, tile, K, val_bytes=2) == expect16
 
