@@ -9,9 +9,18 @@ just below lambda_max = ||A^T dL/dz(0)||_inf (above which x* = 0).
 pass ``solver="block_fused"`` / ``"sharded"`` / ... and the per-λ solves
 ride the Pallas or distributed paths, warm-started through their ``x0``
 support.
+
+The path is traced in the profiler's own trace (``jax.profiler``; nothing
+is recorded unless a trace is running): host spans ``shotgun.path``,
+``shotgun.path.p_star``, ``shotgun.path.lambda_max``,
+``shotgun.path.lambda`` (``lam=i``), ``shotgun.path.chunk`` (``chunk=c``)
+and ``shotgun.path.sync`` around every device→host read, which
+``PathResult.syncs`` counts; the per-λ objective's device ops run under
+the scope ``shotgun.path.objective``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -29,6 +38,27 @@ class PathResult(NamedTuple):
     objectives: np.ndarray        # final objective at each lambda
     nnz: np.ndarray               # sparsity along the path
     rounds: np.ndarray | None = None   # rounds spent per lambda (cache= only)
+    syncs: int | None = None      # device→host reads the path made
+
+
+class _Reads:
+    """The path's device→host reads: each one runs inside a
+    ``shotgun.path.sync`` span and is counted, at the same boundary."""
+
+    def __init__(self):
+        self.count = 0
+
+    def __call__(self, read: Callable, value):
+        self.count += 1
+        with jax.profiler.TraceAnnotation("shotgun.path.sync"):
+            return read(value)
+
+
+@jax.jit
+def _path_objective(x, prob: obj.Problem) -> jax.Array:
+    """F(x) at one λ of the path, as one program under its own scope."""
+    with jax.named_scope("shotgun.path.objective"):
+        return obj.objective(x, prob)
 
 
 def lambda_sequence(lam_max: float, lam_target: float, num: int = 10) -> np.ndarray:
@@ -101,6 +131,7 @@ def _solver_by_name(name: str, **solver_kwargs) -> Callable:
     raise ValueError(f"no path adapter for solver {name!r}")
 
 
+@functools.partial(jax.profiler.annotate_function, name="shotgun.path")
 def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
                P: int | None = None, rounds_per_lambda: int | None = None,
                num_lambdas: int = 10,
@@ -148,18 +179,20 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
             warnings.warn(
                 "solve_path(P=..., rounds_per_lambda=...) kwargs are "
                 "deprecated; pass spec=SolverSpec(...)", DeprecationWarning,
-                stacklevel=2)
+                stacklevel=3)
         P = 8 if P is None else P
         rounds_per_lambda = 200 if rounds_per_lambda is None else rounds_per_lambda
+    read = _Reads()
     if validate_p:
         from repro.core import spectral
-        ps = spectral.p_star(prob.A)
+        with jax.profiler.TraceAnnotation("shotgun.path.p_star"):
+            ps = read(int, spectral.p_star_array(prob.A))
         if P > ps:
             import warnings
             warnings.warn(
                 f"solve_path: P={P} exceeds the Thm 3.2 safe parallelism "
                 f"P*={ps} for this design; clamping to P*={ps} "
-                f"(pass validate_p=False to override)", stacklevel=2)
+                f"(pass validate_p=False to override)", stacklevel=3)
             P = ps
     if isinstance(solver, str):
         solver = _solver_by_name(solver, **solver_kwargs)
@@ -169,46 +202,52 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
             f"``solver`` is a registry name; got solver={solver!r}")
     elif solver is None:
         solver = lambda p, k, P, rounds, x0: shotgun.shotgun_solve(p, k, P=P, rounds=rounds, x0=x0)
-    lmax = float(obj.lambda_max(prob.A, prob.y, prob.loss))
+    with jax.profiler.TraceAnnotation("shotgun.path.lambda_max"):
+        lmax = read(float, obj.lambda_max(prob.A, prob.y, prob.loss))
     lams = lambda_sequence(lmax, lam_target, num_lambdas)
     dt = prob.A.dtype if hasattr(prob.A, "dtype") else jnp.float32
     x = jnp.zeros(prob.d, dt)
     objs, nnzs = [], []
     if cache is None:
         for i, lam in enumerate(lams):
-            key, sub = jax.random.split(key)
-            p_i = prob._replace(lam=jnp.float32(lam))
-            res = solver(p_i, sub, P, rounds_per_lambda, x)
-            x = res.x
-            objs.append(float(res.trace.objective[-1]))
-            nnzs.append(int(res.trace.nnz[-1]))
+            with jax.profiler.TraceAnnotation("shotgun.path.lambda", lam=i):
+                key, sub = jax.random.split(key)
+                p_i = prob._replace(lam=jnp.float32(lam))
+                res = solver(p_i, sub, P, rounds_per_lambda, x)
+                x = res.x
+                objs.append(read(float, res.trace.objective[-1]))
+                nnzs.append(read(int, res.trace.nnz[-1]))
         return PathResult(x=x, lambdas=lams, objectives=np.array(objs),
-                          nnz=np.array(nnzs))
+                          nnz=np.array(nnzs), syncs=read.count)
 
     from repro.core.batched import launch_converged
     pid = "path" if problem_id is None else problem_id
     chunk = _largest_divisor_leq(rounds_per_lambda, 8)
     rounds_used = []
     for i, lam in enumerate(lams):
-        p_i = prob._replace(lam=jnp.float32(lam))
-        x0, kind = cache.get(pid, float(lam), loss=prob.loss)
-        if kind != "miss":
-            x = jnp.asarray(x0, dt)      # cache hit beats in-sweep x
-        f_prev = float(obj.objective(x, p_i))
-        spent = 0
-        res = None
-        while spent < rounds_per_lambda:
-            key, sub = jax.random.split(key)
-            res = solver(p_i, sub, P, chunk, x)
-            x = res.x
-            spent += chunk
-            f_chunk = np.asarray(res.trace.objective)
-            if launch_converged(f_prev, f_chunk, tol):
-                break
-            f_prev = float(f_chunk[-1])
-        cache.put(pid, float(lam), np.asarray(x), loss=prob.loss)
-        rounds_used.append(spent)
-        objs.append(float(res.trace.objective[-1]))
-        nnzs.append(int(res.trace.nnz[-1]))
+        with jax.profiler.TraceAnnotation("shotgun.path.lambda", lam=i):
+            p_i = prob._replace(lam=jnp.float32(lam))
+            x0, kind = cache.get(pid, float(lam), loss=prob.loss)
+            if kind != "miss":
+                x = jnp.asarray(x0, dt)      # cache hit beats in-sweep x
+            f_prev = read(float, _path_objective(x, p_i))
+            spent = 0
+            res = None
+            while spent < rounds_per_lambda:
+                with jax.profiler.TraceAnnotation("shotgun.path.chunk",
+                                                  chunk=spent // chunk):
+                    key, sub = jax.random.split(key)
+                    res = solver(p_i, sub, P, chunk, x)
+                    x = res.x
+                    spent += chunk
+                    f_chunk = read(np.asarray, res.trace.objective)
+                    if launch_converged(f_prev, f_chunk, tol):
+                        break
+                    f_prev = float(f_chunk[-1])
+            cache.put(pid, float(lam), read(np.asarray, x), loss=prob.loss)
+            rounds_used.append(spent)
+            objs.append(read(float, res.trace.objective[-1]))
+            nnzs.append(read(int, res.trace.nnz[-1]))
     return PathResult(x=x, lambdas=lams, objectives=np.array(objs),
-                      nnz=np.array(nnzs), rounds=np.array(rounds_used))
+                      nnz=np.array(nnzs), rounds=np.array(rounds_used),
+                      syncs=read.count)

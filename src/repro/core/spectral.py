@@ -21,22 +21,32 @@ from repro.core import objectives as obj
 
 @functools.partial(jax.jit, static_argnames=("iters",))
 def spectral_radius(A, key: jax.Array | None = None, iters: int = 100) -> jax.Array:
-    """Largest eigenvalue of A^T A via power iteration with Rayleigh quotient."""
+    """Largest eigenvalue of A^T A via power iteration with Rayleigh quotient.
+
+    Its device ops run under the scope ``shotgun.p_star``."""
     d = A.shape[1]
     if key is None:
         key = jax.random.PRNGKey(0)
-    v0 = jax.random.normal(key, (d,), A.dtype)
-    v0 = v0 / jnp.linalg.norm(v0)
+    with jax.named_scope("shotgun.p_star"):
+        v0 = jax.random.normal(key, (d,), A.dtype)
+        v0 = v0 / jnp.linalg.norm(v0)
 
-    def step(v, _):
-        w = obj.rmatvec(A, obj.matvec(A, v))
-        nw = jnp.linalg.norm(w)
-        v = w / jnp.maximum(nw, 1e-30)
-        return v, nw
+        def step(v, _):
+            w = obj.rmatvec(A, obj.matvec(A, v))
+            nw = jnp.linalg.norm(w)
+            v = w / jnp.maximum(nw, 1e-30)
+            return v, nw
 
-    v, _ = jax.lax.scan(step, v0, None, length=iters)
-    Av = obj.matvec(A, v)
-    return jnp.vdot(Av, Av) / jnp.maximum(jnp.vdot(v, v), 1e-30)
+        v, _ = jax.lax.scan(step, v0, None, length=iters)
+        Av = obj.matvec(A, v)
+        return jnp.vdot(Av, Av) / jnp.maximum(jnp.vdot(v, v), 1e-30)
+
+
+def p_star_array(A: jax.Array, key: jax.Array | None = None,
+                 iters: int = 100) -> jax.Array:
+    """``p_star`` left on the device, for a caller that reads it itself."""
+    rho = spectral_radius(A, key, iters)
+    return jnp.ceil(A.shape[1] / jnp.maximum(rho, 1.0) - 0.01)
 
 
 def p_star(A: jax.Array, key: jax.Array | None = None, iters: int = 100) -> int:
@@ -45,9 +55,7 @@ def p_star(A: jax.Array, key: jax.Array | None = None, iters: int = 100) -> int:
     Power iteration approaches rho from below; the 1% slack keeps d/rho from
     landing epsilon above an integer (e.g. exactly-correlated features must
     give P* = 1, not 2)."""
-    rho = spectral_radius(A, key, iters)
-    d = A.shape[1]
-    return int(jnp.ceil(d / jnp.maximum(rho, 1.0) - 0.01))
+    return int(p_star_array(A, key, iters))
 
 
 def p_star_dup(A: jax.Array, key: jax.Array | None = None, iters: int = 100) -> int:

@@ -53,15 +53,27 @@ from repro.kernels.shotgun_sparse import (block_delta,
 def pad_problem(A, y, block=BLOCK, tile_n=TILE_N):
     """Zero-pad A to (n % tile_n == 0, d % block == 0).  Zero rows contribute
     nothing to gradients if y is padded with zeros *and* the loss is the
-    squared loss; for logistic we pad with a sample-weight mask instead."""
+    squared loss; for logistic we pad with a sample-weight mask instead.
+
+    An aligned design comes back as it is; any other is padded by one
+    program, ``jit_pad_problem`` in a profile."""
+    n, d = A.shape
+    if n % tile_n == 0 and d % block == 0:
+        return A, y, jnp.ones(n, A.dtype)
+    return _pad(A, y, block, tile_n)
+
+
+def _pad(A, y, block, tile_n):
     n, d = A.shape
     n_pad = (-n) % tile_n
-    d_pad = (-d) % block
-    if n_pad or d_pad:
-        A = jnp.pad(A, ((0, n_pad), (0, d_pad)))
-        y = jnp.pad(y, (0, n_pad))
+    A = jnp.pad(A, ((0, n_pad), (0, (-d) % block)))
+    y = jnp.pad(y, (0, n_pad))
     mask = jnp.pad(jnp.ones(n, A.dtype), (0, n_pad))
     return A, y, mask
+
+
+_pad.__name__ = "pad_problem"         # the program's name in a profile
+_pad = jax.jit(_pad, static_argnums=(2, 3))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "loss"))
@@ -146,6 +158,10 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
     trips is discarded wholesale — iterate and margin roll back to the
     last-good snapshot in the scan carry, k_eff halves — so divergence
     detection costs one scalar read per launch, not a trace scan.
+
+    Its device ops carry the scopes ``shotgun.warm_margin`` (z0 = A x0),
+    ``shotgun.draw`` (the block draws) and ``shotgun.rounds`` (the scan of
+    launches) in their ``op_name``.
     """
     n, d = A.shape
     nblk = d // block
@@ -157,21 +173,27 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
           else x0.astype(jnp.float32))
     # warm-start margin in f32 even for bf16-stored A (cast before the
     # matmul, not after — the accumulation itself is what must stay f32)
-    z0 = obj.matvec(A.astype(jnp.float32), x0)
-    draw = functools.partial(jax.random.choice, a=nblk, shape=(K,),
-                             replace=False)
-    keys = jax.random.split(key, rounds).reshape(L, R, -1)
+    with jax.named_scope("shotgun.warm_margin"):
+        z0 = obj.matvec(A.astype(jnp.float32), x0)
+
+    def draw(keys_l):
+        with jax.named_scope("shotgun.draw"):
+            return jax.vmap(lambda kt: jax.random.choice(
+                kt, nblk, (K,), replace=False))(keys_l).astype(jnp.int32)
+
+    with jax.named_scope("shotgun.draw"):
+        keys = jax.random.split(key, rounds).reshape(L, R, -1)
 
     if guard is None:
         def launch_fn(carry, keys_l):
             x, z = carry
-            idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
             x, z, fs, nnzs, _ = fused_shotgun_rounds(
-                A, z, x, idx, lam, beta, y, mask, loss=loss, block=block,
-                tile_n=tile_n)
+                A, z, x, draw(keys_l), lam, beta, y, mask, loss=loss,
+                block=block, tile_n=tile_n)
             return (x, z), (fs, nnzs)
 
-        (x, z), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0), keys)
+        with jax.named_scope("shotgun.rounds"):
+            (x, z), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0), keys)
         fs = fs.reshape(rounds)
         return Result(x=x, z=z,
                       trace=Trace(objective=fs, nnz=nnzs.reshape(rounds)),
@@ -181,10 +203,9 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
 
     def launch_fn(carry, keys_l):
         x, z, gs = carry
-        idx = jax.vmap(lambda kt: draw(kt))(keys_l).astype(jnp.int32)
         x_new, z_new, fs, nnzs, h = fused_shotgun_rounds(
-            A, z, x, idx, lam, beta, y, mask, loss=loss, block=block,
-            tile_n=tile_n, k_eff=gs.p_eff,
+            A, z, x, draw(keys_l), lam, beta, y, mask, loss=loss,
+            block=block, tile_n=tile_n, k_eff=gs.p_eff,
             guard_f=health.guard_threshold(gs.f_good, guard.factor))
         x, z, f_rep, gs, bad = health.apply_sentinel(
             gs, x_new, z_new, fs[-1], factor=guard.factor, p_floor=p_floor,
@@ -198,7 +219,8 @@ def _fused_solve(A, y, mask, lam, beta, key, K, rounds, R, block, tile_n,
     f0 = (obj.masked_data_loss(z0, y, mask, lname)
           + lam * jnp.sum(jnp.abs(x0)))
     gs0 = health.init_guard_state(x0, z0, f0, K)
-    (x, z, gs), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0, gs0), keys)
+    with jax.named_scope("shotgun.rounds"):
+        (x, z, gs), (fs, nnzs) = jax.lax.scan(launch_fn, (x0, z0, gs0), keys)
     fs = fs.reshape(rounds)
     return Result(x=x, z=z,
                   trace=Trace(objective=fs, nnz=nnzs.reshape(rounds)),
@@ -344,6 +366,7 @@ def _fused_sparse_solve(rows, vals, y, lam, beta, key, K, rounds, R, loss,
                   status=health.status_from_trace(fs, gs.backoffs))
 
 
+@functools.partial(jax.profiler.annotate_function, name="shotgun.solve")
 def block_shotgun_solve(prob: Problem, key: jax.Array,
                         K: int | None = None, rounds: int | None = None,
                         block: int = BLOCK,
@@ -388,6 +411,9 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
     ``DeprecationWarning``.  ``newton=True`` (or ``spec.newton``) swaps the
     β-Lipschitz step for the per-block Newton curvature computed from the
     already-fetched A tile — fused path only.
+
+    The host side (padding, dispatch, result slices; it returns before the
+    device finishes) runs in the profiler span ``shotgun.solve``.
     """
     if spec is not None:
         reject_legacy_kwargs(spec, K=K, rounds=rounds)
@@ -400,7 +426,7 @@ def block_shotgun_solve(prob: Problem, key: jax.Array,
             raise TypeError("block_shotgun_solve needs (K, rounds) or spec=")
         warnings.warn(
             "block_shotgun_solve(K=..., rounds=...) kwargs are deprecated; "
-            "pass spec=SolverSpec(...)", DeprecationWarning, stacklevel=2)
+            "pass spec=SolverSpec(...)", DeprecationWarning, stacklevel=3)
     loss = prob.loss
     if newton:
         if not fused:

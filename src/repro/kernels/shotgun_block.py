@@ -138,6 +138,7 @@ def gather_block_matvec(A, r, blk_idx, block: int = BLOCK,
         _gather_matvec_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
+        name="gather_block_matvec",
         **_call_params(interpret),
     )(blk_idx, A, r.reshape(n, 1))
 
@@ -185,6 +186,7 @@ def scatter_block_update(A, z, blk_idx, delta, block: int = BLOCK,
         _scatter_update_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        name="scatter_block_update",
         **_call_params(interpret),
     )(blk_idx, A, delta.astype(A.dtype), z.reshape(n, 1))
     return out.reshape(n).astype(z.dtype)
@@ -567,6 +569,8 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
         _make_fused_kernel(loss, R, K, T, block, tile_n, emit_dz=emit_dz),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=("fused_shotgun_delta_rounds" if emit_dz
+              else "fused_shotgun_rounds"),
         **_call_params(interpret),
     )(idx, scal, A, z0, x0, y2, m2)
 

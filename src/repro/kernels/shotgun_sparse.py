@@ -142,6 +142,7 @@ def sparse_gather_block_matvec(rows, vals, r, blk_idx,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
+        name="sparse_gather_block_matvec",
         **_call_params(interpret),
     )(blk_idx.astype(jnp.int32), rows, vals,
       r.reshape(n, 1).astype(jnp.float32))
@@ -200,6 +201,7 @@ def sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
         _make_scatter_kernel(K),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        name="sparse_scatter_block_update",
         **_call_params(interpret),
     )(blk_idx.astype(jnp.int32), rows, vals,
       delta.astype(jnp.float32), z.reshape(n, 1).astype(jnp.float32))
@@ -407,6 +409,8 @@ def _fused_sparse_call(rows, vals, z, x, blk_idx, lam, beta, y, loss,
         _make_fused_sparse_kernel(loss, K, emit_dz=emit_dz),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=("fused_sparse_shotgun_delta_rounds" if emit_dz
+              else "fused_sparse_shotgun_rounds"),
         **_call_params(interpret),
     )(idx, scal, rows, vals, z0, x0, y2)
 

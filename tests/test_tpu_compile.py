@@ -9,6 +9,7 @@ module fixture, never at import: only the worker that runs this file may
 load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +110,22 @@ def test_largest_admitted_n_compiles(one_chip):
     c = _compile(fused_shotgun_rounds, *_fused_args(one_chip, n, d, K),
                  loss="lasso", interpret=False)
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("emit_dz,name", [
+    (False, "fused_shotgun_rounds"), (True, "fused_shotgun_delta_rounds")])
+def test_fused_kernel_keeps_its_name_inside_any_jitted_caller(one_chip,
+                                                               emit_dz, name):
+    """The compiled custom call takes the kernel's own name
+    (``pallas_call(name=...)``), not that of the jitted function holding it:
+    a device trace finds the kernel by that name after its wrapper is
+    inlined or renamed."""
+    from repro.kernels.shotgun_block import _fused_call
+
+    def holder(A, z, x, idx, lam, beta, y, mask):
+        return _fused_call(A, z, x, idx, lam, beta, y, mask, "lasso", BLOCK,
+                           None, False, emit_dz=emit_dz)
+    text = _compile(jax.jit(holder),
+                    *_fused_args(one_chip, 4096, 8192, 4)).as_text()
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text)
+    assert "%holder" not in text
