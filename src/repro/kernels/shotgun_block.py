@@ -29,9 +29,11 @@ and the fused multi-round kernel (DESIGN §4.2):
   gather/scatter phases as above but without the z/r/g HBM round trips.
 
 Block size B = 128 (MXU/lane width); the two-phase sample tile is TILE_N
-= 512.  Every kernel compiles with ``vmem_limit_bytes = VMEM_BUDGET``; the
-VMEM model (each (n, 1) vector at 512 B per sample in (8, 128) tiles) is
-in ``fused_vmem_bytes`` and DESIGN §4.3.
+= 512.  The fused kernel keeps every sample-indexed vector as a lane-dense
+(1, n) row and works through its A tile SUB_TILE rows at a time.  Every
+kernel compiles with ``vmem_limit_bytes = VMEM_BUDGET``; the VMEM model
+(each (1, n) row at 4 B per sample) is in ``fused_vmem_bytes`` and DESIGN
+§4.3.
 """
 from __future__ import annotations
 
@@ -45,17 +47,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 128        # coordinate block width (MXU dimension)
 TILE_N = 512       # sample-dimension tile
+SUB_TILE = 4096    # rows of A (lanes of a row) per in-kernel chunk
 
 # Scoped-VMEM limit every kernel here is compiled with (``vmem_limit_bytes``)
 # and the ceiling ``fused_vmem_bytes`` / ``fused_sparse_vmem_bytes`` refuse
 # shapes against.  A v5e core has 128 MiB of VMEM and the compiler's default
-# scoped limit is 16 MiB.  The kernel's scoped area and the (n, 1) operands
-# XLA places in VMEM beside it must share the 128 MiB, so the model counts
-# both and 8 MiB stay free for the XLA ops around the kernel.  Rehearsed on a
-# described v5e: at the largest n ``auto_tile_n`` admits under this budget
-# (d=2048, K=4: lasso 40448, logistic Newton 24064, their Δz engine
-# variants 30208 / 22016) every fused variant compiles.
+# scoped limit is 16 MiB; 8 MiB stay free for the XLA ops around the kernel.
+# Rehearsed on a described v5e: at the largest n ``auto_tile_n`` admits
+# single-phase (d=2048, K=8: lasso 119808, logistic Newton and the lasso Δz
+# engine variant 119296) every fused variant compiles; the double-buffered
+# (n, 128) A panel is nearly all of it.
 VMEM_BUDGET = 120 * 2 ** 20
+MOSAIC_SLACK = 256 * 2 ** 10  # the compiler's spill slots beside the buffers
 
 
 def interpret_mode() -> bool:
@@ -90,6 +93,9 @@ def _check_divisible(n: int, d: int, block: int, tile_n: int) -> None:
         raise ValueError(f"d={d} not divisible by block={block}")
     if n % tile_n:
         raise ValueError(f"n={n} not divisible by tile_n={tile_n}")
+    if tile_n % 128:
+        raise ValueError(f"tile_n={tile_n} is not a multiple of 128: a tile "
+                         f"is a lane slice of the (1, n) sample rows")
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +323,7 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
     ``emit_dz`` selects the shard-local engine variant (DESIGN §3/§4.2): z0
     is a read-only *global* margin snapshot; the kernel still keeps its own
     live local view z_s = z0 + Σ own contributions in VMEM, but additionally
-    accumulates those contributions into a Δz scratch and outputs (Δz, x)
+    accumulates those contributions into its Δz output and outputs (Δz, x)
     instead of (z, x, f, nnz) — the caller merges Δz across shards (psum)
     and owns the trace bookkeeping.
 
@@ -331,7 +337,7 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
     scalar instead of scanning the trace.
 
     Per-block Newton (``loss.newton``, DESIGN §12): the round start also
-    snapshots the per-sample curvature weights w = L''(z) into a (n, 1)
+    snapshots the per-sample curvature weights w = L''(z) into a (1, n)
     scratch, and the gather phase accumulates the per-block diagonal
     curvature h_B = A_B²ᵀ w from the SAME already-fetched A tile (one extra
     dot_general, zero extra HBM traffic); the delta then divides by
@@ -339,15 +345,34 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
     single = T == 1
     newton = loss.newton
 
+    def chunks(length, body, carry):
+        """``body(off, size, carry)`` over [0, length) in ``SUB_TILE``-row
+        chunks, the last one shorter where ``SUB_TILE`` does not divide
+        ``length`` (every size a multiple of 128), so no value outgrows a
+        chunk whatever n is."""
+        full, tail = divmod(length, SUB_TILE)
+        if full:
+            carry = jax.lax.fori_loop(
+                0, full, lambda i, c: body(
+                    pl.multiple_of(i * SUB_TILE, SUB_TILE), SUB_TILE, c),
+                carry)
+        return body(full * SUB_TILE, tail, carry) if tail else carry
+
+    def over_row(body, init):
+        """``body(lanes, carry)`` over a whole (1, n) row, chunk by chunk."""
+        return chunks(T * tile_n,
+                      lambda off, size, c: body(pl.ds(off, size), c), init)
+
     def kernel(idx_ref, scal_ref, a_ref, z0_ref, x0_ref, y_ref, m_ref,
                *refs):
         if newton:
             refs, (w_s, c_s) = refs[:-2], refs[-2:]
         if emit_dz:
-            (dzo_ref, xo_ref, h_ref, z_s, dz_s, r_s, x_s, g_s, d_s) = refs
+            # Δz accumulates in its own output block; z_ref is the live view.
+            (dz_ref, xo_ref, h_ref, z_ref, r_s, x_s, g_s, d_s) = refs
         else:
-            (zo_ref, xo_ref, f_ref, nnz_ref, h_ref,
-             z_s, r_s, x_s, g_s, d_s) = refs
+            # The z output block is the live margin itself.
+            (z_ref, xo_ref, f_ref, nnz_ref, h_ref, r_s, x_s, g_s, d_s) = refs
         r_id = pl.program_id(0)
         k_id = pl.program_id(1)
         if single:
@@ -368,42 +393,66 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
 
         @pl.when(first_step)
         def _init_launch():
-            z_s[...] = z0_ref[...]
+            def init(lanes, carry):
+                z_ref[:, lanes] = z0_ref[:, lanes]
+                if emit_dz:
+                    dz_ref[:, lanes] = jnp.zeros_like(z0_ref[:, lanes])
+                return carry
+
+            over_row(init, 0)
             x_s[...] = x0_ref[...]
             h_ref[0, 0] = jnp.float32(0.0)
-            if emit_dz:
-                dz_s[...] = jnp.zeros_like(dz_s)
 
         @pl.when((k_id == 0) & gather_on & (t_id == 0))
         def _round_start():
-            r_s[...] = loss.residual(z_s[...], y_ref[...], m_ref[...])
-            if newton:
-                # Curvature weights from the SAME round-start margin the
-                # residual uses — all K blocks see pre-round curvature,
-                # preserving Alg. 2's multiset semantics.
-                w_s[...] = loss.curvature_weights(z_s[...], y_ref[...],
-                                                  m_ref[...])
+            def start(lanes, carry):
+                z, y, m = z_ref[:, lanes], y_ref[:, lanes], m_ref[:, lanes]
+                r_s[:, lanes] = loss.residual(z, y, m)
+                if newton:
+                    # Curvature weights from the SAME round-start margin the
+                    # residual uses — all K blocks see pre-round curvature,
+                    # preserving Alg. 2's multiset semantics.
+                    w_s[:, lanes] = loss.curvature_weights(z, y, m)
+                return carry
 
-        a = a_ref[...].astype(jnp.float32)          # (tile_n, block)
+            over_row(start, 0)
+
+        # The step's A tile is consumed chunk by chunk, each paired with
+        # the matching lane slice of the (1, n) rows.
+        base = 0 if single else t_id * tile_n
+
+        def tile_chunks(body, carry):
+            def step(off, size, c):
+                a = a_ref[pl.ds(off, size), :].astype(jnp.float32)
+                lanes = pl.ds(pl.multiple_of(base + off, 128), size)
+                return body(a, lanes, c)
+            return chunks(tile_n, step, carry)
 
         @pl.when(gather_on)
         def _gather_phase():
+            row = pl.ds(k_id, 1)
+
             @pl.when(t_id == 0)
             def _zero_g():
-                g_s[pl.ds(k_id, 1), :] = jnp.zeros((1, block), jnp.float32)
+                g_s[row, :] = jnp.zeros((1, block), jnp.float32)
                 if newton:
-                    c_s[pl.ds(k_id, 1), :] = jnp.zeros((1, block),
-                                                       jnp.float32)
+                    c_s[row, :] = jnp.zeros((1, block), jnp.float32)
 
-            rt = r_s[pl.ds(t_id * tile_n, tile_n), :]   # (tile_n, 1)
-            contrib = _f32_dot(a, rt, ((0,), (0,)))      # (block, 1)
-            g_s[pl.ds(k_id, 1), :] += contrib.reshape(1, block)
+            def gather(a, lanes, carry):
+                g, c = carry
+                g += _f32_dot(r_s[:, lanes], a, ((1,), (0,)))   # (1, block)
+                if newton:
+                    # h_B += w (a∘a) from the chunk already in VMEM: the
+                    # Newton curvature costs one more dot, no more A bytes.
+                    c += _f32_dot(w_s[:, lanes], a * a, ((1,), (0,)))
+                return g, c
+
+            g, c = tile_chunks(
+                gather,
+                (g_s[row, :], c_s[row, :] if newton else jnp.float32(0.0)))
+            g_s[row, :] = g
             if newton:
-                # h_B += (a∘a)ᵀ w from the tile already in VMEM: the Newton
-                # curvature costs one extra dot_general, no extra A bytes.
-                wt = w_s[pl.ds(t_id * tile_n, tile_n), :]
-                hc = _f32_dot(a * a, wt, ((0,), (0,)))  # (block, 1)
-                c_s[pl.ds(k_id, 1), :] += hc.reshape(1, block)
+                c_s[row, :] = c
 
             @pl.when(t_id == T - 1)
             def _delta():
@@ -412,27 +461,31 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
                 # a round reproduce Alg. 2's multiset semantics exactly.
                 b = idx_ref[r_id, k_id]
                 x_sel = x_s[pl.ds(b, 1), :]
-                g = g_s[pl.ds(k_id, 1), :]
                 if newton:
                     # Per-block Newton: divide by the accumulated diagonal
                     # curvature, floored (zero/padded columns fall back to a
                     # tiny h whose threshold λ/h kills the step anyway).
-                    h = jnp.maximum(c_s[pl.ds(k_id, 1), :], 1e-8)
+                    h = jnp.maximum(c_s[row, :], 1e-8)
                 else:
                     h = beta
-                x_new = _soft_threshold(x_sel - g / h, lam / h)
+                x_new = _soft_threshold(x_sel - g_s[row, :] / h, lam / h)
                 # Backoff mask: blocks at or past k_eff contribute nothing
                 # this round (multiply by exactly 1.0 when k_eff == K).
                 live = jnp.where(k_id < k_eff, 1.0, 0.0).astype(jnp.float32)
-                d_s[pl.ds(k_id, 1), :] = (x_new - x_sel) * live
+                d_s[row, :] = (x_new - x_sel) * live
 
         @pl.when(scatter_on)
         def _scatter_phase():
             dlt = d_s[pl.ds(k_id, 1), :]                 # (1, block)
-            contrib = _f32_dot(a, dlt, ((1,), (1,)))      # (tile_n, 1)
-            z_s[pl.ds(t_id * tile_n, tile_n), :] += contrib
-            if emit_dz:
-                dz_s[pl.ds(t_id * tile_n, tile_n), :] += contrib
+
+            def scatter(a, lanes, carry):
+                contrib = _f32_dot(dlt, a, ((1,), (1,)))  # (1, size)
+                z_ref[:, lanes] += contrib
+                if emit_dz:
+                    dz_ref[:, lanes] += contrib
+                return carry
+
+            tile_chunks(scatter, 0)
 
             @pl.when((k_id == K - 1) & (t_id == T - 1))
             def _round_end():
@@ -444,25 +497,29 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
                 jax.lax.fori_loop(0, K, apply_delta, 0)
                 # Constant-index outputs flush to HBM once, after the last
                 # grid step; rewriting them every round is free in VMEM.
+                xo_ref[...] = x_s[...]
                 if emit_dz:
-                    dzo_ref[...] = dz_s[...]
-                    xo_ref[...] = x_s[...]
                     # Engine variant has no in-kernel objective; the health
                     # scalar trips on a non-finite margin view instead.
-                    ok = jnp.all(jnp.isfinite(z_s[...]))
+                    def nonfinite(lanes, bad):
+                        return jnp.maximum(bad, jnp.max(jnp.where(
+                            jnp.isfinite(z_ref[:, lanes]), 0.0, 1.0)))
+
                     h_ref[0, 0] = jnp.maximum(
-                        h_ref[0, 0], jnp.where(ok, 0.0, 1.0))
+                        h_ref[0, 0], over_row(nonfinite, jnp.float32(0.0)))
                 else:
-                    f = loss.objective(z_s[...], y_ref[...], m_ref[...],
-                                       x_s[...], lam)
+                    def data(lanes, acc):
+                        return acc + loss.data_loss(
+                            z_ref[:, lanes], y_ref[:, lanes], m_ref[:, lanes])
+
+                    f = (over_row(data, jnp.float32(0.0))
+                         + lam * jnp.sum(jnp.abs(x_s[...])))
                     f_ref[r_id, 0] = f
                     bad = ~jnp.isfinite(f) | (f > guard)
                     h_ref[0, 0] = jnp.maximum(
                         h_ref[0, 0], jnp.where(bad, 1.0, 0.0))
                     nnz_ref[r_id, 0] = jnp.sum(
                         (x_s[...] != 0).astype(jnp.int32))
-                    zo_ref[...] = z_s[...]
-                    xo_ref[...] = x_s[...]
 
     return kernel
 
@@ -495,10 +552,11 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
                           jnp.float32)
     scal = jnp.stack([jnp.asarray(lam, jnp.float32),
                       jnp.asarray(beta, jnp.float32), k_eff, guard_f])
-    z0 = z.reshape(n, 1).astype(jnp.float32)
+    # Every sample-indexed vector is a lane-dense (1, n) row.
+    z0 = z.reshape(1, n).astype(jnp.float32)
     x0 = x.reshape(nblk, block).astype(jnp.float32)
-    y2 = y.reshape(n, 1).astype(jnp.float32)
-    m2 = mask.reshape(n, 1).astype(jnp.float32)
+    y2 = y.reshape(1, n).astype(jnp.float32)
+    m2 = mask.reshape(1, n).astype(jnp.float32)
 
     if single:
         grid = (R, K)
@@ -515,26 +573,26 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     if emit_dz:
         out_specs = [
-            pl.BlockSpec((n, 1), const),            # Δz
+            pl.BlockSpec((1, n), const),            # Δz
             pl.BlockSpec((nblk, block), const),     # x
             smem,                                   # health scalar
         ]
         out_shape = [
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((nblk, block), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ]
-        extra_scratch = [pltpu.VMEM((n, 1), jnp.float32)]   # Δz accumulator
+        extra_scratch = [pltpu.VMEM((1, n), jnp.float32)]   # z (live view)
     else:
         out_specs = [
-            pl.BlockSpec((n, 1), const),            # z
+            pl.BlockSpec((1, n), const),            # z
             pl.BlockSpec((nblk, block), const),     # x
             smem,                                   # f trace
             smem,                                   # nnz trace
             smem,                                   # health scalar
         ]
         out_shape = [
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((nblk, block), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.int32),
@@ -547,21 +605,19 @@ def _fused_call(A, z, x, blk_idx, lam, beta, y, mask, loss, block, tile_n,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_n, block), a_map),   # streamed A block
-            pl.BlockSpec((n, 1), const),            # z0   (VMEM-resident)
+            pl.BlockSpec((1, n), const),            # z0   (VMEM-resident)
             pl.BlockSpec((nblk, block), const),     # x0   (VMEM-resident)
-            pl.BlockSpec((n, 1), const),            # y    (VMEM-resident)
-            pl.BlockSpec((n, 1), const),            # mask (VMEM-resident)
+            pl.BlockSpec((1, n), const),            # y    (VMEM-resident)
+            pl.BlockSpec((1, n), const),            # mask (VMEM-resident)
         ],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((n, 1), jnp.float32),        # z  (live local view)
-        ] + extra_scratch + [
-            pltpu.VMEM((n, 1), jnp.float32),        # r  (round-start residual)
+        scratch_shapes=extra_scratch + [
+            pltpu.VMEM((1, n), jnp.float32),        # r  (round-start residual)
             pltpu.VMEM((nblk, block), jnp.float32),  # x
             pltpu.VMEM((K, block), jnp.float32),    # g  accumulators
             pltpu.VMEM((K, block), jnp.float32),    # delta
         ] + ([
-            pltpu.VMEM((n, 1), jnp.float32),        # w  curvature weights
+            pltpu.VMEM((1, n), jnp.float32),        # w  curvature weights
             pltpu.VMEM((K, block), jnp.float32),    # h  curvature accumulators
         ] if loss.newton else []),
     )
@@ -645,8 +701,10 @@ def fused_shotgun_delta_rounds(A, z, x, blk_idx, lam, beta, y, mask,
 
 def vec_vmem_bytes(rows: int, cols: int = 1) -> int:
     """VMEM bytes of a (rows, cols) f32 buffer as Mosaic lays it out: in
-    (8, 128) tiles, so an (n, 1) vector costs 512 B per sample, not 4."""
-    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+    (8, 128) tiles, so an (n, 1) column costs 512 B per sample, and a
+    single row in (1, 128) tiles, so a (1, n) row costs 4."""
+    sub = 1 if rows == 1 else 8
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * 4
 
 
 def check_vmem(need: int, what: str) -> None:
@@ -665,20 +723,20 @@ def fused_vmem_bytes(n: int, d: int, K: int, block: int = BLOCK,
                      loss: str | Loss = "lasso") -> int:
     """VMEM the dense fused kernel needs — the twin of
     ``shotgun_sparse.fused_sparse_vmem_bytes`` for ``_fused_call``'s
-    buffers, each priced at its (8, 128)-tiled layout (``vec_vmem_bytes``).
+    buffers, each priced at its tiled layout (``vec_vmem_bytes``).
 
-    Every (n, 1) vector counts 512 B per sample: the z0/y/mask in-vectors
-    and the z (or Δz) out-vector, which XLA places in VMEM beside the
-    kernel's scoped area; the z/r scratch (+ Δz scratch for the ``emit_dz``
-    engine variant, + the curvature-weight scratch for a Newton spec); and
-    the (n, 1) temporaries the compiler allocates for the loss math — three
-    for the logistic tile, one for the engine variant's finiteness check.
-    Then the three full-d x buffers (x0/scratch/out), the (K, block) g/δ
-    (+ Newton h) scratches, and the double-buffered streamed (tile_n,
-    block) A tile.  ``a_bytes`` is the stored dtype of A (4 = f32, 2 =
-    bf16).  R never enters: the (R, K) index matrix and the (R, 1) traces
-    live in SMEM.  Calibrated against the v5e compiler's own scoped-VMEM
-    reports; it over-counts by at most two n-vectors.
+    Every sample-indexed vector is a (1, n) row at 4 B per sample: the
+    z0/y/mask in-rows, the z (or Δz) out-row, which is also the live
+    margin (or the Δz accumulator), the r scratch (+ the live-view z
+    scratch for the ``emit_dz`` engine variant, + the curvature-weight
+    scratch for a Newton spec).  The loss math runs one ``SUB_TILE`` chunk
+    at a time, so it adds no n-sized temporary.  Then the three
+    full-d x buffers (x0/scratch/out), the (K, block) g/δ (+ Newton h)
+    scratches, the double-buffered streamed (tile_n, block) A tile, and
+    ``MOSAIC_SLACK`` for the compiler's own spill slots.  ``a_bytes`` is
+    the stored dtype of A (4 = f32, 2 = bf16).  R never enters: the (R, K)
+    index matrix and the (R, 1) traces live in SMEM.  Calibrated against
+    the v5e compiler's own VMEM reports.
 
     ``slots`` is the batched-launch multiplier (DESIGN §11): the vmapped
     entry points (``kernels/batched.py``) stack S independent problems on
@@ -690,14 +748,12 @@ def fused_vmem_bytes(n: int, d: int, K: int, block: int = BLOCK,
     if tile_n is None:
         tile_n = auto_tile_n(n, block, d=d, K=K, loss=spec, emit_dz=emit_dz,
                              a_bytes=a_bytes)
-    # z0/y/mask in, z-or-Δz out, z/r scratch (+ Δz, + Newton w scratch)
-    nvec = 6 + emit_dz + spec.newton
-    nvec += 3 if spec.name == LOGISTIC else int(emit_dz)   # temporaries
-    vecs = nvec * vec_vmem_bytes(n)
+    # z0/y/mask in, z-or-Δz out, r scratch (+ z view scratch, + Newton w)
+    vecs = (5 + emit_dz + spec.newton) * vec_vmem_bytes(1, n)
     xbuf = 3 * vec_vmem_bytes(d // block, block)   # x0, x scratch, x out
     kbuf = (3 if spec.newton else 2) * vec_vmem_bytes(K, block)
     tiles = 2 * tile_n * block * a_bytes           # double-buffered A tile
-    return slots * (vecs + xbuf + kbuf + tiles)
+    return slots * (vecs + xbuf + kbuf + tiles + MOSAIC_SLACK)
 
 
 def auto_tile_n(n: int, block: int = BLOCK, d: int = 0, K: int = 1,
@@ -705,18 +761,22 @@ def auto_tile_n(n: int, block: int = BLOCK, d: int = 0, K: int = 1,
                 a_bytes: int = 4) -> int:
     """Largest sample tile whose ``fused_vmem_bytes`` fits ``VMEM_BUDGET``.
     Prefers tile_n == n (single-phase fused kernel, one A fetch per block
-    per round) whenever it fits, else ``TILE_N`` (two-phase).  Raises
-    ``ValueError`` naming the limit when even that does not fit: the
-    resident (n, 1) vectors and x buffers alone exceed it.  See DESIGN
-    §4.3."""
+    per round) whenever it fits, else ``TILE_N`` (two-phase).  A tile is a
+    lane slice of the (1, n) rows, so n must be a multiple of 128.  Raises
+    ``ValueError`` naming the limit when even ``TILE_N`` does not fit: the
+    resident (1, n) rows and x buffers alone exceed it.  See DESIGN §4.3."""
+    if n % 128:
+        raise ValueError(f"n={n} is not a multiple of 128: a tile is a lane "
+                         f"slice of the (1, n) sample rows")
+
     def need(tile):
         return fused_vmem_bytes(n, d, K, block, tile, emit_dz, a_bytes,
                                 loss=loss)
 
     if need(n) <= VMEM_BUDGET:
         return n
-    tile = max(TILE_N, block)
+    tile = TILE_N
     while n % tile:            # n is pre-padded to a TILE_N multiple by
-        tile //= 2             # ops.pad_problem, so this terminates >= 8
+        tile //= 2             # ops.pad_problem; 128 always divides it
     check_vmem(need(tile), f"fused kernel (n={n}, d={d}, K={K})")
     return tile

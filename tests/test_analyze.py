@@ -264,8 +264,8 @@ def test_findings_deterministic_ordering(tmp_path):
 
 def test_sl101_catches_oversized_scratch_config():
     from repro.analyze.trace_checks import check_vmem
-    over = {"kind": "dense", "n": 65536, "d": 131072, "K": 8,
-            "tile_n": 65536, "label": "oversized"}
+    over = {"kind": "dense", "n": 131072, "d": 131072, "K": 8,
+            "tile_n": 131072, "label": "oversized"}
     fits = {"kind": "dense", "n": 1024, "d": 2048, "K": 4}
     fs = check_vmem(REPO, configs=[over, fits])
     assert len(fs) == 1 and fs[0].rule == "SL101"
@@ -391,8 +391,8 @@ def test_cli_exits_nonzero_on_seeded_tree(tmp_path):
         import jax
         import jax.numpy as jnp
 
-        VMEM_CONFIGS = [{"kind": "dense", "n": 65536, "d": 131072, "K": 8,
-                         "tile_n": 65536, "label": "oversized"}]
+        VMEM_CONFIGS = [{"kind": "dense", "n": 131072, "d": 131072, "K": 8,
+                         "tile_n": 131072, "label": "oversized"}]
 
         @functools.partial(jax.jit, static_argnames=("lam",))
         def _leaky(x, lam):

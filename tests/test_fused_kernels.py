@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import objectives as obj
 from repro.data import synthetic as syn
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, shotgun_block
 from repro.kernels.shotgun_block import (BLOCK, VMEM_BUDGET, auto_tile_n,
                                          fused_shotgun_rounds,
                                          fused_vmem_bytes)
@@ -52,6 +52,35 @@ def test_fused_rounds_match_oracle(loss, tile_n):
     xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
         Ap, z, x, idx, prob.lam, prob.beta, yp, mask, loss, BLOCK)
 
+    np.testing.assert_allclose(np.asarray(xk), np.asarray(xr),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(zk), np.asarray(zr),
+                               rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(fk), np.asarray(fr),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(nr))
+
+
+@pytest.mark.parametrize("loss", [obj.LASSO, "logistic_newton"])
+@pytest.mark.parametrize("tile_n", [None, 128])
+def test_fused_rounds_in_chunks_match_oracle(monkeypatch, loss, tile_n):
+    """n = 640 in 256-row chunks: two whole chunks and a 128-row tail
+    single-phase, a tail alone per 128-row tile two-phase."""
+    monkeypatch.setattr(shotgun_block, "SUB_TILE", 256)
+    jax.clear_caches()
+    A, y, _ = syn.logistic_data(seed=3, n=600, d=500)
+    prob = obj.make_problem(A, y, lam=1.0, loss=(
+        obj.LASSO if loss == obj.LASSO else obj.LOGISTIC))
+    Ap, yp, mask = ops.pad_problem(prob.A, prob.y, tile_n=128)
+    assert Ap.shape[0] == 640
+    x, z = _warm_start(Ap, scale=0.01)
+    idx = _idx_with_duplicates(Ap.shape[1] // BLOCK, 8, 2)
+    xk, zk, fk, nk, _h = fused_shotgun_rounds(
+        Ap, z, x, idx, prob.lam, prob.beta, yp, mask, loss=loss,
+        tile_n=tile_n)
+    xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
+        Ap, z, x, idx, prob.lam, prob.beta, yp, mask, loss, BLOCK)
+    jax.clear_caches()
     np.testing.assert_allclose(np.asarray(xk), np.asarray(xr),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(zk), np.asarray(zr),
@@ -108,17 +137,36 @@ def test_fused_bf16_storage():
 def test_auto_tile_n():
     assert auto_tile_n(512, d=512) == 512     # whole-n tile -> single phase
     assert auto_tile_n(2048, d=8192) == 2048  # benchmark shape fits easily
-    big = auto_tile_n(1 << 15)
-    assert big < (1 << 15) and (1 << 15) % big == 0
-    # at 512 B per sample the resident (n, 1) vectors alone outgrow the
+    # the double-buffered (n, 128) A panel alone outgrows the budget
+    big = auto_tile_n(1 << 17)
+    assert big < (1 << 17) and (1 << 17) % big == 0
+    # at 4 B per sample per (1, n) row the resident rows alone outgrow the
     # budget: refused up front, naming the limit
     with pytest.raises(ValueError, match="VMEM_BUDGET"):
-        auto_tile_n(1 << 20)
+        auto_tile_n(1 << 23)
     # large d pins 3 full-d x buffers in VMEM: must veto single-phase even
     # though the A tile alone would fit
     n = 8192
     spare = VMEM_BUDGET - fused_vmem_bytes(n, 0, 1, tile_n=n)
     assert auto_tile_n(n, d=(spare // 12 // BLOCK + 1) * BLOCK) < n
+
+
+def test_zeta_cell_shape_runs_single_phase():
+    """The Newton kernel at the zeta cell's padded shape takes the whole
+    n as one tile (T == 1: one A-panel fetch per block a round), because
+    each sample-indexed vector is a (1, n) row priced at 4 B a sample."""
+    n, d, K = 24064, 2048, 8
+    assert auto_tile_n(n, d=d, K=K, loss="logistic_newton") == n
+    for loss, dz, rows in [("lasso", False, 5), ("lasso", True, 6),
+                           ("logistic_newton", False, 6),
+                           ("logistic_newton", True, 7)]:
+        grow = (fused_vmem_bytes(n + 128, d, K, tile_n=128, emit_dz=dz,
+                                 loss=loss)
+                - fused_vmem_bytes(n, d, K, tile_n=128, emit_dz=dz,
+                                   loss=loss))
+        assert grow == rows * 128 * 4
+    with pytest.raises(ValueError, match="multiple of 128"):
+        auto_tile_n(24000, d=d, K=K)
 
 
 def test_fused_solve_trace_parity():

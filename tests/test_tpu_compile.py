@@ -96,19 +96,43 @@ def test_two_kernel_round_compiles(one_chip, kernel):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_largest_admitted_n_compiles(one_chip):
-    """The VMEM model is the compiler's: the largest n ``auto_tile_n``
-    admits under ``VMEM_BUDGET`` compiles, and the next is refused up front
-    with the limit named."""
-    d, K, step = 2048, 4, 512
-    n = step
-    while fused_vmem_bytes(n + step, d, K, tile_n=512) <= VMEM_BUDGET:
-        n += step
-    assert auto_tile_n(n, d=d, K=K) == 512
-    with pytest.raises(ValueError, match="VMEM_BUDGET"):
-        auto_tile_n(n + step, d=d, K=K)
+def test_zeta_cell_shape_compiles_single_phase(one_chip):
+    """The Newton kernel at the zeta cell's padded shape (n = 24064,
+    d = 2048, K = 8) takes the whole n as its tile (T == 1) and compiles."""
+    n, d, K = 24064, 2048, 8
+    assert auto_tile_n(n, d=d, K=K, loss="logistic_newton") == n
     c = _compile(fused_shotgun_rounds, *_fused_args(one_chip, n, d, K),
-                 loss="lasso", interpret=False)
+                 loss="logistic_newton", tile_n=None, interpret=False)
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("d,K,loss,single", [
+    (2048, 8, "logistic_newton", True),     # the A panel bounds n
+    (256, 4, "lasso", False),               # the (1, n) rows bound n
+])
+def test_largest_admitted_n_compiles(one_chip, d, K, loss, single):
+    """The VMEM model is the compiler's: the largest n ``auto_tile_n``
+    admits under ``VMEM_BUDGET`` compiles, single-phase or on 512-row
+    tiles, and the next falls back to tiles or is refused up front with
+    the limit named.  (Two-phase at d = 256, where A still fits HBM.)"""
+    step = 512
+
+    def fits(n):
+        return fused_vmem_bytes(n, d, K, tile_n=n if single else 512,
+                                loss=loss) <= VMEM_BUDGET
+    n = step
+    while fits(2 * n):
+        n *= 2
+    while fits(n + step):
+        n += step
+    assert auto_tile_n(n, d=d, K=K, loss=loss) == (n if single else 512)
+    if single:
+        assert auto_tile_n(n + step, d=d, K=K, loss=loss) == 512
+    else:
+        with pytest.raises(ValueError, match="VMEM_BUDGET"):
+            auto_tile_n(n + step, d=d, K=K, loss=loss)
+    c = _compile(fused_shotgun_rounds, *_fused_args(one_chip, n, d, K),
+                 loss=loss, interpret=False)
     assert "tpu_custom_call" in c.as_text()
 
 
