@@ -17,6 +17,11 @@ is recorded unless a trace is running): host spans ``shotgun.path``,
 and ``shotgun.path.sync`` around every device→host read, which
 ``PathResult.syncs`` counts; the per-λ objective's device ops run under
 the scope ``shotgun.path.objective``.
+
+The host reads from the chip only where a decision needs a value: one
+read a chunk carries all that chunk's values, and x stays on the chip
+from λ to λ unless the cache hands back an x this path did not write
+(``PathResult.syncs``, ``PathResult.uploads``).
 """
 from __future__ import annotations
 
@@ -38,7 +43,13 @@ class PathResult(NamedTuple):
     objectives: np.ndarray        # final objective at each lambda
     nnz: np.ndarray               # sparsity along the path
     rounds: np.ndarray | None = None   # rounds spent per lambda (cache= only)
-    syncs: int | None = None      # device→host reads the path made
+    # device→host reads: P* (validate_p), λ_max, then one a chunk with a
+    # cache (its F and nnz traces, its x, and F(x0) on a λ's first chunk),
+    # or one at the end without (every λ's traces)
+    syncs: int | None = None
+    # cache hits whose x0 went to the device: entries this path did not
+    # write (a hit on the x it last put keeps the device's copy)
+    uploads: int | None = None
 
 
 class _Reads:
@@ -164,6 +175,13 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
     (the default) keeps the fixed-budget behavior and key schedule
     bit-for-bit.
 
+    Reads: with a cache, each chunk is one read of its F and nnz traces
+    and its x, and a λ's first chunk also carries F(x0), dispatched just
+    before it; the λ's F, nnz and cached x are the last chunk's.  A cache
+    hit on the x this path last put keeps the device x (the same values);
+    any other hit uploads x0 and counts in ``PathResult.uploads``.
+    Without a cache the path reads once, at its end.
+
     ``spec=SolverSpec(...)`` is the canonical interface (DESIGN §12): P =
     spec.P, rounds_per_lambda = spec.rounds, with ``spec.loss`` validated
     against ``prob.loss``.  The legacy (P, rounds_per_lambda) kwargs still
@@ -207,32 +225,36 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
     lams = lambda_sequence(lmax, lam_target, num_lambdas)
     dt = prob.A.dtype if hasattr(prob.A, "dtype") else jnp.float32
     x = jnp.zeros(prob.d, dt)
-    objs, nnzs = [], []
     if cache is None:
+        traces = []
         for i, lam in enumerate(lams):
             with jax.profiler.TraceAnnotation("shotgun.path.lambda", lam=i):
                 key, sub = jax.random.split(key)
                 p_i = prob._replace(lam=jnp.float32(lam))
                 res = solver(p_i, sub, P, rounds_per_lambda, x)
                 x = res.x
-                objs.append(read(float, res.trace.objective[-1]))
-                nnzs.append(read(int, res.trace.nnz[-1]))
-        return PathResult(x=x, lambdas=lams, objectives=np.array(objs),
-                          nnz=np.array(nnzs), syncs=read.count)
+                traces.append((res.trace.objective, res.trace.nnz))
+        fs, ks = zip(*read(jax.device_get, traces))
+        return PathResult(x=x, lambdas=lams,
+                          objectives=np.array([float(f[-1]) for f in fs]),
+                          nnz=np.array([int(k[-1]) for k in ks]),
+                          syncs=read.count, uploads=0)
 
     from repro.core.batched import launch_converged
     pid = "path" if problem_id is None else problem_id
     chunk = _largest_divisor_leq(rounds_per_lambda, 8)
-    rounds_used = []
+    objs, nnzs, rounds_used = [], [], []
+    uploads, x_host = 0, None    # x_host: the last chunk's x, as cached
     for i, lam in enumerate(lams):
         with jax.profiler.TraceAnnotation("shotgun.path.lambda", lam=i):
             p_i = prob._replace(lam=jnp.float32(lam))
             x0, kind = cache.get(pid, float(lam), loss=prob.loss)
-            if kind != "miss":
+            if kind != "miss" and x0 is not x_host:
                 x = jnp.asarray(x0, dt)      # cache hit beats in-sweep x
-            f_prev = read(float, _path_objective(x, p_i))
+                uploads += 1
+            # F(x0) stays on the device and is read with the first chunk
+            f_prev = _path_objective(x, p_i)
             spent = 0
-            res = None
             while spent < rounds_per_lambda:
                 with jax.profiler.TraceAnnotation("shotgun.path.chunk",
                                                   chunk=spent // chunk):
@@ -240,14 +262,16 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
                     res = solver(p_i, sub, P, chunk, x)
                     x = res.x
                     spent += chunk
-                    f_chunk = read(np.asarray, res.trace.objective)
+                    f_prev, f_chunk, nnz_chunk, x_host = read(
+                        jax.device_get,
+                        (f_prev, res.trace.objective, res.trace.nnz, x))
                     if launch_converged(f_prev, f_chunk, tol):
                         break
                     f_prev = float(f_chunk[-1])
-            cache.put(pid, float(lam), read(np.asarray, x), loss=prob.loss)
+            cache.put(pid, float(lam), x_host, loss=prob.loss)
             rounds_used.append(spent)
-            objs.append(read(float, res.trace.objective[-1]))
-            nnzs.append(read(int, res.trace.nnz[-1]))
+            objs.append(float(f_chunk[-1]))
+            nnzs.append(int(nnz_chunk[-1]))
     return PathResult(x=x, lambdas=lams, objectives=np.array(objs),
                       nnz=np.array(nnzs), rounds=np.array(rounds_used),
-                      syncs=read.count)
+                      syncs=read.count, uploads=uploads)

@@ -1,9 +1,14 @@
 """The λ path's own spans and read counter, and the kernels' names.
 
-``solve_path`` marks its steps with ``jax.profiler.TraceAnnotation`` spans
-and counts its device→host reads in ``PathResult.syncs``; neither may
-change what it computes.  Every ``pallas_call`` carries a name of its own.
+``solve_path`` marks its steps with ``jax.profiler.TraceAnnotation`` spans,
+counts its device→host reads in ``PathResult.syncs`` (one a chunk, none
+elsewhere in a λ) and the cached x it sends back in ``PathResult.uploads``;
+none of it may change what it computes.  Every ``pallas_call`` carries a
+name of its own.
 """
+import contextlib
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,15 +33,17 @@ def _problem(loss):
     return obj.make_problem(A, y, lam, loss=loss, normalize=False), lam
 
 
-def _loop_without_spans(prob, key, lam, spec, num_lambdas, tol):
+def _loop_without_spans(prob, key, lam, spec, num_lambdas, tol, cache=None):
     """The warm-started path loop as it stood before it had spans: eager
-    reads and an eager objective, nothing traced."""
+    reads and an eager objective, nothing traced, every cache hit
+    uploaded."""
     P = min(spec.P, spectral.p_star(prob.A))
     solver = _solver_by_name("block_fused")
     lams = lambda_sequence(float(obj.lambda_max(prob.A, prob.y, prob.loss)),
                            lam, num_lambdas)
-    cache, x, chunk = WarmStartCache(), jnp.zeros(prob.d), 8
-    objs, rounds = [], []
+    cache = WarmStartCache() if cache is None else cache
+    x, chunk = jnp.zeros(prob.d), 8
+    objs, nnzs, rounds = [], [], []
     for lam_i in lams:
         p_i = prob._replace(lam=jnp.float32(lam_i))
         x0, kind = cache.get("path", float(lam_i), loss=prob.loss)
@@ -54,7 +61,16 @@ def _loop_without_spans(prob, key, lam, spec, num_lambdas, tol):
         cache.put("path", float(lam_i), np.asarray(x), loss=prob.loss)
         rounds.append(spent)
         objs.append(float(res.trace.objective[-1]))
-    return x, np.array(objs), np.array(rounds)
+        nnzs.append(int(res.trace.nnz[-1]))
+    return x, np.array(objs), np.array(nnzs), np.array(rounds)
+
+
+def _assert_same_path(got, want):
+    x, objs, nnz, rounds = want
+    assert np.array_equal(np.asarray(got.x), np.asarray(x))
+    assert np.array_equal(got.objectives, objs)
+    assert np.array_equal(got.nnz, nnz)
+    assert np.array_equal(got.rounds, rounds)
 
 
 @pytest.mark.parametrize("loss", ["lasso", "logistic"])
@@ -64,13 +80,91 @@ def test_path_with_spans_is_bit_identical_to_the_loop_without(loss):
     got = solve_path(prob, jax.random.PRNGKey(7), lam_target=lam,
                      num_lambdas=5, solver="block_fused", spec=spec,
                      cache=WarmStartCache(), tol=1e-4)
-    x, objs, rounds = _loop_without_spans(prob, jax.random.PRNGKey(7), lam,
-                                          spec, 5, 1e-4)
-    assert np.array_equal(np.asarray(got.x), np.asarray(x))
-    assert np.array_equal(got.objectives, objs)
-    assert np.array_equal(got.rounds, rounds)
-    # one read a chunk, four a λ, λ_max and P*
-    assert got.syncs == rounds.sum() // 8 + 4 * 5 + 2
+    want = _loop_without_spans(prob, jax.random.PRNGKey(7), lam, spec, 5,
+                               1e-4)
+    _assert_same_path(got, want)
+    # one read a chunk, then λ_max and P*; every hit is the path's own x
+    assert got.syncs == want[3].sum() // 8 + 2
+    assert got.uploads == 0
+
+
+class _RecordingCache(WarmStartCache):
+    """A warm-start cache that keeps every x put and every x0 handed out."""
+
+    def __init__(self):
+        super().__init__()
+        self.written, self.handed = [], []
+
+    def put(self, problem_id, lam, x, loss="lasso"):
+        super().put(problem_id, lam, x, loss=loss)
+        self.written.append(x)
+
+    def get(self, problem_id, lam, loss="lasso"):
+        x0, kind = super().get(problem_id, lam, loss=loss)
+        self.handed.append(x0)
+        return x0, kind
+
+
+def test_path_on_a_cache_filled_by_another_sweep_uploads_only_its_entries():
+    prob, lam = _problem("lasso")
+    spec = SolverSpec(loss="lasso", P=64, rounds=32)
+    cache = _RecordingCache()
+    solve_path(prob, jax.random.PRNGKey(11), lam_target=lam, num_lambdas=2,
+               solver="block_fused", spec=spec, cache=cache, tol=1e-4)
+    first, cache.handed = list(cache.written), []
+    want = _loop_without_spans(prob, jax.random.PRNGKey(7), lam, spec, 5,
+                               1e-4, cache=copy.deepcopy(cache))
+    got = solve_path(prob, jax.random.PRNGKey(7), lam_target=lam,
+                     num_lambdas=5, solver="block_fused", spec=spec,
+                     cache=cache, tol=1e-4)
+    _assert_same_path(got, want)
+    from_first = sum(any(x0 is x for x in first) for x0 in cache.handed)
+    assert 0 < from_first < 5       # some hits the first sweep's, some own
+    assert got.uploads == from_first
+
+
+@pytest.mark.parametrize("cached", [True, False])
+def test_no_lambda_reads_outside_its_chunks(cached, monkeypatch):
+    """Every read of a λ runs inside one of its chunks, one a chunk; the
+    rest are P* and λ_max, and without a cache the one read at the end."""
+    from repro.core import path
+    open_spans, where, per_chunk = [], [], []
+
+    @contextlib.contextmanager
+    def annotation(name, **_):
+        open_spans.append(name)
+        if name == "shotgun.path.chunk":
+            per_chunk.append(0)
+        try:
+            yield
+        finally:
+            open_spans.pop()
+
+    class CountingReads(path._Reads):
+        def __call__(self, read, value):
+            where.append(tuple(open_spans))
+            if open_spans[-1:] == ["shotgun.path.chunk"]:
+                per_chunk[-1] += 1
+            return super().__call__(read, value)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    monkeypatch.setattr(path, "_Reads", CountingReads)
+    prob, lam = _problem("lasso")
+    res = solve_path(prob, jax.random.PRNGKey(7), lam_target=lam,
+                     num_lambdas=3, solver="block_fused",
+                     spec=SolverSpec(loss="lasso", P=64, rounds=32),
+                     cache=WarmStartCache() if cached else None, tol=1e-4)
+    assert len(where) == res.syncs
+    inside = [w for w in where if "shotgun.path.lambda" in w]
+    assert all(w[-1] == "shotgun.path.chunk" for w in inside)
+    outside = [w[-1:] for w in where if "shotgun.path.lambda" not in w]
+    assert outside[:2] == [("shotgun.path.p_star",),
+                           ("shotgun.path.lambda_max",)]
+    if cached:
+        assert per_chunk == [1] * (res.rounds.sum() // 8)
+        assert len(outside) == 2
+    else:
+        assert inside == [] and outside[2:] == [()]
 
 
 def test_syncs_counted_without_a_cache():
@@ -80,7 +174,8 @@ def test_syncs_counted_without_a_cache():
                      spec=SolverSpec(loss="lasso", P=64, rounds=16),
                      validate_p=False)
     assert got.rounds is None
-    assert got.syncs == 2 * 3 + 1     # objective and nnz a λ, λ_max
+    assert got.syncs == 2     # λ_max, then every λ's F and nnz at the end
+    assert got.uploads == 0
 
 
 def test_pad_problem_is_one_program_and_keeps_an_aligned_design():
