@@ -1,13 +1,11 @@
 """Kernel-layer microbenchmark (DESIGN §4.4): per-round cost of
 
   * the scalar Shotgun round it all replaces (P = K·128 gathered columns),
-  * the two-kernel Block-Shotgun round (gather + scatter pallas_call, z/r/g
-    round-tripping through XLA between launches),
   * the fused multi-round kernel — ONE pallas_call per R rounds with z
-    resident in VMEM (2 launches/round -> 1/R launches/round).
+    resident in VMEM, with and without the armed sentinel.
 
 CPU interpret-mode timings; the TPU claims are structural (arithmetic
-intensity O(block) vs O(1); A-stream traffic halved in the single-phase
+intensity O(block) vs O(1); one A-stream a round in the single-phase
 fused kernel; launch/dispatch cost amortized R×).  Emits the repo-root
 ``BENCH_kernels.json`` perf-trajectory point.
 
@@ -45,7 +43,6 @@ def run() -> list[dict]:
         Ap, yp, mask = ops.pad_problem(prob.A, prob.y)
         x = jnp.zeros(Ap.shape[1])
         z = jnp.zeros(Ap.shape[0])
-        blk = jnp.arange(K, dtype=jnp.int32)
         R = ROUNDS_PER_LAUNCH
         idx = (jnp.arange(R * K, dtype=jnp.int32).reshape(R, K)
                % (Ap.shape[1] // ops.BLOCK))
@@ -62,8 +59,6 @@ def run() -> list[dict]:
                 f"{vmem} B of VMEM > {VMEM_BUDGET} B budget — shrink the "
                 "bench shape or K")
 
-        us_two = time_us(lambda: ops.block_shotgun_round(
-            Ap, z, x, blk, prob.lam, prob.beta, yp, mask), reps=5)
         us_fused_launch = time_us(lambda: fused_shotgun_rounds(
             Ap, z, x, idx, prob.lam, prob.beta, yp, mask),
             reps=10)
@@ -88,20 +83,14 @@ def run() -> list[dict]:
             "fused_round_guarded_us": round(us_fused_g, 1),
             "sentinel_overhead_pct": round(
                 100.0 * (us_fused_g - us_fused) / us_fused, 2),
-            "block_round_us": round(us_two, 1),
             "scalar_round_us": round(us_scalar, 1),
             "launches_per_round_fused": 1.0 / R,
-            "launches_per_round_block": 2,
-            "speedup_fused_vs_block": round(us_two / us_fused, 2),
             "hbm_bytes_per_round_fused": model["fused"]["bytes"],
-            "hbm_bytes_per_round_block": model["two_kernel"]["bytes"],
             "flops_per_byte_fused": round(model["fused"]["intensity"], 3),
-            "flops_per_byte_block": round(model["two_kernel"]["intensity"], 3),
             "flops_per_byte_scalar": round(model["scalar"]["intensity"], 3),
         })
         print(f"kernels,n={n},d={d},K={K},fused_round={us_fused:.0f}us,"
-              f"block_round={us_two:.0f}us,scalar_round={us_scalar:.0f}us,"
-              f"speedup={us_two / us_fused:.2f}x", flush=True)
+              f"scalar_round={us_scalar:.0f}us", flush=True)
     emit(rows, "bench_kernels")
     if not os.environ.get("BENCH_SMOKE"):
         # full runs own the untagged rows of the committed perf trajectory

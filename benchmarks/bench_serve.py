@@ -87,8 +87,7 @@ def run() -> list[dict]:
     _serve_once(make_stream(N, D, requests=1, lam=LAM, seed=7), 1)
     wu = make_stream(N, D, requests=1, lam=LAM, seed=7)[0]
     jax.block_until_ready(ops.block_shotgun_solve(
-        wu.prob, wu.key, K, MAX_ROUNDS, fused=True,
-        rounds_per_launch=R).x)
+        wu.prob, wu.key, K, MAX_ROUNDS, rounds_per_launch=R).x)
 
     stream = lambda seed: make_stream(N, D, requests=requests,
                                       repeat_frac=repeat_frac, lam=LAM,
@@ -103,8 +102,7 @@ def run() -> list[dict]:
     t0 = time.time()
     for rq in seq_reqs:
         jax.block_until_ready(ops.block_shotgun_solve(
-            rq.prob, rq.key, K, MAX_ROUNDS, fused=True,
-            rounds_per_launch=R).x)
+            rq.prob, rq.key, K, MAX_ROUNDS, rounds_per_launch=R).x)
     dt_seq = time.time() - t0
     solves_seq = len(seq_reqs) / dt_seq
 

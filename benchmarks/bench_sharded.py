@@ -1,11 +1,11 @@
 """Distributed round-engine benchmark (DESIGN §3): per-round wall time of
-the scalar / block / fused engines × merge modes on a forced 8-device host
+the scalar / fused engines × merge modes on a forced 8-device host
 mesh, plus the modeled Δz ``wire_bytes`` per round for each §7 compression
 scheme (the psum itself moves dense f32 in this SPMD emulation — the wire
 accounting is what a real multi-host deployment would put on the network).
 
 Engines run at matched effective parallelism (P_eff = shards × K × 128 for
-the block engines, P_local = K × 128 for the scalar engine).  Interpret-mode
+the fused engine, P_local = K × 128 for the scalar engine).  Interpret-mode
 Pallas timings; the structural claims (1/R launches per merge, block DMA vs
 random column gather) carry to TPU.
 
@@ -63,7 +63,6 @@ t_model = sharded_merge_model(n)["wire_us_per_merge"]
 
 rows = []
 for engine, ekw in [("scalar", dict(P_local=K * 128)),
-                    ("block", dict(engine="block", K=K)),
                     ("fused", dict(engine="fused", K=K))]:
     launch_kw = dict(merge="launch", rounds_per_launch=R_LAUNCH,
                      trace_every=rounds // R_LAUNCH)

@@ -9,23 +9,18 @@ Comparisons per shape:
 
   * scalar Shotgun round (P = K·128 sampled coordinates): dense column
     gather A[:, idx] vs the O(tile·P) nnz-tile pack;
-  * two-kernel Pallas Block-Shotgun round: streamed (n × 128) dense blocks
-    vs the (tile × 128) rows/vals tiles of ``kernels/shotgun_sparse.py``;
-  * fused multi-round rounds (R rounds per launch, margin in VMEM): the
-    dense §4.2 kernel vs the sparse §8.3 kernel — the composition this
-    bench exists to track, reported as
-    ``speedup_fused_sparse_vs_block_sparse`` so the trajectory in
-    BENCH_kernels.json is directly comparable across PRs.
+  * fused multi-round rounds (R rounds per launch, margin in VMEM):
+    streamed (n × 128) dense blocks in the §4.2 kernel vs the
+    (tile × 128) rows/vals tiles of the §8.3 kernel
+    (``kernels/shotgun_sparse.py``), reported as
+    ``speedup_fused_sparse_vs_dense_fused``.
 
-Interpret-mode timings (CPU container) — per the §4.4/§8.3 cost model the
-interpret cost scales with the bytes each grid step touches, so the
-tile-vs-column ratio and the K-vs-2K grid-step ratio show up directly; the
-analytic HBM model (``roofline.sparse_round_model``) carries the TPU claim,
-and the bench asserts the measured wall-time ordering matches the model's
-HBM-byte ordering (fused-sparse < two-kernel-sparse < dense).  Appends rows
-tagged ``"bench": "sparse"`` to the repo-root ``BENCH_kernels.json`` on
-full runs; BENCH_SMOKE=1 shrinks the shape (still exercising the
-fused-sparse config) and leaves the artifact alone.
+Interpret-mode timings (CPU container), not device numbers; the analytic
+HBM model (``roofline.sparse_round_model``) carries the TPU claim.
+Appends rows tagged ``"bench": "sparse"`` to the repo-root
+``BENCH_kernels.json`` on full runs; BENCH_SMOKE=1 shrinks the shape
+(still exercising the fused-sparse config) and leaves the artifact
+alone.
 """
 from __future__ import annotations
 
@@ -64,7 +59,6 @@ def run() -> list[dict]:
         nblk = rows_t.shape[0]
         xs = jnp.zeros(nblk * 128)
         zs = jnp.zeros(n)
-        blk = jnp.arange(K, dtype=jnp.int32)
         idx_rk = (jnp.arange(R * K, dtype=jnp.int32) % nblk).reshape(R, K)
 
         # refuse configs the fused sparse kernel could not compile on
@@ -77,9 +71,6 @@ def run() -> list[dict]:
                 f"tile={int(ps.A.tile)}) needs {vmem} B of VMEM > "
                 f"{VMEM_BUDGET} B budget — shrink the tile or K")
 
-        # two-kernel sparse round vs R fused sparse rounds in one launch
-        us_blk_sparse = time_us(lambda: ops.sparse_block_shotgun_round(
-            rows_t, vals_t, zs, xs, blk, ps.lam, ps.beta, ps.y))
         us_fused_sparse = time_us(lambda: fused_sparse_shotgun_rounds(
             rows_t, vals_t, zs, xs, idx_rk, ps.lam, ps.beta, ps.y)) / R
 
@@ -114,26 +105,15 @@ def run() -> list[dict]:
         model = sparse_round_model(n, d, K, tile=ps.A.tile, R=R)
         model16 = sparse_round_model(n, d, K, tile=ps.A.tile, R=R,
                                      val_bytes=2)
-        assert (model["sparse_fused"]["bytes"] < model["sparse"]["bytes"]
-                < model["dense"]["bytes"]), model
-        if not smoke:
-            # measured wall ordering must match the model's HBM-byte
-            # ordering (smoke shapes on the 2-core container are noise)
-            assert us_fused_sparse < us_blk_sparse, (us_fused_sparse,
-                                                     us_blk_sparse)
+        assert model["sparse_fused"]["bytes"] < model["dense"]["bytes"], model
         row = {
             "bench": "sparse", "n": n, "d": d, "density": density,
             "K": K, "P_eff": K * 128, "tile": int(ps.A.tile),
             "rounds_per_launch": R,
-            "block_round_us_bcsc": round(us_blk_sparse, 1),
             "fused_round_us_bcsc": round(us_fused_sparse, 1),
-            "speedup_fused_sparse_vs_block_sparse":
-                round(us_blk_sparse / us_fused_sparse, 2),
-            "hbm_bytes_per_round_dense": model["dense"]["bytes"],
-            "hbm_bytes_per_round_bcsc": model["sparse"]["bytes"],
+            "hbm_bytes_per_round_fused_dense": model["dense"]["bytes"],
             "hbm_bytes_per_round_fused_bcsc":
                 round(model["sparse_fused"]["bytes"]),
-            "hbm_bytes_ratio": round(model["hbm_bytes_ratio"], 1),
             "hbm_bytes_ratio_fused": round(model["hbm_bytes_ratio_fused"], 1),
             "storage_bytes_dense": model["storage_bytes_dense"],
             "storage_bytes_bcsc": model["storage_bytes_bcsc"],
@@ -155,35 +135,26 @@ def run() -> list[dict]:
             us_scalar_sparse = time_us(lambda: shotgun_solve(
                 ps, jax.random.PRNGKey(0), P=K * 128, rounds=1))
 
-            # dense Pallas rounds: two-kernel and R fused rounds per launch
+            # dense Pallas rounds: R fused rounds per launch
             Ap, yp, mask = ops.pad_problem(pd.A, pd.y)
             x = jnp.zeros(Ap.shape[1])
             z = jnp.zeros(Ap.shape[0])
-            us_blk_dense = time_us(lambda: ops.block_shotgun_round(
-                Ap, z, x, blk, pd.lam, pd.beta, yp, mask))
             us_fused_dense = time_us(lambda: fused_shotgun_rounds(
                 Ap, z, x, idx_rk, pd.lam, pd.beta, yp, mask)) / R
 
             row.update({
                 "scalar_round_us_dense": round(us_scalar_dense, 1),
                 "scalar_round_us_bcsc": round(us_scalar_sparse, 1),
-                "block_round_us_dense": round(us_blk_dense, 1),
                 "fused_round_us_dense": round(us_fused_dense, 1),
                 "speedup_scalar":
                     round(us_scalar_dense / us_scalar_sparse, 2),
-                "speedup_block": round(us_blk_dense / us_blk_sparse, 2),
                 "speedup_fused_sparse_vs_dense_fused":
                     round(us_fused_dense / us_fused_sparse, 2),
             })
-            if not smoke:
-                assert us_blk_sparse < us_blk_dense, row
 
         rows.append(row)
         print(f"sparse,n={n},d={d},density={density},tile={int(ps.A.tile)},"
-              f"block_bcsc={us_blk_sparse:.0f}us,"
-              f"fused_bcsc={us_fused_sparse:.0f}us,"
-              f"speedup_fused_vs_block="
-              f"{us_blk_sparse / us_fused_sparse:.2f}", flush=True)
+              f"fused_bcsc={us_fused_sparse:.0f}us", flush=True)
 
     emit(rows, "bench_sparse")
     if not smoke:

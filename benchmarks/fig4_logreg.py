@@ -109,7 +109,7 @@ def _fused_bench(regime, n, d, K, newton, conv_rounds, smoke):
 
     def fused(rounds, newton=False):
         return ops.block_shotgun_solve(prob, key, spec=SolverSpec(
-            loss="logistic", P=P, rounds=rounds, fused=True, newton=newton))
+            loss="logistic", P=P, rounds=rounds, newton=newton))
 
     us_scalar = time_us(lambda: scalar(1), reps=3)
     us_fused = time_us(lambda: fused(R_LAUNCH), reps=3) / R_LAUNCH

@@ -16,11 +16,9 @@ ICI_GBPS = 45e9     # per-link ICI bandwidth — floor for the Δz merge time
 
 
 def shotgun_round_model(n, d, K, block=128, a_bytes=4, fused_single=None):
-    """Per-round HBM bytes / flops / roofline time for the three kernels.
+    """Per-round HBM bytes / flops / roofline time of the two rounds.
 
     scalar       P=K·block gathered columns; O(1) flops/byte.
-    two-kernel   gather + scatter launches: A blocks streamed twice, plus
-                 z, r, g, delta round-tripping through HBM between launches.
     fused        single launch; z/r/g/delta stay in VMEM.  In single-phase
                  mode (one sample tile) each A block streams ONCE per round;
                  whether (n, d) gets single-phase is decided by the kernel's
@@ -35,8 +33,6 @@ def shotgun_round_model(n, d, K, block=128, a_bytes=4, fused_single=None):
     rows = {}
     rows["scalar"] = {"bytes": P * n * a_bytes + 3 * vec,
                       "flops": 4 * P * n}
-    rows["two_kernel"] = {"bytes": 2 * K * a_blk + 6 * vec + 4 * K * block * 4,
-                          "flops": 4 * K * block * n}
     rows["fused"] = {"bytes": (1 if fused_single else 2) * K * a_blk,
                      "flops": 4 * K * block * n}
     for name, r in rows.items():
@@ -106,11 +102,11 @@ def logistic_table(shapes=((8192, 256, 2), (1024, 2048, 4))):
 
 
 def sparse_round_model(n, d, K, tile, block=128, R=8, val_bytes=4):
-    """Per-round HBM bytes/flops of the Block-Shotgun round variants on a
+    """Per-round HBM bytes/flops of the fused Block-Shotgun round on a
     dense design vs a BlockedCSC one (DESIGN §8).  Sparse tiles carry both
     int32 row indices and values ((4 + ``val_bytes``) B/slot — 8 for f32
-    vals, 6 for bf16 vals via ``BlockedCSC.astype``); the dense two-kernel
-    round streams whole (n × block) column blocks twice.  The fused sparse round
+    vals, 6 for bf16 vals via ``BlockedCSC.astype``); the dense round
+    streams whole (n × block) column blocks.  The fused sparse round
     (DESIGN §8.3) fetches each selected block's nnz tiles ONCE per round
     (one grid step serves both gather and scatter) and keeps z/Δz/r/x in
     VMEM for all ``R`` rounds of a launch, so the z/x vector traffic is
@@ -118,26 +114,20 @@ def sparse_round_model(n, d, K, tile, block=128, R=8, val_bytes=4):
     is all that remains.  Also reports the at-rest design-matrix footprint
     — the paper-scale constraint that motivates the container.
     """
-    dense = shotgun_round_model(n, d, K, block=block)["two_kernel"]
+    dense = shotgun_round_model(n, d, K, block=block)["fused"]
     d_pad = -(-d // block) * block
     vec = n * 4
     slot = 4 + val_bytes                         # int32 row + stored value
-    sp_bytes = 2 * K * tile * block * slot + 6 * vec + 4 * K * block * 4
-    sp_flops = 2 * 2 * K * tile * block          # madd per nnz, each phase
-    sparse = {"bytes": sp_bytes, "flops": sp_flops,
-              "intensity": sp_flops / sp_bytes,
-              "t_mem_us": sp_bytes / HBM_GBPS * 1e6}
-    # fused: one (tile × block) rows+vals fetch per block per round; the
+    # one (tile × block) rows+vals fetch per block per round; the
     # per-launch z0/y input + z output (3 n-vectors) and the two full-
     # width x transfers (x0 in, x out — 2·d_pad) amortize over R rounds.
     fu_bytes = K * tile * block * slot + (3 * vec + 2 * d_pad * 4) / R
-    fu_flops = 2 * 2 * K * tile * block          # same madds, one fetch
+    fu_flops = 2 * 2 * K * tile * block          # madd per nnz, each phase
     fused = {"bytes": fu_bytes, "flops": fu_flops,
              "intensity": fu_flops / fu_bytes,
              "t_mem_us": fu_bytes / HBM_GBPS * 1e6}
     return {
-        "dense": dense, "sparse": sparse, "sparse_fused": fused,
-        "hbm_bytes_ratio": dense["bytes"] / sp_bytes,
+        "dense": dense, "sparse_fused": fused,
         "hbm_bytes_ratio_fused": dense["bytes"] / fu_bytes,
         "storage_bytes_dense": 4 * n * d,
         "storage_bytes_bcsc": slot * tile * d_pad,

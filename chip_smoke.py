@@ -119,7 +119,7 @@ def phase_lasso(n=4096, d=65536, lam_frac=0.1, rounds=1600,
 
     prob = _lasso_problem(n, d, lam_frac)
     P = _block_parallelism(prob.A)
-    spec = SolverSpec(loss="lasso", P=P, rounds=rounds, fused=True)
+    spec = SolverSpec(loss="lasso", P=P, rounds=rounds)
     key = jax.random.PRNGKey(0)
     solve = get_solver("block_fused")
     res, c_s, s_s = _timed(lambda: solve(prob, key, spec=spec,
@@ -170,8 +170,7 @@ def phase_logistic(n=16384, d=2048, lam_frac=0.05, rounds=160,
     prob = prob._replace(lam=jnp.float32(lam_frac * lmax))
     P = _block_parallelism(prob.A)
     key = jax.random.PRNGKey(0)
-    spec = SolverSpec(loss="logistic", P=P, rounds=rounds, fused=True,
-                      newton=True)
+    spec = SolverSpec(loss="logistic", P=P, rounds=rounds, newton=True)
     solve = get_solver("block_fused")
     res, c_s, s_s = _timed(lambda: solve(prob, key, spec=spec,
                                          rounds_per_launch=R_LAUNCH))
@@ -283,7 +282,7 @@ def phase_sharded(n=4096, d=262144, lam_frac=0.1, rounds=4000,
                         merge="round")))
     one, c1, s1 = _timed(lambda: get_solver("block_fused")(
         prob, key, spec=SolverSpec(loss="lasso", P=chips * K * BLOCK,
-                                   rounds=rounds, fused=True),
+                                   rounds=rounds),
         rounds_per_launch=R_LAUNCH))
     fstar, ref_s = _fista_fstar(sharded, fista_iters)   # XLA splits it
     f_sh = float(res.trace.objective[-1])

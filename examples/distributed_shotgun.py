@@ -35,20 +35,13 @@ def main():
           f"nnz = {int(res.trace.nnz[-1])}")
 
     # 2. Block-Shotgun (Pallas kernel; the interpreter on the CPU): aligned
-    #    128-coordinate blocks -> MXU matmuls instead of scalar gathers
+    #    128-coordinate blocks -> MXU matmuls instead of scalar gathers, 10
+    #    rounds a pallas_call with the margin resident in VMEM (DESIGN §4.2)
     K = max(1, min(ps // ops.BLOCK, 4))
     res_blk = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=K,
-                                      rounds=500)
-    print(f"Block-Shotgun (K = {K} blocks of {ops.BLOCK}): "
+                                      rounds=500, rounds_per_launch=10)
+    print(f"Block-Shotgun (K = {K} blocks of {ops.BLOCK}, R = 10/launch): "
           f"F = {float(res_blk.trace.objective[-1]):.4f}")
-
-    # 2b. fused multi-round kernel (DESIGN §4.2): one pallas_call per 10
-    #     rounds, margin resident in VMEM; identical trajectory to (2)
-    res_fus = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=K,
-                                      rounds=500, fused=True,
-                                      rounds_per_launch=10)
-    print(f"fused Block-Shotgun (R = 10/launch): "
-          f"F = {float(res_fus.trace.objective[-1]):.4f}")
 
     # 3. reference: single-device scalar Shotgun
     ref = shotgun_solve(prob, jax.random.PRNGKey(1), P=K * ops.BLOCK,

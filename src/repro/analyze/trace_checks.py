@@ -286,9 +286,6 @@ def default_retrace_targets() -> list[tuple]:
         if name == "shooting_cdn":
             return (lambda: solve(lprob, k0, rounds=2),
                     lambda: solve(lprob2, k1, rounds=2))
-        if name == "block":
-            return (lambda: solve(prob, k0, K=1, rounds=2),
-                    lambda: solve(prob2, k1, K=1, rounds=2))
         if name == "block_fused":
             return (lambda: solve(prob, k0, K=1, rounds=2,
                                   rounds_per_launch=2),

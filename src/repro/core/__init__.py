@@ -11,7 +11,7 @@ Public API:
     solve_path                                            (lambda continuation)
     shotgun_sharded_solve                                 (multi-device driver)
 
-The Pallas solvers (``block`` / ``block_fused`` in ``get_solver``) live in
+The Pallas solver (``block_fused`` in ``get_solver``) lives in
 ``repro.kernels.ops``, and the round engines (``core/engines.py``) import
 them lazily, to keep core import-light.  ``solve_path(solver=<name>)``
 accepts any registry entry; ``shotgun_sharded_solve(engine=<name>)`` any
@@ -27,8 +27,8 @@ from repro.core.shotgun import (shooting_solve, shotgun_solve,
                                 diverged, get_solver, SOLVER_NAMES,
                                 Result, Trace)
 from repro.core.cdn import shotgun_cdn_solve, shooting_cdn_solve
-from repro.core.engines import (ENGINE_NAMES, BlockEngine, FusedEngine,
-                                ScalarEngine, make_engine)
+from repro.core.engines import (ENGINE_NAMES, FusedEngine, ScalarEngine,
+                                make_engine)
 from repro.core.spectral import spectral_radius, p_star, p_star_dup
 from repro.core.path import solve_path, lambda_sequence
 from repro.core.sharded import shotgun_sharded_solve

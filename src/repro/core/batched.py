@@ -18,7 +18,7 @@ advances every live slot R rounds:
     equals the standalone solve of the same padded problem.
   * ``batched_block_shotgun_solve`` — the fixed-budget stacked solve:
     slot *i* is bit-identical in x to ``ops.block_shotgun_solve(prob_i,
-    key_i, fused=True)`` for the same key (dense and BlockedCSC; tested).
+    key_i)`` for the same key (dense and BlockedCSC; tested).
   * ``launch_rounds`` — the serving step: ONE batched launch of R rounds
     against stacked state, per-slot ``k_eff`` freezing converged/empty
     slots bit-exactly, returning the in-kernel per-round objective/nnz
@@ -205,7 +205,7 @@ def launch_rounds(meta: BatchMeta, stacked: SlotArrays, z, x, idx, k_eff,
 
 def init_margin(meta: BatchMeta, stacked: SlotArrays, x):
     """Stacked warm-start margins z0 = A x0, f32 accumulation — exactly the
-    per-slot init of ``ops._fused_solve`` / ``_fused_sparse_solve``."""
+    per-slot init of ``ops._fused_solve`` / ``_sparse_fused_solve``."""
     if meta.layout == "bcsc":
         return jax.vmap(lambda r, v, x_: bcsc_matvec(r, v, x_, meta.n_pad)
                         )(stacked.rows, stacked.vals, x)
@@ -236,7 +236,7 @@ def batched_block_shotgun_solve(probs: Sequence[Problem], keys,
                                 ) -> Result:
     """Fixed-budget stacked solve: every slot runs the full round budget in
     lock-step batched launches.  Slot *i* is bit-identical in x to
-    ``ops.block_shotgun_solve(probs[i], keys[i], K, rounds, fused=True,
+    ``ops.block_shotgun_solve(probs[i], keys[i], K, rounds,
     rounds_per_launch=R)`` run standalone on the same padded canvas — the
     vmapped kernels change the grid, not the math (tested for dense and
     BlockedCSC in tests/test_batched_serve.py).

@@ -4,9 +4,8 @@ The distributed Shotgun driver (``core/sharded.py``) is a thin shard_map
 loop over a pluggable **round engine**: the per-shard computation "run R
 rounds of coordinate updates against a margin snapshot z, emit the margin
 contribution Δz = A_shard δx" behind one small protocol, so the same driver
-composes the scalar jnp path, the two-kernel Pallas paths (dense and
-BlockedCSC), and the fused multi-round Pallas kernels (dense §4.2, sparse
-§8.3) with either merge cadence.
+composes the scalar jnp path and the fused multi-round Pallas kernels
+(dense §4.2, sparse §8.3) with either merge cadence.
 
 Protocol (all engines are hashable NamedTuples so they can ride through
 ``jax.jit`` as static configuration; the driver owns iterate init,
@@ -25,7 +24,7 @@ padding, and the Δz merge):
 
       ``p_eff`` (dynamic int32 scalar) is the driver's adaptive-P backoff
       knob (DESIGN §9), in the engine's own parallelism units (coordinates
-      for the scalar engine, 128-blocks for the rest): each round still
+      for the scalar engine, 128-blocks for the fused ones): each round still
       draws the engine's full candidate set but masks updates at or past
       ``p_eff`` — a bit-exact no-op at full width.  ``health`` is a scalar
       f32 flag (0.0 healthy / 1.0 tripped): the O(1)-per-merge divergence
@@ -54,9 +53,9 @@ padding, and the Δz merge):
   ``engine.fold_always``
       scalar engine: True — the per-round key is folded with the shard
       index even on a 1-shard mesh, preserving the pre-engine trajectory
-      bit-for-bit.  Block/fused engines fold only on real multi-shard
+      bit-for-bit.  The fused engines fold only on real multi-shard
       meshes so a 1-shard run draws *exactly* the same block indices as the
-      single-device solvers in ``kernels/ops.py`` (trace-equivalence,
+      single-device solver in ``kernels/ops.py`` (trace-equivalence,
       DESIGN §3).
 
 Engines never touch collectives — the driver owns the Δz merge (psum /
@@ -73,7 +72,7 @@ import jax.numpy as jnp
 from repro.core import health
 from repro.core import objectives as obj
 
-ENGINE_NAMES = ("scalar", "block", "fused", "sparse_block", "sparse_fused")
+ENGINE_NAMES = ("scalar", "fused", "sparse_fused")
 
 
 def _run_segment(self, A_blk, y, mask, lam, beta, z, w_pend, x_l, keys,
@@ -123,47 +122,6 @@ class ScalarEngine(NamedTuple):
         return x_l, dz, health.nonfinite_flag(dz)
 
 
-class BlockEngine(NamedTuple):
-    """Two-kernel Pallas engine: K aligned 128-blocks per round
-    (``gather_block_matvec`` + ``scatter_block_update``, DESIGN §4.1), with
-    the scatter accumulating into the Δz buffer instead of the margin."""
-
-    K: int
-    loss: str
-    block: int = 128
-
-    fold_always = False
-    run_segment = _run_segment
-
-    @property
-    def p_full(self):
-        return self.K
-
-    def run(self, A_blk, y, mask, lam, beta, z, x_l, keys, p_eff):
-        from repro.kernels.shotgun_block import (gather_block_matvec,
-                                                 scatter_block_update)
-        nblk = x_l.shape[0] // self.block
-        live = health.live_mask(self.K, p_eff)[:, None]
-
-        def round_fn(carry, key_t):
-            x_l, dz = carry
-            blk = jax.random.choice(key_t, nblk, (self.K,),
-                                    replace=False).astype(jnp.int32)
-            r = obj.residual_like(z + dz, y, self.loss) * mask
-            g = gather_block_matvec(A_blk, r, blk, block=self.block)
-            xb = x_l.reshape(nblk, self.block)
-            x_sel = jnp.take(xb, blk, axis=0)
-            x_new = obj.soft_threshold(x_sel - g / beta, lam / beta)
-            delta = (x_new - x_sel) * live
-            dz = scatter_block_update(A_blk, dz, blk, delta,
-                                      block=self.block)
-            x_l = xb.at[blk].add(delta).reshape(-1)
-            return (x_l, dz), None
-
-        (x_l, dz), _ = jax.lax.scan(round_fn, (x_l, jnp.zeros_like(z)), keys)
-        return x_l, dz, health.nonfinite_flag(dz)
-
-
 class FusedEngine(NamedTuple):
     """Fused multi-round Pallas engine: all R rounds of a merge window in
     ONE ``pallas_call`` with the local margin view and Δz accumulator
@@ -192,58 +150,16 @@ class FusedEngine(NamedTuple):
             block=self.block, tile_n=self.tile_n, k_eff=p_eff)
 
 
-class SparseBlockEngine(NamedTuple):
-    """Two-kernel sparse engine for BlockedCSC designs (DESIGN §8): K
-    aligned 128-blocks per round via the nnz-tile kernels
-    (``kernels/shotgun_sparse.py``), scatter-accumulating into the Δz
-    buffer.  ``A_blk`` arrives as a column-sharded ``BlockedCSC`` (leaves
-    split on the nblk axis by shard_map); only its raw rows/vals tiles are
-    read, so the global-d metadata needs no per-shard fix-up."""
-
-    K: int
-    loss: str
-    block: int = 128
-
-    fold_always = False
-    run_segment = _run_segment
-
-    @property
-    def p_full(self):
-        return self.K
-
-    def run(self, A_blk, y, mask, lam, beta, z, x_l, keys, p_eff):
-        from repro.kernels.shotgun_sparse import (sparse_gather_block_matvec,
-                                                  sparse_scatter_block_update)
-        rows, vals = A_blk.rows, A_blk.vals
-        nblk = rows.shape[0]
-        live = health.live_mask(self.K, p_eff)[:, None]
-
-        def round_fn(carry, key_t):
-            x_l, dz = carry
-            blk = jax.random.choice(key_t, nblk, (self.K,),
-                                    replace=False).astype(jnp.int32)
-            r = obj.residual_like(z + dz, y, self.loss) * mask
-            g = sparse_gather_block_matvec(rows, vals, r, blk)
-            xb = x_l.reshape(nblk, self.block)
-            x_sel = jnp.take(xb, blk, axis=0)
-            x_new = obj.soft_threshold(x_sel - g / beta, lam / beta)
-            delta = (x_new - x_sel) * live
-            dz = sparse_scatter_block_update(rows, vals, dz, blk, delta)
-            x_l = xb.at[blk].add(delta).reshape(-1)
-            return (x_l, dz), None
-
-        (x_l, dz), _ = jax.lax.scan(round_fn, (x_l, jnp.zeros_like(z)), keys)
-        return x_l, dz, health.nonfinite_flag(dz)
-
-
 class SparseFusedEngine(NamedTuple):
     """Fused multi-round sparse engine for BlockedCSC designs (DESIGN §8.3):
     all R rounds of a merge window in ONE ``pallas_call`` with the shard's
     live local margin view AND the Δz accumulator resident in VMEM,
     streaming only the selected (tile, 128) nnz tiles
-    (``fused_sparse_shotgun_delta_rounds``).  Like ``SparseBlockEngine``,
-    ``A_blk`` arrives as a column-sharded ``BlockedCSC`` and only its raw
-    rows/vals tiles are read (block width included — no ``block`` field);
+    (``fused_sparse_shotgun_delta_rounds``).  ``A_blk`` arrives as a
+    column-sharded ``BlockedCSC`` (leaves split on the nblk axis by
+    shard_map) and only its raw rows/vals tiles are read, so the global-d
+    metadata needs no per-shard fix-up (block width included — no
+    ``block`` field);
     the sample mask is ignored (the sparse path never pads samples)."""
 
     K: int
@@ -277,8 +193,8 @@ def make_engine(name: str, *, loss: str, P_local: int = 8, K: int = 2,
     ``loss`` is a registry string ("lasso" / "logistic") or a full
     ``kernels.shotgun_block.Loss`` spec — engines carry it as static
     configuration either way.  ``newton=True`` upgrades a fused engine to
-    the per-block Newton curvature step (DESIGN §12); the two-kernel and
-    scalar engines have no curvature tile, so it is fused-only.
+    the per-block Newton curvature step (DESIGN §12); the scalar engine
+    has no curvature tile, so it is fused-only.
     """
     if newton:
         if name not in ("fused", "sparse_fused"):
@@ -286,17 +202,13 @@ def make_engine(name: str, *, loss: str, P_local: int = 8, K: int = 2,
                 f"newton=True requires a fused engine, got {name!r}")
         from repro.kernels.shotgun_block import resolve_loss
         loss = resolve_loss(loss)._replace(newton=True)
-    # non-fused engines read the loss through objectives.py, which only
-    # knows registry names
-    lname = loss if isinstance(loss, str) else loss.name
     if name == "scalar":
+        # the scalar engine reads the loss through objectives.py, which
+        # only knows registry names
+        lname = loss if isinstance(loss, str) else loss.name
         return ScalarEngine(P_local=P_local, loss=lname)
-    if name == "block":
-        return BlockEngine(K=K, loss=lname, block=block)
     if name == "fused":
         return FusedEngine(K=K, loss=loss, block=block, tile_n=tile_n)
-    if name == "sparse_block":
-        return SparseBlockEngine(K=K, loss=lname, block=block)
     if name == "sparse_fused":
         return SparseFusedEngine(K=K, loss=loss)
     raise ValueError(f"unknown engine {name!r}; choose from {ENGINE_NAMES}")

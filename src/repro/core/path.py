@@ -120,20 +120,18 @@ def _solver_by_name(name: str, **solver_kwargs) -> Callable:
             res = solve(dp, k, P=P, rounds=r, xhat0=xhat0, **solver_kwargs)
             return res._replace(x=obj.dup_to_signed(res.x))
         return run_dup
-    if family in ("block", "block_fused"):
+    if family == "block_fused":
         def run_block(p, k, P, r, x0):
             from repro.kernels.shotgun_block import BLOCK
             kw = dict(solver_kwargs)
             K = kw.pop("K", max(1, -(-P // BLOCK)))
-            if family == "block_fused" and "rounds_per_launch" not in kw:
-                kw["rounds_per_launch"] = _largest_divisor_leq(r, 8)
             return solve(p, k, K=K, rounds=r, x0=x0, **kw)
         return run_block
     if family == "sharded":
         def run_sharded(p, k, P, r, x0):
             kw = dict(solver_kwargs)
-            if kw.get("engine") in ("block", "fused"):
-                # block engines take their parallelism as K blocks of 128
+            if kw.get("engine") == "fused":
+                # the fused engine takes its parallelism as K blocks of 128
                 # per shard, not P_local
                 from repro.kernels.shotgun_block import BLOCK
                 kw.setdefault("K", max(1, -(-P // BLOCK)))
@@ -153,8 +151,8 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
     """Warm-started lambda-continuation wrapper around any shotgun-family
     solver.
 
-    ``solver`` is a ``SOLVER_NAMES`` entry (adapted automatically, warm
-    starts included) or a callable
+    ``solver`` is a ``SOLVER_NAMES`` entry or a ``(family, loss)`` pair
+    (adapted automatically, warm starts included) or a callable
     ``solver(prob, key, P, rounds, x0) -> shotgun.Result``.
 
     ``validate_p`` checks the requested ``P`` against the paper's safe
@@ -212,7 +210,7 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
                 f"P*={ps} for this design; clamping to P*={ps} "
                 f"(pass validate_p=False to override)", stacklevel=3)
             P = ps
-    if isinstance(solver, str):
+    if isinstance(solver, (str, tuple)):
         solver = _solver_by_name(solver, **solver_kwargs)
     elif solver_kwargs:
         raise ValueError(

@@ -9,7 +9,7 @@ compare-and-swap.  On an SPMD mesh there is no shared memory; instead:
     ``(pod, f)`` mesh work,
   * every device holds the full margin ``z`` (n,), replicated,
   * each merge window, device k runs a **round engine** (``core/engines.py``:
-    scalar jnp / two-kernel Pallas / fused multi-round Pallas) for R rounds
+    scalar jnp / fused multi-round Pallas) for R rounds
     against the last merged ``z`` and emits Δz_k = A_k δx_k,
   * one all-reduce merges the contributions — the shared-Ax write.
 
@@ -20,7 +20,7 @@ Two merge cadences:
                        (devices own disjoint coordinates, which only shrinks
                        Lemma 3.3's interference term), and for the fused
                        engine on a 1-shard mesh it is trace-equivalent to
-                       ``block_shotgun_solve(fused=True)``.
+                       ``block_shotgun_solve``.
   ``merge="launch"``   R = rounds_per_launch stale rounds per merge: each
                        shard sees its own updates immediately but other
                        shards' only at merge boundaries — the paper's
@@ -354,12 +354,11 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
     """Distributed Shotgun over any round engine (DESIGN §3).
 
     engine      "scalar" (P = P_local × shards coordinate updates/round),
-                "block" / "fused" (P = K × 128 × shards via the Pallas
-                kernels), "sparse_block" /
-                "sparse_fused" (same P but over a BlockedCSC design via the
-                nnz-tile kernels, DESIGN §8 — column blocks sharded on
-                nblk; "sparse_fused" keeps the margin view and Δz in VMEM
-                for the whole merge window, DESIGN §8.3).
+                "fused" (P = K × 128 × shards via the fused Pallas
+                kernel), "sparse_fused" (same P but over a BlockedCSC
+                design via the nnz-tile kernel, DESIGN §8.3 — column
+                blocks sharded on nblk; the margin view and Δz stay in
+                VMEM for the whole merge window).
     merge       "round" — one Δz psum per round (no staleness);
                 "launch" — ``rounds_per_launch`` stale rounds per merge.
     x0          optional warm start (λ-continuation); zero-padded and
@@ -430,7 +429,7 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
     nshards = mesh.devices.size
     merge_rounds = 1 if merge == "round" else rounds_per_launch
 
-    if engine in ("sparse_block", "sparse_fused"):
+    if engine == "sparse_fused":
         if not isinstance(prob.A, BlockedCSC):
             raise ValueError(
                 f"engine={engine!r} needs a BlockedCSC design; got "
@@ -450,7 +449,7 @@ def shotgun_sharded_solve(prob: Problem, key: jax.Array,
     elif isinstance(prob.A, BlockedCSC):
         raise ValueError(
             f"engine={engine!r} needs a dense design; BlockedCSC problems "
-            "use engine='sparse_block' or 'sparse_fused'")
+            "use engine='sparse_fused'")
     elif engine == "scalar":
         A, y = pad_features(prob.A, nshards), prob.y
         mask = jnp.ones(prob.n, jnp.float32)

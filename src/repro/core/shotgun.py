@@ -202,7 +202,7 @@ def shotgun_dup_solve(dp: DupProblem, key: jax.Array, P: int, rounds: int,
 # ---------------------------------------------------------------------------
 
 SOLVER_NAMES = ("shooting", "shotgun", "shotgun_dup", "shotgun_cdn",
-                "shooting_cdn", "block", "block_fused", "sharded",
+                "shooting_cdn", "block_fused", "sharded",
                 "shotgun_logreg_fused", "sparse_logreg_fused")
 
 
@@ -233,8 +233,8 @@ def get_solver(name):
 
       shooting / shotgun / shotgun_dup   this module (Alg. 1 / Alg. 2)
       shotgun_cdn / shooting_cdn         CDN inner-Newton variants
-      block                              Pallas two-kernel Block-Shotgun
-      block_fused                        fused multi-round Pallas kernel
+      block_fused                        Pallas Block-Shotgun, fused
+                                         multi-round kernel
       sharded                            multi-device round-engine driver
                                          (pick the per-shard kernel with
                                          ``engine=`` from ``ENGINE_NAMES``,
@@ -264,10 +264,10 @@ def get_solver(name):
         return _loss_bound(get_solver(family), loss, family)
     if name == "shotgun_logreg_fused":
         from repro.kernels import ops
-        return _loss_bound(ops.fused_block_shotgun_solve, obj.LOGISTIC, name)
+        return _loss_bound(ops.block_shotgun_solve, obj.LOGISTIC, name)
     if name == "sparse_logreg_fused":
         from repro.kernels import ops
-        return _loss_bound(ops.fused_block_shotgun_solve, obj.LOGISTIC, name,
+        return _loss_bound(ops.block_shotgun_solve, obj.LOGISTIC, name,
                            require_sparse=True)
     if name == "shooting":
         return shooting_solve
@@ -279,12 +279,9 @@ def get_solver(name):
         from repro.core import cdn
         return {"shotgun_cdn": cdn.shotgun_cdn_solve,
                 "shooting_cdn": cdn.shooting_cdn_solve}[name]
-    if name == "block":
-        from repro.kernels import ops
-        return ops.block_shotgun_solve
     if name == "block_fused":
         from repro.kernels import ops
-        return ops.fused_block_shotgun_solve
+        return ops.block_shotgun_solve
     if name == "sharded":
         from repro.core import sharded
         return sharded.shotgun_sharded_solve

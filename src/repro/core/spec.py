@@ -9,7 +9,7 @@ trajectories) and emits a ``DeprecationWarning``.
 
 The spec is solver-family agnostic: fields a family does not implement are
 simply ignored by it (``merge``/``pipeline`` only matter to the sharded
-solver; ``fused``/``newton`` only to the block solvers).  ``loss`` is
+solver; ``newton`` only to the block solvers).  ``loss`` is
 always validated against the problem's loss so a spec built for one
 workload can never silently drive another.
 """
@@ -34,10 +34,12 @@ class SolverSpec:
     pipeline  sharded double-buffered merge pipeline; ignored elsewhere.
     guard     ``health.GuardConfig`` enabling the divergence sentinel +
               adaptive-P backoff (DESIGN §9), or None.
-    fused     run the fused multi-round kernel path (block solvers).
+    fused     always True: the block solvers have one round, the fused
+              multi-round kernel, and ``False`` (the retired two-kernel
+              round) raises.  The field stays only for callers that still
+              pass ``fused=True``.
     newton    per-block Newton curvature (Bian et al.) instead of the
-              β-Lipschitz step; requires ``fused=True`` (the curvature
-              tile only exists inside the fused kernel body).
+              β-Lipschitz step.
     """
 
     loss: str = "lasso"
@@ -46,14 +48,15 @@ class SolverSpec:
     merge: str = "round"
     pipeline: bool = False
     guard: GuardConfig | None = None
-    fused: bool = False
+    fused: bool = True
     newton: bool = False
 
     def __post_init__(self):
-        if self.newton and not self.fused:
+        if not self.fused:
             raise ValueError(
-                "SolverSpec(newton=True) requires fused=True: the per-block "
-                "curvature tile is computed inside the fused kernel body")
+                "SolverSpec(fused=False) named the two-kernel Block-Shotgun "
+                "round, which is retired: the block solvers run the fused "
+                "multi-round kernel only")
         if self.P < 1 or self.rounds < 1:
             raise ValueError(
                 f"SolverSpec needs P >= 1 and rounds >= 1, got "

@@ -1,9 +1,4 @@
 """Pallas TPU kernels for the paper's compute hot-spot (Block-Shotgun)."""
 from repro.kernels.shotgun_block import (BLOCK, TILE_N, auto_tile_n,
-                                         fused_shotgun_rounds,
-                                         gather_block_matvec,
-                                         scatter_block_update)
-from repro.kernels.shotgun_sparse import (sparse_gather_block_matvec,
-                                          sparse_scatter_block_update)
-from repro.kernels.ops import (block_shotgun_round, block_shotgun_solve,
-                               fused_block_shotgun_solve, pad_problem)
+                                         fused_shotgun_rounds)
+from repro.kernels.ops import block_shotgun_solve, pad_problem

@@ -153,17 +153,3 @@ def fused_sparse_shotgun_delta_rounds_ref(rows, vals, z, x, blk_idx, lam,
         rows, vals, z, x, blk_idx, lam, beta, y, loss)
     return x_new, z_new - z.astype(jnp.float32)
 
-
-def block_shotgun_round_ref(A, z, x, blk_idx, lam, beta, y, loss, block: int):
-    """One full Block-Shotgun round (oracle for ops.block_shotgun_round)."""
-    from repro.core import objectives as obj
-    r = obj.residual_like(z, y, loss)
-    g = gather_block_matvec_ref(A, r, blk_idx, block)   # (K, B)
-    d = x.shape[0]
-    xb = x.reshape(d // block, block)
-    x_sel = jnp.take(xb, blk_idx, axis=0)               # (K, B)
-    x_new = obj.soft_threshold(x_sel - g / beta, lam / beta)
-    delta = x_new - x_sel
-    z_new = scatter_block_update_ref(A, z, blk_idx, delta, block)
-    xb = xb.at[blk_idx].add(delta)
-    return xb.reshape(d), z_new, delta

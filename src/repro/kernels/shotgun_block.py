@@ -11,12 +11,7 @@ block of 128 coordinates* at a time so that
   * the gradient gather g_B = A_B^T r and the margin update z += A_B δ are
     (TILE_N × 128) MXU matmuls — arithmetic intensity O(128) flops/byte.
 
-Two single-round kernels, both tiled over the sample dimension n:
-
-  gather_block_matvec   g[k] = A[:, blk_k]ᵀ r        grid (K, T), accumulate over T
-  scatter_block_update  z   += Σ_k A[:, blk_k] δ_k    grid (T, K), accumulate over K
-
-and the fused multi-round kernel (DESIGN §4.2):
+The fused multi-round kernel (DESIGN §4.2):
 
   fused_shotgun_rounds_kernel   R rounds per launch; the margin z, the
   round-start residual r, the iterate x, and the per-round deltas all live
@@ -24,9 +19,9 @@ and the fused multi-round kernel (DESIGN §4.2):
   are the only per-round HBM traffic.  A scalar-prefetched (R, K) index
   matrix selects the blocks each round touches.  When one sample tile
   covers all of n (T == 1) the kernel runs single-phase — each A block is
-  fetched ONCE per round and used for both g_B = A_Bᵀ r and z += A_B δ —
-  halving A traffic vs. the two-kernel round; otherwise it runs the same
-  gather/scatter phases as above but without the z/r/g HBM round trips.
+  fetched ONCE per round and used for both g_B = A_Bᵀ r and z += A_B δ;
+  otherwise it runs a gather phase and a scatter phase over the sample
+  tiles, streaming A twice a round, with z/r/g/δ still in VMEM.
 
 Block size B = 128 (MXU/lane width); the two-phase sample tile is TILE_N
 = 512.  The fused kernel keeps every sample-indexed vector as a lane-dense
@@ -99,107 +94,7 @@ def _check_divisible(n: int, d: int, block: int, tile_n: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Kernel 1: g[k] = A[:, blk_k*B:(blk_k+1)*B]^T r
-# ---------------------------------------------------------------------------
-
-def _gather_matvec_kernel(idx_ref, a_ref, r_ref, g_ref):
-    # grid = (K, T); T (sample tiles) is the fast axis -> accumulate into
-    # row k of the VMEM-resident (K, B) output (a (1, B) block is not a
-    # legal Mosaic tile, so the whole array stays put and flushes once).
-    k = pl.program_id(0)
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        g_ref[pl.ds(k, 1), :] = jnp.zeros((1, g_ref.shape[1]), jnp.float32)
-
-    a = a_ref[...]                       # (TILE_N, B)
-    r = r_ref[...]                       # (TILE_N, 1)
-    # MXU: (B, TILE_N) @ (TILE_N, 1) with f32 accumulation
-    contrib = _f32_dot(a, r, ((0,), (0,)))          # (B, 1)
-    g_ref[pl.ds(k, 1), :] += contrib.reshape(1, -1)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "tile_n", "interpret"))
-def gather_block_matvec(A, r, blk_idx, block: int = BLOCK,
-                        tile_n: int = TILE_N, interpret: bool | None = None):
-    """g (K, block) = per-selected-block column gradients A_Bᵀ r.
-
-    ``interpret=None`` lets the backend decide (``interpret_mode``)."""
-    n, d = A.shape
-    _check_divisible(n, d, block, tile_n)
-    K = blk_idx.shape[0]
-    T = n // tile_n
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(K, T),
-        in_specs=[
-            pl.BlockSpec((tile_n, block), lambda k, t, idx: (t, idx[k])),
-            pl.BlockSpec((tile_n, 1), lambda k, t, idx: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((K, block), lambda k, t, idx: (0, 0)),
-    )
-    return pl.pallas_call(
-        _gather_matvec_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
-        name="gather_block_matvec",
-        **_call_params(interpret),
-    )(blk_idx, A, r.reshape(n, 1))
-
-
-# ---------------------------------------------------------------------------
-# Kernel 2: z += sum_k A[:, blk_k] @ delta_k   (the shared-Ax write)
-# ---------------------------------------------------------------------------
-
-def _scatter_update_kernel(idx_ref, a_ref, d_ref, z_ref, out_ref):
-    # grid = (T, K); K is the fast axis -> accumulate into out[t].
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = z_ref[...].astype(jnp.float32)
-
-    a = a_ref[...]                       # (TILE_N, B)
-    dlt = d_ref[pl.ds(k, 1), :]          # (1, B) row of the resident (K, B)
-    contrib = _f32_dot(a, dlt, ((1,), (1,)))          # (TILE_N, 1)
-    out_ref[...] += contrib
-
-
-@functools.partial(jax.jit, static_argnames=("block", "tile_n", "interpret"))
-def scatter_block_update(A, z, blk_idx, delta, block: int = BLOCK,
-                         tile_n: int = TILE_N, interpret: bool | None = None):
-    """z_new = z + Σ_k A[:, blk_k] δ_k  — f32 accumulation, z.dtype out.
-
-    ``interpret=None`` lets the backend decide (``interpret_mode``)."""
-    n, d = A.shape
-    _check_divisible(n, d, block, tile_n)
-    K = blk_idx.shape[0]
-    T = n // tile_n
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T, K),
-        in_specs=[
-            pl.BlockSpec((tile_n, block), lambda t, k, idx: (t, idx[k])),
-            pl.BlockSpec((K, block), lambda t, k, idx: (0, 0)),
-            pl.BlockSpec((tile_n, 1), lambda t, k, idx: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_n, 1), lambda t, k, idx: (t, 0)),
-    )
-    out = pl.pallas_call(
-        _scatter_update_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        name="scatter_block_update",
-        **_call_params(interpret),
-    )(blk_idx, A, delta.astype(A.dtype), z.reshape(n, 1))
-    return out.reshape(n).astype(z.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Kernel 3: fused multi-round Block-Shotgun — R rounds per launch, z in VMEM
+# The fused multi-round Block-Shotgun kernel — R rounds per launch, z in VMEM
 # ---------------------------------------------------------------------------
 
 LASSO = "lasso"      # kept in sync with repro.core.objectives (string keys
@@ -288,7 +183,8 @@ class Loss(NamedTuple):
         return jnp.sum(m * ll)
 
     def objective(self, z, y, m, x, lam):
-        """F(x) from the VMEM-resident margin/iterate; matches ops._solve."""
+        """F(x) from the VMEM-resident margin/iterate; matches
+        ``objectives.masked_data_loss`` + λ‖x‖₁."""
         return self.data_loss(z, y, m) + lam * jnp.sum(jnp.abs(x))
 
 
@@ -317,8 +213,8 @@ def _make_fused_kernel(loss: Loss, R: int, K: int, T: int, block: int,
                        tile_n: int, emit_dz: bool = False):
     """Kernel body factory.  grid = (R, K) when T == 1 (single-phase: each A
     block fetched once per round), else (R, K, 2, T) (gather phase p=0,
-    scatter phase p=1; A streamed twice per round, as in the two-kernel
-    baseline, but z/r/g/δ never leave VMEM).
+    scatter phase p=1; A streamed twice per round, but z/r/g/δ never leave
+    VMEM).
 
     ``emit_dz`` selects the shard-local engine variant (DESIGN §3/§4.2): z0
     is a read-only *global* margin snapshot; the kernel still keeps its own

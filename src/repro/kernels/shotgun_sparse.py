@@ -1,33 +1,21 @@
 """Pallas Block-Shotgun kernels for BlockedCSC designs (DESIGN §8).
 
-Sparse counterparts of the dense round kernels in ``shotgun_block.py``.
+Sparse counterparts of the dense fused kernels in ``shotgun_block.py``.
 The dense kernels stream whole (tile_n × 128) column blocks of A; at the
 paper's Large-Sparse densities (~0.002) that is ~500× more HBM traffic than
 the nonzeros.  Here a scalar-prefetched block pointer selects the selected
 block's padded (tile, 128) nnz row/value tiles instead, so every kernel
 touches O(tile·128) bytes of A per block instead of O(n·128).
 
-Two single-round kernels (the two-kernel round used by ``ops.py`` and the
-``sparse_block`` engine):
-
-  sparse_gather_block_matvec   g_B = A_Bᵀ r     grid (K,): fetch the block's
-                               (tile, 128) rows/vals tiles, gather r at the
-                               row indices, multiply-accumulate over the
-                               tile axis.
-  sparse_scatter_block_update  z += Σ_B A_B δ_B  grid (K,): scatter-add
-                               vals·δ into a VMEM-resident f32 z accumulator
-                               at the row indices; flushed once per call.
-
-and the fused multi-round kernel (DESIGN §8.3), which composes the nnz-tile
-data path with the §4.2 VMEM-residency dataflow:
+The fused multi-round kernel (DESIGN §8.3) composes the nnz-tile data path
+with the §4.2 VMEM-residency dataflow:
 
   fused_sparse_shotgun_rounds  R rounds in ONE pallas_call.  The margin z,
   the round-start residual r, the iterate x, and the per-round deltas all
   live in VMEM scratch across the whole launch; a scalar-prefetched (R, K)
   block-index matrix selects each grid step's nnz tiles.  Because z is
   full-length in VMEM (never sample-tiled), every round is "single-phase":
-  one tile fetch per block serves both g_B = A_Bᵀ r and z += A_B δ_B, and
-  the z/r/g/δ HBM round trips of the two-kernel round disappear entirely.
+  one tile fetch per block serves both g_B = A_Bᵀ r and z += A_B δ_B.
   ``fused_sparse_shotgun_delta_rounds`` is the shard-local engine variant
   (DESIGN §3): z is a read-only global snapshot and the kernel additionally
   accumulates its contributions into a Δz output for the caller's psum.
@@ -77,9 +65,8 @@ def require_sparse_backend() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Shared per-block update math: the gather/scatter tile bodies and the
-# soft-threshold delta exist ONCE here, used by both the two-kernel round
-# (kernels below + ops.sparse_block_shotgun_round) and the fused round loop.
+# Per-block update math of the fused round: the gather/scatter tile bodies
+# and the soft-threshold delta.
 # ---------------------------------------------------------------------------
 
 def _tile_gather(rows, vals, r_flat):
@@ -98,118 +85,13 @@ def _tile_scatter(z_flat, rows, vals, dlt):
 
 def block_delta(x_sel, g, lam, beta):
     """The per-block Shotgun update δ_B = S(x_B − g_B/β, λ/β) − x_B (Alg. 2
-    soft-threshold step) — shared by ``ops.sparse_block_shotgun_round`` and
-    the fused round loop so the threshold logic exists once."""
+    soft-threshold step); ``beta`` is the Lipschitz bound or, for a Newton
+    spec, the per-block curvature."""
     return _soft_threshold(x_sel - g / beta, lam / beta) - x_sel
 
 
 # ---------------------------------------------------------------------------
-# Kernel 1: g[k] = A_{B_k}ᵀ r from nnz tiles
-# ---------------------------------------------------------------------------
-
-def _gather_kernel(idx_ref, rows_ref, vals_ref, r_ref, g_ref):
-    # grid = (K,); one selected column block per step, written to row k of
-    # the resident (K, block) output.
-    k = pl.program_id(0)
-    g_ref[pl.ds(k, 1), :] = _tile_gather(rows_ref[0],
-                                         vals_ref[0].astype(jnp.float32),
-                                         r_ref[...].reshape(-1))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sparse_gather_block_matvec(rows, vals, r, blk_idx,
-                               interpret: bool | None = None):
-    """g (K, block) = A_Bᵀ r for the selected blocks, from nnz tiles.
-
-    rows/vals: (nblk, tile, block) BlockedCSC tiles; r: (n,) f32;
-    blk_idx: (K,) int32.
-    """
-    nblk, tile, block = rows.shape
-    n = r.shape[0]
-    K = blk_idx.shape[0]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(K,),
-        in_specs=[
-            pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
-            pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
-            pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((K, block), lambda k, idx: (0, 0)),
-    )
-    return pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, block), jnp.float32),
-        name="sparse_gather_block_matvec",
-        **_call_params(interpret),
-    )(blk_idx.astype(jnp.int32), rows, vals,
-      r.reshape(n, 1).astype(jnp.float32))
-
-
-# ---------------------------------------------------------------------------
-# Kernel 2: z += Σ_k A_{B_k} δ_k from nnz tiles
-# ---------------------------------------------------------------------------
-
-def _make_scatter_kernel(K: int):
-    def kernel(idx_ref, rows_ref, vals_ref, d_ref, z_ref, out_ref, acc_ref):
-        k = pl.program_id(0)
-
-        @pl.when(k == 0)
-        def _init():
-            acc_ref[...] = z_ref[...].astype(jnp.float32)
-
-        n = acc_ref.shape[0]
-        acc_ref[...] = _tile_scatter(
-            acc_ref[...].reshape(-1), rows_ref[0],
-            vals_ref[0].astype(jnp.float32),
-            d_ref[pl.ds(k, 1), :]).reshape(n, 1)
-
-        @pl.when(k == K - 1)
-        def _flush():
-            out_ref[...] = acc_ref[...]
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sparse_scatter_block_update(rows, vals, z, blk_idx, delta,
-                                interpret: bool | None = None):
-    """z_new = z + Σ_k A_{B_k} δ_k from nnz tiles — f32 accumulation.
-
-    delta: (K, block).  Duplicate blocks in ``blk_idx`` accumulate, matching
-    the multiset semantics of the dense scatter.
-    """
-    nblk, tile, block = rows.shape
-    n = z.shape[0]
-    K = blk_idx.shape[0]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(K,),
-        in_specs=[
-            pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
-            pl.BlockSpec((1, tile, block), lambda k, idx: (idx[k], 0, 0)),
-            pl.BlockSpec((K, block), lambda k, idx: (0, 0)),
-            pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, 1), lambda k, idx: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _make_scatter_kernel(K),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        name="sparse_scatter_block_update",
-        **_call_params(interpret),
-    )(blk_idx.astype(jnp.int32), rows, vals,
-      delta.astype(jnp.float32), z.reshape(n, 1).astype(jnp.float32))
-    return out.reshape(n).astype(z.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Kernel 3: fused multi-round sparse Shotgun — R rounds per launch, z and
+# The fused multi-round sparse Shotgun kernel — R rounds per launch, z and
 # the Δz accumulator resident in VMEM, nnz tiles as the only per-round A
 # traffic (DESIGN §8.3).
 # ---------------------------------------------------------------------------

@@ -70,7 +70,7 @@ def test_batched_dense_slots_bit_identical_to_standalone():
     res = batched_block_shotgun_solve(probs, keys, K, ROUNDS,
                                       rounds_per_launch=R)
     for s, (p, k) in enumerate(zip(probs, keys)):
-        ref = ops.block_shotgun_solve(p, k, K, ROUNDS, fused=True,
+        ref = ops.block_shotgun_solve(p, k, K, ROUNDS,
                                       rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
@@ -86,7 +86,7 @@ def test_batched_sparse_slots_bit_identical_to_standalone():
     res = batched_block_shotgun_solve(probs, keys, K, ROUNDS,
                                       rounds_per_launch=R)
     for s, (p, k) in enumerate(zip(probs, keys)):
-        ref = ops.block_shotgun_solve(p, k, K, ROUNDS, fused=True,
+        ref = ops.block_shotgun_solve(p, k, K, ROUNDS,
                                       rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
@@ -113,7 +113,7 @@ def test_heterogeneous_tile_admission_matches_stream_tiling():
                            vals=jnp.pad(S.vals, pad),
                            n=S.n, d=S.d, block=S.block)
         ref = ops.block_shotgun_solve(p._replace(A=S), k, K, ROUNDS,
-                                      fused=True, rounds_per_launch=R)
+                                      rounds_per_launch=R)
         assert np.array_equal(np.asarray(res.x[s][: p.d]),
                               np.asarray(ref.x)), f"slot {s}"
 

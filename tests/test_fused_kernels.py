@@ -1,7 +1,7 @@
 """Fused multi-round Block-Shotgun kernel (DESIGN §4.2): interpret-mode
 equivalence against the pure-jnp multi-round oracle, padding/duplicate-draw
-edge cases, bf16 A storage, and solver-level trace parity (the fused launch
-scan must reproduce the two-kernel round scan exactly, same key)."""
+edge cases, bf16 A storage, and solver-level trace parity (the launch scan
+must reproduce an oracle replay of its own block draws)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,23 +169,31 @@ def test_zeta_cell_shape_runs_single_phase():
         auto_tile_n(24000, d=d, K=K)
 
 
-def test_fused_solve_trace_parity():
-    """block_shotgun_solve(fused=True) must retrace the two-kernel solver:
-    same key -> same block draws -> same objective/nnz trajectory.  Guards
-    the launch-scan refactor against trajectory drift."""
-    A, y, _ = syn.sparco(seed=6, n=640, d=1024)
-    prob = obj.make_problem(A, y, lam=1.0)
-    key = jax.random.PRNGKey(0)
-    two = ops.block_shotgun_solve(prob, key, K=2, rounds=32)
-    fus = ops.block_shotgun_solve(prob, key, K=2, rounds=32,
-                                  fused=True, rounds_per_launch=8)
-    f2, ff = np.asarray(two.trace.objective), np.asarray(fus.trace.objective)
-    np.testing.assert_allclose(ff, f2, rtol=2e-5)
-    np.testing.assert_array_equal(np.asarray(fus.trace.nnz),
-                                  np.asarray(two.trace.nnz))
-    np.testing.assert_allclose(np.asarray(fus.x), np.asarray(two.x),
+@pytest.mark.parametrize("loss", [obj.LASSO, obj.LOGISTIC])
+def test_fused_solve_trace_parity(loss):
+    """block_shotgun_solve must retrace a replay of its own block draws
+    (round t: ``choice(split(key, rounds)[t], nblk, (K,))``) through the
+    pure-jnp oracle: same objective/nnz trajectory, iterate and margin.
+    Guards the launch scan against trajectory drift."""
+    prob, Ap, yp, mask = _padded_problem(loss, seed=6, n=640, d=1024,
+                                         lam=1.0 if loss == obj.LASSO
+                                         else 0.05)
+    key, K, rounds = jax.random.PRNGKey(0), 2, 32
+    fus = ops.block_shotgun_solve(prob, key, K=K, rounds=rounds,
+                                  rounds_per_launch=8)
+    idx = jax.vmap(lambda kt: jax.random.choice(
+        kt, Ap.shape[1] // BLOCK, (K,), replace=False))(
+        jax.random.split(key, rounds)).astype(jnp.int32)
+    x0 = jnp.zeros(Ap.shape[1], jnp.float32)
+    xr, zr, fr, nr = ref.fused_shotgun_rounds_ref(
+        Ap, jnp.zeros(Ap.shape[0], jnp.float32), x0, idx, prob.lam,
+        prob.beta, yp, mask, loss, BLOCK)
+    np.testing.assert_allclose(np.asarray(fus.trace.objective),
+                               np.asarray(fr), rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(fus.trace.nnz), np.asarray(nr))
+    np.testing.assert_allclose(np.asarray(fus.x), np.asarray(xr[: prob.d]),
                                rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(fus.z), np.asarray(two.z),
+    np.testing.assert_allclose(np.asarray(fus.z), np.asarray(zr[: prob.n]),
                                rtol=1e-4, atol=1e-4)
 
 
@@ -194,7 +202,7 @@ def test_fused_solve_rejects_indivisible_rounds():
     prob = obj.make_problem(A, y, lam=0.5)
     with pytest.raises(ValueError, match="rounds_per_launch"):
         ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=1, rounds=9,
-                                fused=True, rounds_per_launch=8)
+                                rounds_per_launch=8)
 
 
 def test_solver_registry_exposes_fused():

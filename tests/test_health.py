@@ -91,38 +91,27 @@ def test_guard_is_bitexact_noop_at_safe_p(corr_prob):
 # Fused Pallas solver: in-kernel sentinel + launch-granular backoff
 # ---------------------------------------------------------------------------
 
-def test_fused_guard_backs_off_and_recovers():
+@pytest.mark.parametrize("layout", ["dense", "bcsc"])
+def test_fused_guard_backs_off_and_recovers(layout):
     A, y, _ = syn.sparco(seed=0, n=256, d=2048)   # d >> n: rho > d, P* = 1
+    if layout == "bcsc":
+        from repro.data.sparse import BlockedCSC
+        A = BlockedCSC.from_dense(A)
     prob = obj.make_problem(A, y, lam=1.0)
     key = jax.random.PRNGKey(0)
 
-    r_un = ops.fused_block_shotgun_solve(prob, key, K=16, rounds=96,
-                                         rounds_per_launch=8)
+    r_un = ops.block_shotgun_solve(prob, key, K=16, rounds=96,
+                                   rounds_per_launch=8)
     assert int(r_un.status) == health.STATUS_DIVERGED
 
-    r_g = ops.fused_block_shotgun_solve(prob, key, K=16, rounds=96,
-                                        rounds_per_launch=8,
-                                        guard=GuardConfig(factor=10.0,
-                                                          p_min=1))
+    r_g = ops.block_shotgun_solve(prob, key, K=16, rounds=96,
+                                  rounds_per_launch=8,
+                                  guard=GuardConfig(factor=10.0, p_min=1))
     f = r_g.trace.objective
     assert int(r_g.status) == health.STATUS_RECOVERED
     assert bool(jnp.all(jnp.isfinite(f)))
     # after backing off to a safe K the solve makes real progress again
     assert float(f[-1]) < 0.5 * float(f[0])
-
-
-def test_block_guard_round_granular():
-    A, y, _ = syn.sparco(seed=0, n=256, d=2048)
-    prob = obj.make_problem(A, y, lam=1.0)
-    key = jax.random.PRNGKey(0)
-    r_un = ops.block_shotgun_solve(prob, key, K=16, rounds=64)
-    assert int(r_un.status) == health.STATUS_DIVERGED
-    r_g = ops.block_shotgun_solve(prob, key, K=16, rounds=64,
-                                  guard=GuardConfig(factor=10.0, p_min=1))
-    f = r_g.trace.objective
-    assert int(r_g.status) == health.STATUS_RECOVERED
-    assert bool(jnp.all(jnp.isfinite(f)))
-    assert float(f[-1]) < float(f[0])
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Pallas kernel allclose sweeps (the interpreter on the CPU backend) against the ref.py oracles,
-across shapes and dtypes, plus full-round and solver-level parity."""
+across shapes, dtypes and losses, plus solver-level convergence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +8,7 @@ import pytest
 from repro.core import objectives as obj
 from repro.data import synthetic as syn
 from repro.kernels import ops, ref
-from repro.kernels.shotgun_block import gather_block_matvec, scatter_block_update
+from repro.kernels.shotgun_block import fused_shotgun_rounds
 
 SHAPES = [
     # (n, d, block, tile_n, K)
@@ -21,61 +21,45 @@ SHAPES = [
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
-def _mk(n, d, dtype, seed=0):
+LOSSES = [obj.LASSO, obj.LOGISTIC, "logistic_newton"]
+
+
+def _mk(n, d, dtype, loss, seed=0):
+    """A stored in ``dtype``; labels ±1 for the logistic losses; a mask
+    with its last 16 samples off, as ``pad_problem`` leaves padded ones."""
     rng = np.random.default_rng(seed)
-    A = jnp.asarray(rng.standard_normal((n, d)), dtype)
-    r = jnp.asarray(rng.standard_normal(n), dtype)
-    z = jnp.asarray(rng.standard_normal(n), dtype)
-    return A, r, z
+    A = jnp.asarray(rng.standard_normal((n, d)) / np.sqrt(n), dtype)
+    y = rng.standard_normal(n)
+    if loss != obj.LASSO:
+        y = np.where(y >= 0, 1.0, -1.0)
+    mask = np.ones(n)
+    mask[-16:] = 0.0
+    x = jnp.asarray(rng.standard_normal(d) * 0.1, jnp.float32)
+    z = jnp.asarray(A, jnp.float32) @ x
+    return A, jnp.asarray(y, jnp.float32), jnp.asarray(mask, jnp.float32), x, z
 
 
 @pytest.mark.parametrize("n,d,block,tile_n,K", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_gather_block_matvec_allclose(n, d, block, tile_n, K, dtype):
-    A, r, _ = _mk(n, d, dtype)
-    nblk = d // block
-    blk = jax.random.choice(jax.random.PRNGKey(1), nblk, (K,), replace=False)
-    got = gather_block_matvec(A, r, blk, block=block, tile_n=tile_n)
-    want = ref.gather_block_matvec_ref(A, r, blk, block)
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=tol, atol=tol * 10)
-
-
-@pytest.mark.parametrize("n,d,block,tile_n,K", SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_scatter_block_update_allclose(n, d, block, tile_n, K, dtype):
-    A, _, z = _mk(n, d, dtype, seed=1)
-    rng = np.random.default_rng(2)
-    nblk = d // block
-    blk = jax.random.choice(jax.random.PRNGKey(2), nblk, (K,), replace=False)
-    delta = jnp.asarray(rng.standard_normal((K, block)) * 0.1, dtype)
-    got = scatter_block_update(A, z, blk, delta, block=block, tile_n=tile_n)
-    want = ref.scatter_block_update_ref(A, z, blk, delta, block)
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol * 10)
-
-
-@pytest.mark.parametrize("loss", [obj.LASSO, obj.LOGISTIC])
-def test_block_round_matches_ref(loss):
-    A, y, _ = (syn.sparco(seed=3, n=512, d=512) if loss == obj.LASSO
-               else syn.logistic_data(seed=3, n=512, d=512))
-    prob = obj.make_problem(A, y, lam=0.4, loss=loss)
-    Ap, yp, mask = ops.pad_problem(prob.A, prob.y)
-    rng = np.random.default_rng(4)
-    x = jnp.asarray(rng.standard_normal(Ap.shape[1]) * 0.1, jnp.float32)
-    z = Ap @ x
-    blk = jax.random.choice(jax.random.PRNGKey(5), Ap.shape[1] // ops.BLOCK,
-                            (3,), replace=False)
-    x_k, z_k, d_k = ops.block_shotgun_round(Ap, z, x, blk, prob.lam, prob.beta,
-                                            yp, mask, loss=loss)
-    x_r, z_r, d_r = ref.block_shotgun_round_ref(Ap, z, x, blk, prob.lam,
-                                                prob.beta, yp, loss, ops.BLOCK)
-    np.testing.assert_allclose(np.asarray(x_k), np.asarray(x_r), rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(z_k), np.asarray(z_r), rtol=1e-3, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("loss", LOSSES)
+def test_fused_rounds_allclose_sweep(n, d, block, tile_n, K, dtype, loss):
+    """Two fused rounds against ``ref.fused_shotgun_rounds_ref`` over the
+    sweep's shapes, block widths (256 included), sample tiles (two-phase
+    where tile_n < n) and stored dtypes of A, for every loss spec."""
+    A, y, mask, x, z = _mk(n, d, dtype, loss)
+    beta = 1.0 if loss == obj.LASSO else 0.25
+    idx = jnp.stack([
+        jax.random.choice(jax.random.PRNGKey(r), d // block, (K,),
+                          replace=False) for r in range(2)]).astype(jnp.int32)
+    got = fused_shotgun_rounds(A, z, x, idx, 0.05, beta, y, mask, loss=loss,
+                               block=block, tile_n=tile_n)
+    want = ref.fused_shotgun_rounds_ref(A, z, x, idx, 0.05, beta, y, mask,
+                                        loss, block)
+    tol = 1e-4 if dtype == jnp.float32 else 1e-3
+    for g, w in zip(got[:3], want[:3]):          # x, z, f
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
 
 
 def test_block_solver_converges_to_reference_objective():

@@ -103,7 +103,7 @@ def test_fused_logistic_matches_scalar_solution_dense():
     prob = obj.make_problem(A, y, lam=0.5, loss=obj.LOGISTIC)
     rf = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0),
                                  spec=SolverSpec(loss="logistic", P=256,
-                                                 rounds=600, fused=True))
+                                                 rounds=600))
     rs = shotgun_solve(prob, jax.random.PRNGKey(1),
                        spec=SolverSpec(loss="logistic", P=256, rounds=1500))
     ff, fs = float(rf.trace.objective[-1]), float(rs.trace.objective[-1])
@@ -116,7 +116,7 @@ def test_fused_logistic_matches_scalar_solution_bcsc():
     prob = _bcsc_logistic_problem()
     rf = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0),
                                  spec=SolverSpec(loss="logistic", P=128,
-                                                 rounds=600, fused=True))
+                                                 rounds=600))
     rs = shotgun_solve(prob, jax.random.PRNGKey(1),
                        spec=SolverSpec(loss="logistic", P=128, rounds=2000))
     ff, fs = float(rf.trace.objective[-1]), float(rs.trace.objective[-1])
@@ -134,7 +134,7 @@ def test_logistic_beta_quarter_converges_near_pstar():
     assert p_star(prob.A) >= BLOCK      # K=1 → P=128 is theory-legal
     r = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0),
                                 spec=SolverSpec(loss="logistic", P=BLOCK,
-                                                rounds=200, fused=True))
+                                                rounds=200))
     tr = np.asarray(r.trace.objective)
     assert not bool(diverged(tr))
     assert tr[-1] < tr[0]
@@ -147,9 +147,9 @@ def test_newton_beats_gradient_rounds_to_tolerance():
     prob = _logistic_problem()
     key = jax.random.PRNGKey(0)
     rg = ops.block_shotgun_solve(prob, key, spec=SolverSpec(
-        loss="logistic", P=256, rounds=64, fused=True))
+        loss="logistic", P=256, rounds=64))
     rn = ops.block_shotgun_solve(prob, key, spec=SolverSpec(
-        loss="logistic", P=256, rounds=64, fused=True, newton=True))
+        loss="logistic", P=256, rounds=64, newton=True))
     fg, fn = np.asarray(rg.trace.objective), np.asarray(rn.trace.objective)
     fstar = min(fg.min(), fn.min())
     r_grad = int(rounds_to_tolerance(fg, fstar, 0.005))
@@ -177,10 +177,9 @@ def test_spec_shim_bit_for_bit_fused():
     prob = _logistic_problem(n=300, d=256)
     key = jax.random.PRNGKey(2)
     with pytest.warns(DeprecationWarning):
-        r_old = ops.block_shotgun_solve(prob, key, K=1, rounds=8,
-                                        fused=True)
+        r_old = ops.block_shotgun_solve(prob, key, K=1, rounds=8)
     r_new = ops.block_shotgun_solve(prob, key, spec=SolverSpec(
-        loss="logistic", P=128, rounds=8, fused=True))
+        loss="logistic", P=128, rounds=8))
     np.testing.assert_array_equal(np.asarray(r_old.x), np.asarray(r_new.x))
     np.testing.assert_array_equal(np.asarray(r_old.trace.objective),
                                   np.asarray(r_new.trace.objective))
@@ -202,14 +201,13 @@ def test_spec_shim_bit_for_bit_batched():
 
 def test_spec_rejects_mixed_interfaces_and_bad_combos():
     prob = _logistic_problem(n=200, d=128)
-    spec = SolverSpec(loss="logistic", P=128, rounds=4, fused=True)
+    spec = SolverSpec(loss="logistic", P=128, rounds=4)
     with pytest.raises(ValueError, match="spec"):
         ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=1, rounds=4,
                                 spec=spec)
-    # newton is a fused-kernel feature (the curvature scratch lives in the
-    # fused round body) — the spec constructor enforces it
-    with pytest.raises(ValueError, match="newton"):
-        SolverSpec(loss="logistic", P=128, rounds=4, newton=True)
+    # the two-kernel round is retired: a spec that asks for it is refused
+    with pytest.raises(ValueError, match="two-kernel"):
+        SolverSpec(loss="logistic", P=128, rounds=4, fused=False)
     # spec loss must match the problem's loss
     lasso = obj.make_problem(*syn.sparco(seed=0, n=128, d=256)[:2], lam=0.5)
     with pytest.raises(ValueError) as ei:
@@ -246,9 +244,8 @@ def test_logreg_fused_aliases():
     rs = get_solver("sparse_logreg_fused")(
         sprob, jax.random.PRNGKey(0), 1, 2, rounds_per_launch=2)
     assert np.isfinite(float(rs.trace.objective[-1]))
-    # the alias speaks spec= too, promoting fused=True (a spec left at
-    # its fused=False default must not silently fall off the fused path),
-    # and refuses the mixed spec+legacy interface like every entry point
+    # the alias speaks spec= too, and refuses the mixed spec+legacy
+    # interface like every entry point
     r2 = get_solver("shotgun_logreg_fused")(
         prob, jax.random.PRNGKey(0), rounds_per_launch=2,
         spec=SolverSpec(loss="logistic", P=128, rounds=2))

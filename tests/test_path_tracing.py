@@ -212,23 +212,12 @@ def _sparse_args(nblk=4, tile=8, block=128, n=64, R=2, K=2):
 def _kernel_calls():
     A, z, x, idx, lam, beta, y, mask = _dense_args()
     rows, vals, zs, xs, sidx, _, _, ys = _sparse_args()
-    delta = jnp.zeros((2, 128))
     return {
-        "gather_block_matvec": lambda: shotgun_block.gather_block_matvec(
-            A, z, idx[0]),
-        "scatter_block_update": lambda: shotgun_block.scatter_block_update(
-            A, z, idx[0], delta),
         "fused_shotgun_rounds": lambda: shotgun_block.fused_shotgun_rounds(
             A, z, x, idx, lam, beta, y, mask),
         "fused_shotgun_delta_rounds":
             lambda: shotgun_block.fused_shotgun_delta_rounds(
                 A, z, x, idx, lam, beta, y, mask),
-        "sparse_gather_block_matvec":
-            lambda: shotgun_sparse.sparse_gather_block_matvec(
-                rows, vals, zs, sidx[0]),
-        "sparse_scatter_block_update":
-            lambda: shotgun_sparse.sparse_scatter_block_update(
-                rows, vals, zs, sidx[0], delta),
         "fused_sparse_shotgun_rounds":
             lambda: shotgun_sparse.fused_sparse_shotgun_rounds(
                 rows, vals, zs, xs, sidx, lam, beta, ys),
@@ -250,10 +239,8 @@ def _pallas_names(jaxpr):
 
 
 @pytest.mark.parametrize("name", [
-    "gather_block_matvec", "scatter_block_update", "fused_shotgun_rounds",
-    "fused_shotgun_delta_rounds", "sparse_gather_block_matvec",
-    "sparse_scatter_block_update", "fused_sparse_shotgun_rounds",
-    "fused_sparse_shotgun_delta_rounds"])
+    "fused_shotgun_rounds", "fused_shotgun_delta_rounds",
+    "fused_sparse_shotgun_rounds", "fused_sparse_shotgun_delta_rounds"])
 def test_every_pallas_call_carries_its_own_name(name):
     jaxpr = jax.make_jaxpr(_kernel_calls()[name])().jaxpr
     assert _pallas_names(jaxpr) == [name]

@@ -1,5 +1,5 @@
 """Round-engine architecture (DESIGN §3): the sharded driver over
-scalar/block/fused engines, merge cadences, Δz wire compression, and the
+scalar/fused engines, merge cadences, Δz wire compression, and the
 λ-path registry wiring.
 
 Single-shard trace-equivalence and validation run in-process (a 1-device
@@ -37,31 +37,26 @@ def prob():
 # Single-shard trace equivalence (acceptance: sharded-fused == fused solver)
 # ---------------------------------------------------------------------------
 
-def test_fused_engine_single_shard_matches_fused_solver(prob):
+@pytest.mark.parametrize("loss", [obj.LASSO, "logistic_newton"])
+def test_fused_engine_single_shard_matches_fused_solver(prob, loss):
     """engine="fused", merge="round" on a 1-shard mesh must retrace
-    ``block_shotgun_solve(fused=True)`` for the same key: same split/choice
-    draws, same kernel dataflow, Δz merged through an identity psum."""
+    ``block_shotgun_solve`` for the same key: same split/choice draws,
+    same kernel dataflow, Δz merged through an identity psum."""
+    newton = loss == "logistic_newton"
+    if newton:
+        A, y, _ = syn.logistic_data(seed=6, n=640, d=1024)
+        prob = obj.make_problem(A, y, lam=0.05, loss=obj.LOGISTIC)
     key = jax.random.PRNGKey(0)
     sh = shotgun_sharded_solve(prob, key, rounds=16, mesh=_mesh1(),
-                               engine="fused", merge="round", K=2)
+                               engine="fused", merge="round", K=2,
+                               newton=newton)
     fu = ops.block_shotgun_solve(prob, key, K=2, rounds=16,
-                                 fused=True, rounds_per_launch=8)
+                                 rounds_per_launch=8, newton=newton)
     np.testing.assert_allclose(np.asarray(sh.trace.objective),
                                np.asarray(fu.trace.objective), rtol=2e-5)
     np.testing.assert_allclose(np.asarray(sh.x), np.asarray(fu.x),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(sh.z), np.asarray(fu.z),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_block_engine_single_shard_matches_two_kernel_solver(prob):
-    key = jax.random.PRNGKey(0)
-    sh = shotgun_sharded_solve(prob, key, rounds=8, mesh=_mesh1(),
-                               engine="block", merge="round", K=2)
-    tk = ops.block_shotgun_solve(prob, key, K=2, rounds=8)
-    np.testing.assert_allclose(np.asarray(sh.trace.objective),
-                               np.asarray(tk.trace.objective), rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(sh.x), np.asarray(tk.x),
                                rtol=1e-4, atol=1e-4)
 
 
@@ -115,13 +110,17 @@ def test_divisibility_value_errors(prob):
 
 def test_kernel_shape_checks_raise_value_error_not_assert():
     """Tiling checks survive ``python -O``: they must be ValueErrors."""
-    from repro.kernels.shotgun_block import gather_block_matvec
-    A = jnp.zeros((256, 200))          # 200 % 128 != 0
+    from repro.kernels.shotgun_block import fused_shotgun_rounds
+
+    def call(n, d, **kw):
+        v = jnp.zeros(n)
+        return fused_shotgun_rounds(jnp.zeros((n, d)), v, jnp.zeros(d),
+                                    jnp.zeros((1, 1), jnp.int32), 1.0, 1.0,
+                                    v, jnp.ones(n), **kw)
     with pytest.raises(ValueError, match="block"):
-        gather_block_matvec(A, jnp.zeros(256), jnp.zeros(1, jnp.int32))
-    A = jnp.zeros((250, 256))          # 250 % 512 != 0
+        call(256, 200)                 # 200 % 128 != 0
     with pytest.raises(ValueError, match="tile_n"):
-        gather_block_matvec(A, jnp.zeros(250), jnp.zeros(1, jnp.int32))
+        call(250, 256, tile_n=512)     # 250 % 512 != 0
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +154,9 @@ def test_sharded_solver_warm_start(prob):
                               mesh=_mesh1()).trace.objective[0])
 
 
-@pytest.mark.parametrize("name", ["shotgun", "block", "block_fused"])
+@pytest.mark.parametrize("name", [
+    "shotgun", pytest.param(("block_fused", "lasso"), id="block_fused-lasso"),
+    "block_fused"])
 def test_solve_path_runs_on_registry_solvers(name):
     from repro.core.path import solve_path
     A, y, _ = syn.sparco(seed=0, n=512, d=1024)

@@ -1,5 +1,5 @@
 """Blocked-CSC sparse data path (DESIGN §8): container/ops correctness,
-sparse Pallas kernels vs the dense oracles, and dense-vs-sparse solver
+the fused sparse Pallas kernels vs the oracles, and dense-vs-sparse solver
 equivalence (same key => same trajectory) across the stack."""
 import jax
 import jax.numpy as jnp
@@ -13,9 +13,7 @@ from repro.data import synthetic as syn
 from repro.data.sparse import BlockedCSC, pad_feature_blocks
 from repro.kernels import ops, ref
 from repro.kernels.shotgun_sparse import (fused_sparse_shotgun_delta_rounds,
-                                          fused_sparse_shotgun_rounds,
-                                          sparse_gather_block_matvec,
-                                          sparse_scatter_block_update)
+                                          fused_sparse_shotgun_rounds)
 
 
 def _pair(seed=0, n=256, d=512, density=0.02, category="sparse_imaging"):
@@ -144,34 +142,6 @@ def test_pad_feature_blocks_zero_tail():
 
 
 # ---------------------------------------------------------------------------
-# Sparse Pallas kernels vs dense oracles
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("K", [1, 3])
-def test_sparse_gather_kernel_matches_dense_ref(K):
-    Ad, S, _ = _pair(seed=4)
-    r = jnp.asarray(np.random.default_rng(5).standard_normal(S.n), jnp.float32)
-    blk = jax.random.choice(jax.random.PRNGKey(6), S.nblk, (K,), replace=False)
-    got = sparse_gather_block_matvec(S.rows, S.vals, r, blk)
-    want = ref.gather_block_matvec_ref(jnp.asarray(Ad), r, blk, S.block)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("K", [1, 3])
-def test_sparse_scatter_kernel_matches_dense_ref(K):
-    Ad, S, _ = _pair(seed=7)
-    rng = np.random.default_rng(8)
-    z = jnp.asarray(rng.standard_normal(S.n), jnp.float32)
-    delta = jnp.asarray(rng.standard_normal((K, S.block)) * 0.1, jnp.float32)
-    blk = jax.random.choice(jax.random.PRNGKey(9), S.nblk, (K,), replace=False)
-    got = sparse_scatter_block_update(S.rows, S.vals, z, blk, delta)
-    want = ref.scatter_block_update_ref(jnp.asarray(Ad), z, blk, delta, S.block)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
 # Solver-level equivalence: same key => same trajectory
 # ---------------------------------------------------------------------------
 
@@ -190,22 +160,6 @@ def test_sparse_shotgun_matches_dense_trajectory(category):
     # acceptance: objective parity well under 1%
     f_d, f_s = float(rd.trace.objective[-1]), float(rs.trace.objective[-1])
     assert abs(f_s - f_d) / abs(f_d) < 0.01
-
-
-@pytest.mark.parametrize("category", ["sparse_imaging", "large_sparse"])
-def test_sparse_block_solver_matches_dense_trajectory(category):
-    """The sparse Pallas path draws the same blocks for the same key as the
-    dense two-kernel path, so whole trajectories coincide."""
-    Ad, S, y = _pair(category=category)
-    pd = obj.make_problem(Ad, y, lam=0.5)
-    ps = obj.make_problem(S, y, lam=0.5)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(1), K=2, rounds=80)
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80)
-    np.testing.assert_allclose(np.asarray(rs.trace.objective),
-                               np.asarray(rd.trace.objective),
-                               rtol=1e-3, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(rs.x), np.asarray(rd.x),
-                               rtol=1e-3, atol=1e-3)
 
 
 def test_sparse_warm_start_threads_through():
@@ -234,36 +188,18 @@ def test_sparse_path_continuation():
     assert path.x.shape == (S.d,)
 
 
-def test_sparse_engine_single_shard_matches_block_solver():
-    """sharded sparse_block engine on a 1-shard mesh draws the same blocks
-    as the single-device sparse solver (DESIGN §3 trace equivalence)."""
-    from repro.core.sharded import make_feature_mesh, shotgun_sharded_solve
-    _, S, y = _pair()
-    ps = obj.make_problem(S, y, lam=0.5)
-    mesh = make_feature_mesh(jax.devices()[:1])
-    rounds = 40
-    r_blk = ops.block_shotgun_solve(ps, jax.random.PRNGKey(4), K=2,
-                                    rounds=rounds)
-    r_sh = shotgun_sharded_solve(ps, jax.random.PRNGKey(4), rounds=rounds,
-                                 engine="sparse_block", K=2, mesh=mesh,
-                                 trace_every=rounds)
-    np.testing.assert_allclose(float(r_sh.trace.objective[-1]),
-                               float(r_blk.trace.objective[-1]), rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(r_sh.x), np.asarray(r_blk.x),
-                               rtol=1e-3, atol=1e-3)
-
-
 # ---------------------------------------------------------------------------
 # Fused multi-round sparse kernel (DESIGN §8.3)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("category", ["sparse_imaging", "large_sparse"])
-def test_fused_sparse_kernel_matches_refs(category):
+@pytest.mark.parametrize("K", [1, 3])
+def test_fused_sparse_kernel_matches_refs(category, K):
     """The fused sparse kernel retraces both the nnz-tile oracle and the
     dense fused oracle for the same (R, K) index matrix."""
     Ad, S, y = _pair(seed=10, category=category)
     rng = np.random.default_rng(11)
-    R, K = 4, 2
+    R = 4
     idx = jnp.asarray(rng.integers(0, S.nblk, (R, K)), jnp.int32)
     x = jnp.asarray(rng.standard_normal(S.d_pad) * 0.1, jnp.float32)
     z = S.matvec(x)
@@ -311,32 +247,21 @@ def test_fused_sparse_delta_rounds_matches_ref():
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("category", ["sparse_imaging", "large_sparse"])
-def test_fused_sparse_solver_matches_two_kernel_sparse(category):
-    """block_shotgun_solve(fused=True) on BlockedCSC draws the same blocks
-    as the two-kernel sparse scan for the same key, so whole trajectories
-    coincide (the §8.3 acceptance equivalence)."""
-    _, S, y = _pair(category=category)
-    ps = obj.make_problem(S, y, lam=0.5)
-    r2 = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80)
-    rf = ops.block_shotgun_solve(ps, jax.random.PRNGKey(1), K=2, rounds=80, fused=True,
-                                 rounds_per_launch=8)
-    np.testing.assert_allclose(np.asarray(rf.trace.objective),
-                               np.asarray(r2.trace.objective),
-                               rtol=1e-3, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(rf.x), np.asarray(r2.x),
-                               rtol=1e-3, atol=1e-3)
-
-
-def test_fused_sparse_solver_matches_dense_fused():
-    """Same key on the densified design: fused-sparse == dense-fused."""
-    Ad, S, y = _pair()
+@pytest.mark.parametrize("category,seed,rounds,rounds_per_launch", [
+    ("sparse_imaging", 1, 80, 8), ("large_sparse", 1, 80, 8),
+    ("sparse_imaging", 1, 80, 4), ("large_sparse", 1, 80, 5),
+    ("sparse_imaging", 5, 16, 8)])
+def test_fused_sparse_solver_matches_dense_fused(category, seed, rounds,
+                                                 rounds_per_launch):
+    """Same key on the densified design: the sparse solve draws the same
+    blocks as the dense one, so whole trajectories coincide (the §8.3
+    acceptance equivalence), whatever the launch length."""
+    Ad, S, y = _pair(category=category)
     pd = obj.make_problem(Ad, y, lam=0.5)
     ps = obj.make_problem(S, y, lam=0.5)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(5), K=2, rounds=16, fused=True,
-                                 rounds_per_launch=8)
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(5), K=2, rounds=16, fused=True,
-                                 rounds_per_launch=8)
+    kw = dict(K=2, rounds=rounds, rounds_per_launch=rounds_per_launch)
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(seed), **kw)
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(seed), **kw)
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
                                rtol=1e-3, atol=1e-3)
@@ -349,7 +274,7 @@ def test_fused_sparse_rejects_bad_rounds_per_launch():
     ps = obj.make_problem(S, y, lam=0.5)
     with pytest.raises(ValueError, match="rounds=10"):
         ops.block_shotgun_solve(ps, jax.random.PRNGKey(0), K=2, rounds=10,
-                                fused=True, rounds_per_launch=8)
+                                rounds_per_launch=8)
 
 
 def test_fused_sparse_warm_start():
@@ -360,22 +285,22 @@ def test_fused_sparse_warm_start():
     ps = obj.make_problem(S, y, lam=0.5)
     x0 = np.asarray(shotgun_solve(pd, jax.random.PRNGKey(2), P=8,
                                   rounds=200).x)
-    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
+    rd = ops.block_shotgun_solve(pd, jax.random.PRNGKey(3), K=2, rounds=16,
                                  rounds_per_launch=8, x0=jnp.asarray(x0))
-    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
+    rs = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16,
                                  rounds_per_launch=8, x0=jnp.asarray(x0))
     np.testing.assert_allclose(np.asarray(rs.trace.objective),
                                np.asarray(rd.trace.objective),
                                rtol=1e-3, atol=1e-3)
     # warm trace must continue below the cold start's first objective
-    cold = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16, fused=True,
+    cold = ops.block_shotgun_solve(ps, jax.random.PRNGKey(3), K=2, rounds=16,
                                    rounds_per_launch=8)
     assert float(rs.trace.objective[0]) < float(cold.trace.objective[0])
 
 
 def test_sparse_fused_engine_single_shard_matches_fused_solver():
     """engine="sparse_fused", merge="round" on a 1-shard mesh retraces
-    block_shotgun_solve(fused=True) on the same BlockedCSC problem (DESIGN
+    block_shotgun_solve on the same BlockedCSC problem (DESIGN
     §3 trace equivalence), and merge="launch" matches at merge points."""
     from repro.core.sharded import make_feature_mesh, shotgun_sharded_solve
     _, S, y = _pair()
@@ -383,8 +308,7 @@ def test_sparse_fused_engine_single_shard_matches_fused_solver():
     mesh = make_feature_mesh(jax.devices()[:1])
     rounds = 16
     rf = ops.block_shotgun_solve(ps, jax.random.PRNGKey(4), K=2,
-                                 rounds=rounds, fused=True,
-                                 rounds_per_launch=8)
+                                 rounds=rounds, rounds_per_launch=8)
     r_sh = shotgun_sharded_solve(ps, jax.random.PRNGKey(4), rounds=rounds,
                                  engine="sparse_fused", merge="round", K=2,
                                  mesh=mesh, trace_every=rounds)
@@ -432,6 +356,7 @@ from repro.core import objectives as obj
 from repro.core.sharded import shotgun_sharded_solve, make_feature_mesh
 from repro.core.shotgun import shotgun_solve
 from repro.data import synthetic as syn
+from repro.kernels import ops
 
 # Same interference-safe shape as the dense engine leg: P* ~ 855 at
 # (2048, 8192, density 0.002), P_eff = 8 shards * K=1 * 128 = 1024 with
@@ -453,13 +378,10 @@ f = float(r.trace.objective[-1])
 assert abs(f - f_ref) / f_ref < 0.10, (f, f_ref)
 np.testing.assert_allclose(np.asarray(r.z), np.asarray(obj.matvec(prob.A, r.x)),
                            rtol=2e-3, atol=2e-3)
-# the sparse_fused and sparse_block engines draw the same blocks per shard,
-# and merge="round" removes all staleness: identical trajectories
-rb = shotgun_sharded_solve(prob, jax.random.PRNGKey(0), rounds=256,
-                           mesh=mesh8, engine="sparse_block", merge="round",
-                           K=1, trace_every=8)
-np.testing.assert_allclose(np.asarray(r.trace.objective),
-                           np.asarray(rb.trace.objective), rtol=1e-4)
+# the one-device fused sparse solve at the same P = 8 * 128 draws other
+# blocks but runs the same algorithm: the same converged objective
+rb = ops.block_shotgun_solve(prob, jax.random.PRNGKey(0), K=8, rounds=256)
+np.testing.assert_allclose(float(rb.trace.objective[-1]), f, rtol=1e-4)
 print("SPARSE_FUSED_ROUND_OK")
 
 # merge="launch" on 2 shards: stale windows of R*K*128*2 = 512 updates stay

@@ -20,9 +20,7 @@ from repro.kernels.batched import batched_fused_shotgun_rounds
 from repro.kernels.shotgun_block import (BLOCK, VMEM_BUDGET, auto_tile_n,
                                          fused_shotgun_delta_rounds,
                                          fused_shotgun_rounds,
-                                         fused_vmem_bytes,
-                                         gather_block_matvec,
-                                         scatter_block_update)
+                                         fused_vmem_bytes)
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +53,8 @@ def _compile(fn, *args, **kw):
 
 @pytest.mark.parametrize("n,d,K,tile_n,loss", [
     (4096, 65536, 20, None, "lasso"),            # single-phase (T == 1)
+    (4096, 65536, 20, None, "logistic"),
+    (4096, 65536, 20, None, "logistic_newton"),
     (8192, 8192, 8, 512, "lasso"),               # two-phase (T == 16)
     (4096, 2048, 8, None, "logistic_newton"),    # per-block Newton
 ])
@@ -79,20 +79,6 @@ def test_batched_fused_rounds_compile(one_chip):
     c = _compile(batched_fused_shotgun_rounds, _sds(one_chip, (S, n, d)), z,
                  x, idx, lam, beta, y, mask, _sds(one_chip, (S,)),
                  _sds(one_chip, (S,)), loss="lasso", interpret=False)
-    assert "tpu_custom_call" in c.as_text()
-
-
-@pytest.mark.parametrize("kernel", ["gather", "scatter"])
-def test_two_kernel_round_compiles(one_chip, kernel):
-    n, d, K = 4096, 65536, 20
-    A = _sds(one_chip, (n, d))
-    idx = _sds(one_chip, (K,), jnp.int32)
-    if kernel == "gather":
-        c = _compile(gather_block_matvec, A, _sds(one_chip, (n,)), idx,
-                     interpret=False)
-    else:
-        c = _compile(scatter_block_update, A, _sds(one_chip, (n,)), idx,
-                     _sds(one_chip, (K, BLOCK)), interpret=False)
     assert "tpu_custom_call" in c.as_text()
 
 
