@@ -21,7 +21,10 @@ the scope ``shotgun.path.objective``.
 The host reads from the chip only where a decision needs a value: one
 read a chunk carries all that chunk's values, and x stays on the chip
 from λ to λ unless the cache hands back an x this path did not write
-(``PathResult.syncs``, ``PathResult.uploads``).
+(``PathResult.syncs``, ``PathResult.uploads``).  On the ``block_fused``
+family a chunk is one dispatch beside its read: the key split, the solve
+and its pads and slices run in one program, on a design padded once a
+path (``PathResult.pads``).
 """
 from __future__ import annotations
 
@@ -50,6 +53,10 @@ class PathResult(NamedTuple):
     # cache hits whose x0 went to the device: entries this path did not
     # write (a hit on the x it last put keeps the device's copy)
     uploads: int | None = None
+    # padded copies of A the path made: 1 for a ragged dense design on the
+    # block solvers, whatever the number of chunks, 0 for an aligned one;
+    # None for solvers that pad (or not) on their own, uncounted
+    pads: int | None = None
 
 
 class _Reads:
@@ -89,6 +96,42 @@ def _largest_divisor_leq(n: int, cap: int) -> int:
     return 1
 
 
+def _family(name) -> str:
+    """The family a ``SOLVER_NAMES`` entry or ``(family, loss)`` pair adapts
+    like: the frozen *_logreg_fused aliases are ``block_fused``."""
+    family = name[0] if isinstance(name, tuple) else name
+    if family in ("shotgun_logreg_fused", "sparse_logreg_fused"):
+        return "block_fused"
+    return family
+
+
+def _split_on_host(solver: Callable) -> Callable:
+    """A path step ``(prob, key, P, rounds, x0) -> (next key, Result)`` from
+    a solver of the uniform path signature: the path's key is split on the
+    host and the solver gets the second half."""
+    def step(p, key, P, rounds, x0):
+        key, sub = jax.random.split(key)
+        return key, solver(p, sub, P, rounds, x0)
+    return step
+
+
+def _path_step(prob: obj.Problem, name, **solver_kwargs) -> Callable:
+    """The path step of a registry solver, its admission checked once on
+    ``prob`` (every λ of the path shares its loss and design).
+
+    The ``block_fused`` family runs ``ops.BlockShotgunPath``: the key
+    split, the solve and its pads and slices are one program a step, and
+    the padded design is made once a path (its ``pads``).  Any other
+    family splits the key on the host and calls its adapter."""
+    admit = getattr(shotgun.get_solver(name), "admit", None)
+    if admit is not None:
+        admit(prob)
+    if _family(name) == "block_fused":
+        from repro.kernels import ops
+        return ops.BlockShotgunPath(**solver_kwargs)
+    return _split_on_host(_solver_by_name(name, **solver_kwargs))
+
+
 def _solver_by_name(name: str, **solver_kwargs) -> Callable:
     """Adapt any ``SOLVER_NAMES`` entry to the uniform path signature
     ``(prob, key, P, rounds, x0) -> Result`` (warm start threaded through).
@@ -99,11 +142,8 @@ def _solver_by_name(name: str, **solver_kwargs) -> Callable:
     through (e.g. ``engine=``, ``mesh=``, ``tile_n=``).
     """
     solve = shotgun.get_solver(name)
-    # (family, loss) pairs and the frozen *_logreg_fused aliases adapt like
-    # their base family; the loss admission check rides inside ``solve``.
-    family = name[0] if isinstance(name, tuple) else name
-    if family in ("shotgun_logreg_fused", "sparse_logreg_fused"):
-        family = "block_fused"
+    # the loss admission check rides inside ``solve``
+    family = _family(name)
 
     if family in ("shooting", "shooting_cdn"):
         return lambda p, k, P, r, x0: solve(p, k, rounds=r, x0=x0,
@@ -180,6 +220,15 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
     any other hit uploads x0 and counts in ``PathResult.uploads``.
     Without a cache the path reads once, at its end.
 
+    Dispatch: every solve of the path (a chunk, or a λ without a cache)
+    is one call ``step(p_i, key, P, rounds, x) -> (key, Result)``.  On the
+    ``block_fused`` family that is ``ops.BlockShotgunPath``: one program
+    splits the key, pads x, solves and cuts the result back, on the
+    padded design it made on the path's first solve and keeps while the
+    path hands the same A (``PathResult.pads`` counts the copies: 1 for a
+    ragged dense design, 0 for an aligned one).  Any other solver gets
+    the key split on the host, then its own call.
+
     ``spec=SolverSpec(...)`` is the canonical interface (DESIGN §12): P =
     spec.P, rounds_per_lambda = spec.rounds, with ``spec.loss`` validated
     against ``prob.loss``.  The legacy (P, rounds_per_lambda) kwargs still
@@ -211,13 +260,15 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
                 f"(pass validate_p=False to override)", stacklevel=3)
             P = ps
     if isinstance(solver, (str, tuple)):
-        solver = _solver_by_name(solver, **solver_kwargs)
+        step = _path_step(prob, solver, **solver_kwargs)
     elif solver_kwargs:
         raise ValueError(
             f"solver_kwargs {sorted(solver_kwargs)} are only forwarded when "
             f"``solver`` is a registry name; got solver={solver!r}")
-    elif solver is None:
-        solver = lambda p, k, P, rounds, x0: shotgun.shotgun_solve(p, k, P=P, rounds=rounds, x0=x0)
+    else:
+        if solver is None:
+            solver = lambda p, k, P, rounds, x0: shotgun.shotgun_solve(p, k, P=P, rounds=rounds, x0=x0)
+        step = _split_on_host(solver)
     with jax.profiler.TraceAnnotation("shotgun.path.lambda_max"):
         lmax = read(float, obj.lambda_max(prob.A, prob.y, prob.loss))
     lams = lambda_sequence(lmax, lam_target, num_lambdas)
@@ -227,16 +278,16 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
         traces = []
         for i, lam in enumerate(lams):
             with jax.profiler.TraceAnnotation("shotgun.path.lambda", lam=i):
-                key, sub = jax.random.split(key)
                 p_i = prob._replace(lam=jnp.float32(lam))
-                res = solver(p_i, sub, P, rounds_per_lambda, x)
+                key, res = step(p_i, key, P, rounds_per_lambda, x)
                 x = res.x
                 traces.append((res.trace.objective, res.trace.nnz))
         fs, ks = zip(*read(jax.device_get, traces))
         return PathResult(x=x, lambdas=lams,
                           objectives=np.array([float(f[-1]) for f in fs]),
                           nnz=np.array([int(k[-1]) for k in ks]),
-                          syncs=read.count, uploads=0)
+                          syncs=read.count, uploads=0,
+                          pads=getattr(step, "pads", None))
 
     from repro.core.batched import launch_converged
     pid = "path" if problem_id is None else problem_id
@@ -256,8 +307,7 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
             while spent < rounds_per_lambda:
                 with jax.profiler.TraceAnnotation("shotgun.path.chunk",
                                                   chunk=spent // chunk):
-                    key, sub = jax.random.split(key)
-                    res = solver(p_i, sub, P, chunk, x)
+                    key, res = step(p_i, key, P, chunk, x)
                     x = res.x
                     spent += chunk
                     f_prev, f_chunk, nnz_chunk, x_host = read(
@@ -272,4 +322,5 @@ def solve_path(prob: obj.Problem, key: jax.Array, lam_target: float,
             nnzs.append(int(nnz_chunk[-1]))
     return PathResult(x=x, lambdas=lams, objectives=np.array(objs),
                       nnz=np.array(nnzs), rounds=np.array(rounds_used),
-                      syncs=read.count, uploads=uploads)
+                      syncs=read.count, uploads=uploads,
+                      pads=getattr(step, "pads", None))

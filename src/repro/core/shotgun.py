@@ -209,9 +209,9 @@ SOLVER_NAMES = ("shooting", "shotgun", "shotgun_dup", "shotgun_cdn",
 def _loss_bound(fn, loss: str, family, require_sparse: bool = False):
     """Wrap a solver so it refuses problems built for a different loss
     (naming both, serve-layer convention) — and, for the sparse-only
-    entries, refuses dense designs."""
-    @functools.wraps(fn)
-    def solve(prob, *args, **kwargs):
+    entries, refuses dense designs.  The check alone is the wrapper's
+    ``admit(prob)``."""
+    def admit(prob):
         if prob.loss != loss:
             raise ValueError(
                 f"solver {family!r} is bound to loss {loss!r} but the "
@@ -222,7 +222,12 @@ def _loss_bound(fn, loss: str, family, require_sparse: bool = False):
                 raise ValueError(
                     f"solver {family!r} needs a BlockedCSC design; got "
                     f"{type(prob.A).__name__}")
+
+    @functools.wraps(fn)
+    def solve(prob, *args, **kwargs):
+        admit(prob)
         return fn(prob, *args, **kwargs)
+    solve.admit = admit
     return solve
 
 

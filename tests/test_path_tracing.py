@@ -8,6 +8,8 @@ name of its own.
 """
 import contextlib
 import copy
+import glob
+import os
 
 import jax
 import jax.numpy as jnp
@@ -18,18 +20,21 @@ from repro.core import SolverSpec, solve_path, spectral
 from repro.core import objectives as obj
 from repro.core.batched import WarmStartCache, launch_converged
 from repro.core.path import _solver_by_name, lambda_sequence
+from repro.data.sparse import BlockedCSC
 from repro.kernels import shotgun_block, shotgun_sparse
 
 
-def _problem(loss):
+def _problem(loss, shape=None, sparse=False):
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
-    n, d = (300, 1000) if loss == "lasso" else (256, 512)
+    n, d = shape or ((300, 1000) if loss == "lasso" else (256, 512))
     A = jax.random.normal(k1, (n, d)) / np.sqrt(n)
     signal = A @ jnp.zeros(d).at[:10].set(2.0)
     noise = jax.random.normal(k2, (n,))
     y = (signal + 0.01 * noise if loss == "lasso"
          else jnp.sign(signal + 0.1 * noise))
     lam = 0.1 * float(obj.lambda_max(A, y, loss))
+    if sparse:
+        A = BlockedCSC.from_dense(A * (jnp.abs(A) > 1.0 / np.sqrt(n)))
     return obj.make_problem(A, y, lam, loss=loss, normalize=False), lam
 
 
@@ -73,9 +78,18 @@ def _assert_same_path(got, want):
     assert np.array_equal(got.rounds, rounds)
 
 
-@pytest.mark.parametrize("loss", ["lasso", "logistic"])
-def test_path_with_spans_is_bit_identical_to_the_loop_without(loss):
-    prob, lam = _problem(loss)
+@pytest.mark.parametrize("loss,shape,sparse,pads", [
+    pytest.param("lasso", None, False, 1, id="lasso"),
+    pytest.param("logistic", None, False, 1, id="logistic"),
+    pytest.param("lasso", (512, 1024), False, 0, id="lasso-aligned"),
+    pytest.param("lasso", None, True, 0, id="lasso-bcsc")])
+def test_path_with_spans_is_bit_identical_to_the_loop_without(loss, shape,
+                                                              sparse, pads):
+    """The path pads a ragged dense design once and reuses it in every
+    chunk of every λ, against the loop's padded copy a chunk: the same
+    answers, bit for bit.  An aligned or a BlockedCSC design is never
+    copied."""
+    prob, lam = _problem(loss, shape, sparse)
     spec = SolverSpec(loss=loss, P=64, rounds=32)
     got = solve_path(prob, jax.random.PRNGKey(7), lam_target=lam,
                      num_lambdas=5, solver="block_fused", spec=spec,
@@ -86,6 +100,7 @@ def test_path_with_spans_is_bit_identical_to_the_loop_without(loss):
     # one read a chunk, then λ_max and P*; every hit is the path's own x
     assert got.syncs == want[3].sum() // 8 + 2
     assert got.uploads == 0
+    assert got.pads == pads and want[3].max() > 8
 
 
 class _RecordingCache(WarmStartCache):
@@ -176,6 +191,66 @@ def test_syncs_counted_without_a_cache():
     assert got.rounds is None
     assert got.syncs == 2     # λ_max, then every λ's F and nnz at the end
     assert got.uploads == 0
+    assert got.pads == 1      # (300, 1000), padded once for three λ
+
+
+def _host_events(log_dir):
+    """[name, start_ns, end_ns] of the host events on the thread that holds
+    the path's spans, in the newest trace under ``log_dir``."""
+    paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(max(paths,
+                                                  key=os.path.getmtime))
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(name == "shotgun.path.chunk" for name, _, _ in evs):
+                return evs
+    raise AssertionError("no shotgun.path.chunk span in the trace")
+
+
+def _dispatches(evs, span):
+    """The programs dispatched inside ``span`` outside its reads: each
+    ``PjitFunction(...)`` event once (the profiler nests two of a name for
+    one dispatch)."""
+    _, s0, s1 = span
+    reads = [(s, e) for name, s, e in evs
+             if name == "shotgun.path.sync" and s0 <= s < s1]
+    calls = sorted((s, e, name) for name, s, e in evs
+                   if name.startswith("PjitFunction(") and s0 <= s < s1
+                   and not any(r0 <= s < r1 for r0, r1 in reads))
+    out, end = [], {}
+    for s, e, name in calls:
+        if s >= end.get(name, -1):
+            out.append(name)
+            end[name] = e
+    return out
+
+
+def test_a_chunk_after_the_first_is_one_dispatch(tmp_path):
+    """Each chunk of a λ path dispatches one program, the solve, beside
+    its read: the key split, x0's pad and the result's slices run inside
+    it.  The path's first chunk also pads the ragged design, once."""
+    prob, lam = _problem("lasso")
+
+    def path():
+        return solve_path(prob, jax.random.PRNGKey(7), lam_target=lam,
+                          num_lambdas=3, solver="block_fused",
+                          spec=SolverSpec(loss="lasso", P=64, rounds=32),
+                          cache=WarmStartCache(), tol=1e-4)
+    path()                                   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        res = path()
+    evs = _host_events(str(tmp_path))
+    chunks = sorted((e for e in evs if e[0] == "shotgun.path.chunk"),
+                    key=lambda e: e[1])
+    assert len(chunks) == res.rounds.sum() // 8 > 3
+    assert _dispatches(evs, chunks[0]) == ["PjitFunction(pad_problem)",
+                                           "PjitFunction(_fused_solve)"]
+    for chunk in chunks[1:]:
+        assert _dispatches(evs, chunk) == ["PjitFunction(_fused_solve)"]
+    assert res.pads == 1
 
 
 def test_pad_problem_is_one_program_and_keeps_an_aligned_design():
